@@ -52,6 +52,7 @@ from typing import Optional, Tuple
 from urllib.parse import parse_qs, unquote, urlsplit
 
 from kwok_tpu.cluster.flowcontrol import FlowRejected, expose_metrics
+from kwok_tpu.native import status as _native_status
 from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.cluster.k8s_api import (
     PATCH_CONTENT_TYPES,
@@ -674,6 +675,10 @@ class _Handler(BaseHTTPRequestHandler):
                     # tenant count + cold/warm/idle split (kwokctl get
                     # components grows a fleet= column from this)
                     body["fleet"] = fleet.snapshot()
+                # per-unit native load state of THIS process: a store
+                # on the pure-Python drain is slower, never wrong, so
+                # nothing else would tell (kwok_tpu/native/_artifact.py)
+                body["native"] = _native_status()
                 self._send_json(200, body)
             elif head == "debug" and rest == ["flightrecorder"]:
                 # the flight recorder: last-N tick stage breakdowns +

@@ -136,7 +136,7 @@ DEFAULT_RESERVE_BYTES = 256 * 1024
 
 
 def classify_os_error(exc: OSError) -> str:
-    """Exhaustion taxonomy for an append/fsync/seal failure: the three
+    """Exhaustion classes of an append/fsync/seal failure: the three
     errnos the resource-exhaustion layer treats distinctly, plus a
     catch-all.  ``disk-full``/``quota`` mean space may come back (the
     degraded probe re-arms); ``io-error`` means the media itself

@@ -30,7 +30,10 @@ from kwok_tpu.controllers.pod_controller import PodController
 from kwok_tpu.controllers.stage_controller import StageController
 from kwok_tpu.controllers.stages_manager import StagesManager
 from kwok_tpu.utils.clock import Clock, RealClock
+from kwok_tpu.utils.log import get_logger
 from kwok_tpu.utils.queue import Queue
+
+_LOG = get_logger("controller")
 
 
 def _match_annotations(obj: dict, selector: str) -> bool:
@@ -84,6 +87,9 @@ class Controller:
         self.node_leases: Optional[NodeLeaseController] = None
         self.stage_controllers: Dict[str, StageController] = {}
         self.device_players: Dict[str, object] = {}
+        #: kind -> why its stages stay on the host controllers although
+        #: the device backend is configured (StageCompileError text)
+        self.host_fallbacks: Dict[str, str] = {}
         self.stages_manager = StagesManager(
             store,
             on_ref_added=self._on_ref_added,
@@ -199,7 +205,11 @@ class Controller:
             self.nodes.manage_node(node_name)
         if self.pods is not None:
             self.pods.sync_node(node_name)
-        for dp in self.device_players.values():
+        # snapshot: lease workers land here while _start_device_controller
+        # inserts the next kind's player under _mut
+        with self._mut:
+            players = list(self.device_players.values())
+        for dp in players:
             dp.sync_node(node_name)
 
     def _on_node_unmanaged(self, node_name: str) -> None:
@@ -285,7 +295,9 @@ class Controller:
     def _start_device_controller(self, kind: str) -> bool:
         """Try the vectorized device backend for this kind; returns
         False (host fallback) when the stage set does not lower to the
-        AOT tick kernel (SURVEY.md §7.1 compile-time vocabulary split)."""
+        AOT tick kernel (SURVEY.md §7.1 compile-time vocabulary split).
+        The split is by design; it is logged once per kind and kept in
+        ``host_fallbacks`` so nobody has to guess what runs where."""
         from kwok_tpu.controllers.device_player import DeviceStagePlayer
         from kwok_tpu.controllers.pod_controller import PodEnv
         from kwok_tpu.engine.compiler import StageCompileError
@@ -329,8 +341,17 @@ class Controller:
                 seed=self.rng.randrange(2**31),
                 mesh=self._device_mesh,
             )
-        except StageCompileError:
+        except StageCompileError as exc:
+            if self.host_fallbacks.get(kind) != str(exc):
+                self.host_fallbacks[kind] = str(exc)
+                _LOG.warn(
+                    "stages do not lower to the device tick; "
+                    "kind stays on the host controllers",
+                    kind=kind,
+                    reason=str(exc),
+                )
             return False
+        self.host_fallbacks.pop(kind, None)
         if kind == "Node" and self.node_leases is not None:
             # lease renewals ride the node player's device tick
             # (SURVEY §7 step 5): held leases register on a vectorized
@@ -419,6 +440,20 @@ class Controller:
             dp.stop()
 
     # -------------------------------------------------------------------- stats
+
+    def players(self) -> List[tuple]:
+        """``(kind, backend, player)`` for every running stage player:
+        which kinds the device ticks and which the host controllers
+        play (self-metrics, ``chip_smoke.py``)."""
+        with self._mut:
+            out = [
+                (kind, "host", host)
+                for kind, host in (("Node", self.nodes), ("Pod", self.pods))
+                if host is not None
+            ]
+            out += [(k, "host", sc) for k, sc in self.stage_controllers.items()]
+            out += [(k, "device", dp) for k, dp in self.device_players.items()]
+        return out
 
     def transition_count(self) -> int:
         total = 0

@@ -424,14 +424,24 @@ class BinaryRuntime:
             return
         if not os.path.isdir(self._path("pids")):
             return
-        # reverse dependency order; signal everything first so slow
-        # shutdowns overlap (total wait ~= slowest component, not the
-        # sum — a loaded box was paying 4x10s sequentially)
-        comps = self.load_components() if self.exists() else []
-        for comp in reversed(comps):
-            self._signal_component(comp.name)
-        for comp in reversed(comps):
-            self._await_component_exit(comp.name)
+        # reverse dependency order, one wave at a time: a component is
+        # signalled only once nothing still running depends on it.  The
+        # controllers' shutdown WRITES (node-lease and election-lease
+        # releases) need the apiserver; signalled together with it they
+        # retried against a dead port until the SIGKILL below, so the
+        # kwok daemon never exited by itself — under --backend device,
+        # holding the chip.  Within a wave everything is signalled
+        # first so slow shutdowns overlap (wait ~= the slowest, not the
+        # sum — a loaded box was paying 4x10s sequentially).
+        remaining = self.load_components() if self.exists() else []
+        while remaining:
+            needed = {d for c in remaining for d in c.depends_on}
+            wave = [c for c in remaining if c.name not in needed] or remaining
+            for comp in reversed(wave):
+                self._signal_component(comp.name)
+            for comp in reversed(wave):
+                self._await_component_exit(comp.name)
+            remaining = [c for c in remaining if c not in wave]
 
     def running_components(self) -> Dict[str, bool]:
         out = {}

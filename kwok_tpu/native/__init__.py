@@ -1,46 +1,39 @@
 """ctypes bindings for the C++ runtime core (native/kwok_native.cpp).
 
 The shared library is built on demand with g++ the first time it is
-needed (and cached beside this package); when no toolchain is present
-everything falls back to the pure-Python implementations, so the
-native layer is a transparent accelerator, never a hard dependency.
+needed (kwok_tpu/native/_artifact.py: keyed by source hash, built
+atomically).  ``KWOK_TPU_NATIVE=0`` is the explicit switch to the
+pure-Python implementations; any other reason for not loading (no
+toolchain, a compile error) is logged once per process with the
+compiler's output and shows in :func:`kwok_tpu.native.status`.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
 import threading
 from typing import Optional
 
-_LIB_NAME = "libkwok_native.so"
+from kwok_tpu.native._artifact import ensure, load_unit, source_path, status
+
+__all__ = ["NativeDelayHeap", "available", "fnv1a64", "load", "status"]
+
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _source_dir() -> str:
-    repo_root = os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+def _command(target: str) -> list:
+    return [
+        "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
+        "-o", target, source_path("kwok_native.cpp"),
+    ]
+
+
+def _open() -> ctypes.CDLL:
+    return ctypes.CDLL(
+        ensure("libkwok_native", source_path("kwok_native.cpp"), _command)
     )
-    return os.path.join(repo_root, "native")
-
-
-def _build(target: str) -> bool:
-    src = os.path.join(_source_dir(), "kwok_native.cpp")
-    if not os.path.exists(src):
-        return False
-    try:
-        subprocess.run(
-            ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", target, src],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -50,16 +43,11 @@ def load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        here = os.path.dirname(os.path.abspath(__file__))
-        cached = os.path.join(here, _LIB_NAME)
         # the compile runs under _lock on purpose: build-once semantics —
         # concurrent first callers must block until the library exists
         # rather than race duplicate compiler invocations
-        if not os.path.exists(cached) and not _build(cached):  # kwoklint: disable=lock-discipline
-            return None
-        try:
-            lib = ctypes.CDLL(cached)
-        except OSError:
+        lib = load_unit("kwok_native", _open, "the pure-Python heap")
+        if lib is None:
             return None
         lib.kn_heap_new.restype = ctypes.c_void_p
         lib.kn_heap_free.argtypes = [ctypes.c_void_p]

@@ -7,8 +7,11 @@ hack/releases.sh:186).  Unlike the ctypes-based delay heap
 (kwok_tpu/native/__init__.py), the
 drain accelerator manipulates Python dicts directly, so it is a real
 extension module compiled against Python.h and imported from its build
-path.  ``KWOK_TPU_NATIVE=0`` or a missing toolchain falls back to the
-pure-Python implementations everywhere it is used.
+path (kwok_tpu/native/_artifact.py: keyed by source hash and Python ABI,
+built atomically).  ``KWOK_TPU_NATIVE=0`` is the explicit switch to the
+pure-Python implementations everywhere it is used; any other reason for
+not loading (no toolchain, a compile error) is logged once per process
+with the compiler's output and shows in ``kwok_tpu.native.status()``.
 """
 
 from __future__ import annotations
@@ -16,52 +19,38 @@ from __future__ import annotations
 import importlib.machinery
 import importlib.util
 import os
-import subprocess
 import sysconfig
 import threading
-from typing import Optional
 
-_LIB_NAME = "kwok_fastdrain.so"
+from kwok_tpu.native._artifact import ensure, load_unit, note, source_path
+
 _lock = threading.Lock()
 _mod = None
 _tried = False
 
 
-def _source_path() -> str:
-    repo_root = os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+def _command(target: str) -> list:
+    include = sysconfig.get_paths().get("include") or ""
+    return [
+        "g++", "-O2", "-shared", "-fPIC", f"-I{include}",
+        "-o", target, "-x", "c", source_path("kwok_fastdrain.c"),
+    ]
+
+
+def _open():
+    path = ensure(
+        "kwok_fastdrain",
+        source_path("kwok_fastdrain.c"),
+        _command,
+        abi=sysconfig.get_config_var("SOABI") or "",
     )
-    return os.path.join(repo_root, "native", "kwok_fastdrain.c")
-
-
-def _build(target: str) -> bool:
-    src = _source_path()
-    if not os.path.exists(src):
-        return False
-    include = sysconfig.get_paths().get("include")
-    if not include:
-        return False
-    try:
-        subprocess.run(
-            [
-                "g++",
-                "-O2",
-                "-shared",
-                "-fPIC",
-                f"-I{include}",
-                "-o",
-                target,
-                "-x",
-                "c",
-                src,
-            ],
-            check=True,
-            capture_output=True,
-            timeout=120,
-        )
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+    loader = importlib.machinery.ExtensionFileLoader("kwok_fastdrain", path)
+    spec = importlib.util.spec_from_file_location(
+        "kwok_fastdrain", path, loader=loader
+    )
+    mod = importlib.util.module_from_spec(spec)
+    loader.exec_module(mod)
+    return mod
 
 
 def load():
@@ -69,36 +58,14 @@ def load():
     unavailable or disabled via KWOK_TPU_NATIVE=0."""
     global _mod, _tried
     if os.environ.get("KWOK_TPU_NATIVE", "1") == "0":
+        note("fastdrain", "disabled")
         return None
     with _lock:
         if _mod is not None or _tried:
             return _mod
         _tried = True
-        here = os.path.dirname(os.path.abspath(__file__))
-        cached = os.path.join(here, _LIB_NAME)
-        src = _source_path()
-        stale = (
-            not os.path.exists(cached)
-            or (
-                os.path.exists(src)
-                and os.path.getmtime(src) > os.path.getmtime(cached)
-            )
-        )
         # the compile runs under the lock on purpose: build-once
         # semantics — concurrent first callers must block until the
         # extension exists rather than race duplicate compiles
-        if stale and not _build(cached):  # kwoklint: disable=lock-discipline
-            return None
-        try:
-            loader = importlib.machinery.ExtensionFileLoader(
-                "kwok_fastdrain", cached
-            )
-            spec = importlib.util.spec_from_file_location(
-                "kwok_fastdrain", cached, loader=loader
-            )
-            mod = importlib.util.module_from_spec(spec)
-            loader.exec_module(mod)
-        except (ImportError, OSError):
-            return None
-        _mod = mod
+        _mod = load_unit("fastdrain", _open, "the pure-Python drain")
         return _mod

@@ -65,7 +65,7 @@ def _lease(name="test-lease"):
 # ------------------------------------------------------------------ wal unit
 
 
-def test_classify_os_error_taxonomy():
+def test_classify_os_error_classes():
     assert classify_os_error(OSError(errno.ENOSPC, "x")) == "disk-full"
     assert classify_os_error(OSError(errno.EIO, "x")) == "io-error"
     if hasattr(errno, "EDQUOT"):

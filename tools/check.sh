@@ -1,11 +1,13 @@
 #!/usr/bin/env bash
 # One-command repo gate: kwoklint + tier-1 tests + a chaos smoke + a
-# scaled bench smoke.  This is the CI entrypoint shape — each stage
-# fails fast and loudly.
+# scaled bench smoke + the chip smoke's CPU dry run.  This is the CI
+# entrypoint shape — each stage fails fast and loudly.  Everything here
+# runs on the CPU (JAX_PLATFORMS=cpu, given explicitly); the chip is
+# reached with `python chip_smoke.py` through the chip tool.
 #
 #   tools/check.sh            # full tier-1 (sequential, ~15 min)
 #   FAST=1 tools/check.sh     # -n 4 --dist loadfile (~8 min, may flake timing gates)
-#   SKIP_BENCH=1 SKIP_CHAOS=1 tools/check.sh
+#   SKIP_BENCH=1 SKIP_CHAOS=1 SKIP_SMOKE=1 tools/check.sh
 set -euo pipefail
 
 cd "$(dirname "$0")/.."
@@ -72,6 +74,15 @@ if [[ "${SKIP_BENCH:-0}" != "1" ]]; then
         BENCH_E2E_WINDOW_S="${BENCH_E2E_WINDOW_S:-5}" \
         BENCH_E2E_BUDGET_S="${BENCH_E2E_BUDGET_S:-60}" \
         python bench.py
+fi
+
+if [[ "${SKIP_SMOKE:-0}" != "1" ]]; then
+    echo "== chip_smoke dry run (tiny sizes, CPU pinned: real daemons, counts only) =="
+    # the command the chip runs with no arguments (python chip_smoke.py),
+    # debugged here at a tiny size first; it labels itself cpu
+    JAX_PLATFORMS=cpu python chip_smoke.py \
+        --nodes "${SMOKE_NODES:-10}" --pods-per-node 20 --delete-pods 20 \
+        --soa-pods 4096 --soa-nodes 64 --parity-rows 2048 --macro-ticks 3
 fi
 
 echo "== all checks passed =="
