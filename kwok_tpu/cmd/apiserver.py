@@ -24,6 +24,7 @@ import os
 import signal
 import sys
 import threading
+import time
 
 from kwok_tpu.cluster.apiserver import APIServer
 from kwok_tpu.cluster.store import ResourceStore
@@ -532,6 +533,15 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
     if args.wal_file:
         threading.Thread(target=rearm_loop, daemon=True).start()
 
+    from kwok_tpu.utils import telemetry
+
+    # the snapshot serializes the whole store under the request threads'
+    # GIL: how long each took, on this process's /metrics
+    h_save = telemetry.histogram(
+        "kwok_apiserver_save_seconds",
+        help="one periodic save of the store (state file, PITR copy, WAL compaction)",
+        buckets=telemetry.DEFAULT_BUCKETS + (30.0, 60.0),
+    )
     saved_rv = -1
     while not done.wait(args.save_interval):
         if args.state_file and store.resource_version != saved_rv:
@@ -539,7 +549,10 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
             # snapshot serializes must re-trigger the next tick (and
             # the shutdown save), not be marked covered
             rv = store.resource_version
-            if save_once():
+            t_save = time.perf_counter()
+            ok = save_once()
+            h_save.observe(time.perf_counter() - t_save)
+            if ok:
                 saved_rv = rv
     if args.state_file and store.resource_version != saved_rv:
         save_once()

@@ -284,6 +284,17 @@ def _controller_self_metrics(get_ctr, elector=None, device=None):
                 device_kind=device["device_kind"],
                 devices=str(device["count"]),
             )
+            # the fullest local device, as memory_stats() has it (an
+            # XLA:CPU device has none: the series is then absent)
+            mem = accel.memory_stats()
+            for stat, field in (("in_use", "bytes_in_use"), ("peak", "peak_bytes_in_use")):
+                if mem.get(field) is not None:
+                    gauge(
+                        "kwok_device_memory_bytes",
+                        "Device memory of the fullest local device.",
+                        mem[field],
+                        stat=stat,
+                    )
             cs = accel.compile_stats()
             counter(
                 "kwok_jit_compilations_total",
@@ -333,13 +344,6 @@ def _controller_self_metrics(get_ctr, elector=None, device=None):
                 "kwok_stage_transitions_total",
                 "Stage transitions played.",
                 getattr(p, "transitions", 0),
-                kind=kind,
-                backend=backend,
-            )
-            counter(
-                "kwok_patches_total",
-                "Patches written to the cluster.",
-                getattr(p, "patches", 0),
                 kind=kind,
                 backend=backend,
             )

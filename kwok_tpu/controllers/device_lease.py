@@ -22,6 +22,7 @@ the host backend.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -29,9 +30,19 @@ import jax
 import numpy as np
 
 from kwok_tpu.engine.compiler import NEVER
+from kwok_tpu.engine.simulator import ShapeLog
 from kwok_tpu.ops.tick import LeaseLane, lease_tick
+from kwok_tpu.utils import telemetry as _telemetry
 
 __all__ = ["DeviceLeaseLane"]
+
+#: over the whole run, not the last samples: seconds from a lease's
+#: scheduled fire time to the return of the ``renew_batch`` that wrote
+#: it (the lag ``lease_tick`` reports plus the write's round trip)
+_H_DELAY = _telemetry.histogram(
+    "kwok_lease_renew_delay_seconds",
+    help="scheduled fire time of a lease renewal to the return of its write",
+)
 
 
 class DeviceLeaseLane:
@@ -56,6 +67,7 @@ class DeviceLeaseLane:
         #: fire time, virtual clock) — p99 surfaces in self-metrics
         self.renew_lags = deque(maxlen=4096)
         self.renew_count = 0
+        self._shapes = ShapeLog("Node")
 
     # ------------------------------------------------------------- membership
 
@@ -102,6 +114,7 @@ class DeviceLeaseLane:
     def tick(self, now_ms: int) -> int:
         """Advance the lane to the node player's virtual now; renew all
         due leases in one batch.  Returns the number renewed."""
+        t_now = time.perf_counter()  # the instant ``now_ms`` was taken
         with self._mut:
             now_ms -= self._base
             if now_ms >= 2**30:
@@ -120,12 +133,15 @@ class DeviceLeaseLane:
                 self._lane = LeaseLane(
                     fire_at=jax.numpy.asarray(self._fire_np), key=self._key
                 )
-            lane, due, lag = lease_tick(
-                self._lane,
-                jax.numpy.int32(now_ms),
-                jax.numpy.int32(self.renew_ms),
-                jax.numpy.int32(self.jitter_ms),
-            )
+            with self._shapes.first_use(
+                "lease_tick", (len(self._fire_np),), ("capacity",)
+            ):
+                lane, due, lag = lease_tick(
+                    self._lane,
+                    jax.numpy.int32(now_ms),
+                    jax.numpy.int32(self.renew_ms),
+                    jax.numpy.int32(self.jitter_ms),
+                )
             self._lane = lane
             self._key = lane.key
             due_np = np.asarray(due)
@@ -136,15 +152,23 @@ class DeviceLeaseLane:
             self._fire_np = np.array(lane.fire_at)
             lag_np = np.asarray(lag)
             names = []
+            lags = []
             for slot in np.nonzero(due_np)[0]:
                 name = self._names[slot]
                 if name is None:
                     continue
                 names.append(name)
-                self.renew_lags.append(float(lag_np[slot]) / 1000.0)
+                lags.append(float(lag_np[slot]) / 1000.0)
+            self.renew_lags.extend(lags)
         if not names:
             return 0
         failed = self.ctrl.renew_batch(names)
+        if _telemetry.enabled():
+            wrote = time.perf_counter() - t_now
+            lost = set(failed)
+            for name, lag_s in zip(names, lags):
+                if name not in lost:
+                    _H_DELAY.observe(lag_s + wrote)
         with self._mut:
             self.renew_count += len(names) - len(failed)
         for name in failed:

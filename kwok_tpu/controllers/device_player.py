@@ -45,16 +45,13 @@ _FAST = _load_fastdrain()
 
 _LOG = get_logger("device-player")
 
-#: observed per-stage tick pipeline timing (SLO telemetry): the
-#: production drain loop's split of each macro-tick into device kernel
-#: / host drain / host patch build / store round-trips — ROADMAP open
-#: item 1's ``host_build`` wall as a live series instead of a bench
-#: artifact.  Labels are bounded: resource kind x four stage names.
-_H_TICK = _telemetry.histogram(
-    "kwok_tick_stage_seconds",
-    help="per-macro-tick stage time (device_tick/host_drain/host_build/store_bulk)",
-    labelnames=("kind", "stage"),
-)
+#: every stage of the tick thread runs inside one ``_stage(kind, name)``
+#: (utils/telemetry.stage): a TraceAnnotation ``kwok/<kind>/<name>`` on
+#: the profiler's clock and ``kwok_tick_stage_seconds{kind,stage}``.
+#: Outermost: ingest, device_tick, host_drain, post_tick, pace_wait;
+#: host_build and store_bulk nest in host_drain (which reports self
+#: time), compile (engine/simulator.py) overlays the stage it stalls.
+_stage = _telemetry.stage
 
 #: live players for the interpreter-exit safety net: a daemon tick
 #: thread killed mid-XLA-dispatch at teardown aborts the whole process
@@ -115,7 +112,9 @@ class DeviceStagePlayer:
         self.funcs_for = funcs_for or (lambda obj: {})
         self.on_delete = on_delete
         self.tick_ms = tick_ms
-        self.sim = DeviceSimulator(stages, capacity=capacity, seed=seed, mesh=mesh)
+        self.sim = DeviceSimulator(
+            stages, capacity=capacity, seed=seed, mesh=mesh, kind=kind
+        )
         self._informer = Informer(store, kind)
         self.events: Queue = Queue()
         #: (namespace, name) -> row
@@ -143,7 +142,8 @@ class DeviceStagePlayer:
         #: cumulative step() time split (seconds): device tick kernel,
         #: store round-trips (bulk), and host drain (materialize/render
         #: + any sequential-path store calls) — the e2e bench reads
-        #: these to name the pipeline bottleneck (VERDICT r01 #2)
+        #: these to name the pipeline bottleneck (VERDICT r01 #2).  Fed
+        #: from the stage spans' own ``elapsed``: one clock per stage
         self.t_device = 0.0
         self.t_store = 0.0
         self.t_host = 0.0
@@ -407,7 +407,8 @@ class DeviceStagePlayer:
         next_tick = self.clock.now()
         while not self._done.is_set():
             try:
-                self._drain_events()
+                with _stage(self.kind, "ingest"):
+                    self._drain_events()
                 if not self._paced:
                     # saturation mode: overlapped macro-ticks back to
                     # back — device computes batch N+1 while the host
@@ -444,8 +445,9 @@ class DeviceStagePlayer:
                 # a virtual clock can fast-forward the tick cadence;
                 # the wait is bounded by dt_s, which also bounds stop()
                 # latency exactly like the old bare sleep did
-                self._tick_wake.clear()
-                self.clock.wait_signal(self._tick_wake, min(sleep, dt_s))
+                with _stage(self.kind, "pace_wait"):
+                    self._tick_wake.clear()
+                    self.clock.wait_signal(self._tick_wake, min(sleep, dt_s))
         # drain the last in-flight macro-tick so stop() never strands
         # fired rows
         try:
@@ -514,9 +516,9 @@ class DeviceStagePlayer:
         self.flush_pipeline()
         base = (self.t_device, self.t_store, self.t_host, self.t_build)
         dt = dt_ms if dt_ms is not None else self.tick_ms
-        t0 = time.perf_counter()
-        stages_np, t0_ms = self.sim.tick_many(dt, n_ticks)
-        self.t_device += time.perf_counter() - t0
+        with _stage(self.kind, "device_tick") as sp:
+            stages_np, t0_ms = self.sim.tick_many(dt, n_ticks)
+        self.t_device += sp.elapsed
         fired_total = self._drain_stages(stages_np, t0_ms, dt)
         self._run_post_tick()
         self._observe_tick(base, fired_total)
@@ -525,33 +527,25 @@ class DeviceStagePlayer:
     def _observe_tick(
         self, base: Tuple[float, float, float, float], fired: int
     ) -> None:
-        """Observed per-stage deltas for one macro-tick, and (for
-        firing ticks) a flight-recorder breakdown entry.  Observation-
-        only: nothing here feeds back into pacing or drain routing."""
-        if not _telemetry.enabled():
+        """A flight-recorder breakdown entry for a firing macro-tick
+        (the stage spans observe ``kwok_tick_stage_seconds`` themselves).
+        Observation-only: nothing here feeds back into pacing or drain
+        routing."""
+        if not fired or not _telemetry.enabled():
             return
-        d_dev = self.t_device - base[0]
-        d_store = self.t_store - base[1]
-        d_host = self.t_host - base[2]
         d_build = self.t_build - base[3]
-        # host_drain excludes the patch-build subset, matching the
-        # bench's breakdown_s split (host_drain_s = t_host - build)
-        d_drain = max(d_host - d_build, 0.0)
-        _H_TICK.observe(d_dev, self.kind, "device_tick")
-        _H_TICK.observe(d_drain, self.kind, "host_drain")
-        _H_TICK.observe(d_build, self.kind, "host_build")
-        _H_TICK.observe(d_store, self.kind, "store_bulk")
-        if fired:
-            _telemetry.flight_recorder().record_tick(
-                self.kind,
-                fired,
-                {
-                    "device_tick_s": d_dev,
-                    "host_drain_s": d_drain,
-                    "host_build_s": d_build,
-                    "store_bulk_s": d_store,
-                },
-            )
+        _telemetry.flight_recorder().record_tick(
+            self.kind,
+            fired,
+            {
+                "device_tick_s": self.t_device - base[0],
+                # host_drain excludes the patch-build subset, matching
+                # the bench's breakdown_s split (host_drain_s = t_host - build)
+                "host_drain_s": max(self.t_host - base[2] - d_build, 0.0),
+                "host_build_s": d_build,
+                "store_bulk_s": self.t_store - base[1],
+            },
+        )
 
     def _run_post_tick(self) -> None:
         if self.post_tick is None:
@@ -565,12 +559,21 @@ class DeviceStagePlayer:
         else:
             lane_now = self.sim.now_ms
         try:
-            self.post_tick(lane_now)
+            with _stage(self.kind, "post_tick"):
+                self.post_tick(lane_now)
         except Exception:  # noqa: BLE001 — lane trouble must not
             # stall the stage loop
             self._swallow()
 
     def _drain_stages(self, stages_np: np.ndarray, t0_ms: int, dt: int) -> int:
+        store_before = self.t_store
+        with _stage(self.kind, "host_drain") as sp:
+            fired_total = self._drain_stages_inner(stages_np, t0_ms, dt)
+        # t_host: the drain less its store round-trips (build included)
+        self.t_host += sp.elapsed - (self.t_store - store_before)
+        return fired_total
+
+    def _drain_stages_inner(self, stages_np: np.ndarray, t0_ms: int, dt: int) -> int:
         fired_total = 0
         t_start = time.perf_counter()
         # shared grace anchor for the abort checks at every granularity
@@ -623,20 +626,19 @@ class DeviceStagePlayer:
 
         base = (self.t_device, self.t_store, self.t_host, self.t_build)
         prev = self._inflight
-        t0 = time.perf_counter()
-        stages_dev, t0_ms = self.sim.tick_many_async(dt, n_ticks)
-        self._inflight = (stages_dev, t0_ms, dt)
-        # start the device->host copy NOW so it overlaps the drain
-        # below: the next call's device_get finds the bytes on the host
-        # instead of paying a blocking read
-        stages_dev.copy_to_host_async()
-        self.t_device += time.perf_counter() - t0
+        with _stage(self.kind, "device_tick") as sp:
+            stages_dev, t0_ms = self.sim.tick_many_async(dt, n_ticks)
+            self._inflight = (stages_dev, t0_ms, dt)
+            # start the device->host copy NOW so it overlaps the drain
+            # below: the next call's device_get finds the bytes on the host
+            # instead of paying a blocking read
+            stages_dev.copy_to_host_async()
+            if prev is not None:
+                p_stages, p_t0, p_dt = prev
+                stages_np = np.asarray(jax.device_get(p_stages))
+        self.t_device += sp.elapsed
         fired = 0
         if prev is not None:
-            p_stages, p_t0, p_dt = prev
-            t1 = time.perf_counter()
-            stages_np = np.asarray(jax.device_get(p_stages))
-            self.t_device += time.perf_counter() - t1
             fired = self._drain_stages(stages_np, p_t0, p_dt)
         self._run_post_tick()
         self._observe_tick(base, fired)
@@ -650,7 +652,9 @@ class DeviceStagePlayer:
         import jax
 
         stages_dev, t0_ms, dt = prev
-        stages_np = np.asarray(jax.device_get(stages_dev))
+        with _stage(self.kind, "device_tick") as sp:
+            stages_np = np.asarray(jax.device_get(stages_dev))
+        self.t_device += sp.elapsed
         return self._drain_stages(stages_np, t0_ms, dt)
 
     _PLAN_MISS = object()
@@ -695,8 +699,6 @@ class DeviceStagePlayer:
         # the remote degrade path re-sends patches, which the Python
         # loop still collects
         use_c = _FAST is not None and self._store_has_batch
-        t_host0 = time.perf_counter()
-        t_store_before = self.t_store
         self._grow_row_arrays()
         srow = st[rows]
         sigrow = sigs[rows]
@@ -720,14 +722,14 @@ class DeviceStagePlayer:
             exclude = (
                 self._informer.active_watcher if self._batch_has_exclude else None
             )
-            tb = time.perf_counter()
-            if exclude is not None:
-                results = self.store.apply_status_batch(
-                    self.kind, fast_items, exclude=exclude
-                )
-            else:
-                results = self.store.apply_status_batch(self.kind, fast_items)
-            self.t_store += time.perf_counter() - tb
+            with _stage(self.kind, "store_bulk") as sp:
+                if exclude is not None:
+                    results = self.store.apply_status_batch(
+                        self.kind, fast_items, exclude=exclude
+                    )
+                else:
+                    results = self.store.apply_status_batch(self.kind, fast_items)
+            self.t_store += sp.elapsed
             self._confirm_native_locked(
                 results, fast_rows, fast_items, exclude is not None
             )
@@ -797,24 +799,24 @@ class DeviceStagePlayer:
                             sub, s_idx, comp, bound, plan, row_vals_cb, t_ms, slow
                         ):
                             continue
-                        tb_build = time.perf_counter()
-                        noops, slow_rows = _FAST.fast_group(
-                            objects,
-                            sub,
-                            s_idx,
-                            comp,
-                            bound,
-                            vals_cache,
-                            row_vals_cb,
-                            check_noop,
-                            plan.has_null,
-                            plan.all_top_plain,
-                            plan.top_plain,
-                            _merge_patch,
-                            fast_rows,
-                            fast_items,
-                        )
-                        self.t_build += time.perf_counter() - tb_build
+                        with _stage(self.kind, "host_build") as sp:
+                            noops, slow_rows = _FAST.fast_group(
+                                objects,
+                                sub,
+                                s_idx,
+                                comp,
+                                bound,
+                                vals_cache,
+                                row_vals_cb,
+                                check_noop,
+                                plan.has_null,
+                                plan.all_top_plain,
+                                plan.top_plain,
+                                _merge_patch,
+                                fast_rows,
+                                fast_items,
+                            )
+                        self.t_build += sp.elapsed
                         self.transitions += noops
                         for row in slow_rows:
                             slow.append(self._make_transition(row, s_idx, t_ms))
@@ -858,20 +860,14 @@ class DeviceStagePlayer:
                 self.transitions += transitions_local
             if chunk:
                 _flush_locked()
-        # commit time spent inside the lock is already in t_store
-        self.t_host += (time.perf_counter() - t_host0) - (
-            self.t_store - t_store_before
-        )
 
         if fast_items:
             # only the non-native path reaches here: with use_c the
             # chunked _flush_locked above always drains fast_items
-            tb = time.perf_counter()
-            results = self._store_status_batch(fast_items, fast_patches)
-            self.t_store += time.perf_counter() - tb
-            t_host0 = time.perf_counter()
+            with _stage(self.kind, "store_bulk") as sp:
+                results = self._store_status_batch(fast_items, fast_patches)
+            self.t_store += sp.elapsed
             self._confirm_batch_python(results, fast_rows, fast_items)
-            self.t_host += time.perf_counter() - t_host0
 
         if slow:
             self._drain_slow(slow)
@@ -891,39 +887,39 @@ class DeviceStagePlayer:
         ) as lane:
             if lane is None:
                 return False
-            tb = time.perf_counter()
-            # reserve the chunk's whole rv range up front: if the C
-            # pass dies mid-chunk (MemoryError), the rows it already
-            # stamped must never collide with rvs a later commit
-            # re-issues — rv gaps are legal (the real apiserver's rvs
-            # are sparse), duplicates are not
-            rv_start = lane.rv
-            lane.rv = rv_start + len(sub)
-            n_ok, new_rv, slow_rows, release_rows, _skipped = _FAST.fused_group(
-                self.sim.objects,
-                self._store_keys,
-                sub,
-                s_idx,
-                comp,
-                bound,
-                self._vals_cache,
-                row_vals_cb,
-                int(plan.all_top_plain),
-                plan.top_plain,
-                lane.objects,
-                rv_start,
-                self._written_rv,
-            )
-            # feed the actual consumption back: the C pass returned
-            # normally, so exactly new_rv - rv_start rows were stamped
-            # (the full reservation only matters on the exception
-            # path).  A fully-skipped chunk (n_ok == 0, all rows
-            # stale/slow/released) thus no longer advances store._rv
-            # or sets the inplace_rv history-gap marker — which would
-            # spuriously Expire watchers over a commit that wrote
-            # nothing (ADVICE r5 #1).
-            lane.rv = new_rv
-            self.t_build += time.perf_counter() - tb
+            with _stage(self.kind, "host_build") as sp:
+                # reserve the chunk's whole rv range up front: if the C
+                # pass dies mid-chunk (MemoryError), the rows it already
+                # stamped must never collide with rvs a later commit
+                # re-issues — rv gaps are legal (the real apiserver's rvs
+                # are sparse), duplicates are not
+                rv_start = lane.rv
+                lane.rv = rv_start + len(sub)
+                n_ok, new_rv, slow_rows, release_rows, _skipped = _FAST.fused_group(
+                    self.sim.objects,
+                    self._store_keys,
+                    sub,
+                    s_idx,
+                    comp,
+                    bound,
+                    self._vals_cache,
+                    row_vals_cb,
+                    int(plan.all_top_plain),
+                    plan.top_plain,
+                    lane.objects,
+                    rv_start,
+                    self._written_rv,
+                )
+                # feed the actual consumption back: the C pass returned
+                # normally, so exactly new_rv - rv_start rows were stamped
+                # (the full reservation only matters on the exception
+                # path).  A fully-skipped chunk (n_ok == 0, all rows
+                # stale/slow/released) thus no longer advances store._rv
+                # or sets the inplace_rv history-gap marker — which would
+                # spuriously Expire watchers over a commit that wrote
+                # nothing (ADVICE r5 #1).
+                lane.rv = new_rv
+            self.t_build += sp.elapsed
         self.transitions += n_ok
         self.patches += n_ok
         objects = self.sim.objects
@@ -1076,8 +1072,6 @@ class DeviceStagePlayer:
         """Legacy per-transition drain (deletes, finalizers, events,
         non-status patches): grouped ops through store.bulk with the
         sequential fallback."""
-        t_dev = time.perf_counter()
-        t_store_this = 0.0
         can_bulk = hasattr(self.store, "bulk")
         groups: List[Tuple[Tuple[str, str], List[dict]]] = []
         for j, tr in enumerate(transitions):
@@ -1103,15 +1097,15 @@ class DeviceStagePlayer:
                 for _, ops in groups
                 for op in ops
             ]
-            tb = time.perf_counter()
-            try:
-                if self._bulk_no_copy:
-                    results = self.store.bulk(flat, copy_results=False)
-                else:
-                    results = self.store.bulk(flat)
-            except Exception:  # noqa: BLE001 — drop to per-op on bulk failure
-                results = None
-            t_store_this = time.perf_counter() - tb
+            with _stage(self.kind, "store_bulk") as sp:
+                try:
+                    if self._bulk_no_copy:
+                        results = self.store.bulk(flat, copy_results=False)
+                    else:
+                        results = self.store.bulk(flat)
+                except Exception:  # noqa: BLE001 — drop to per-op on bulk failure
+                    results = None
+            self.t_store += sp.elapsed
             if results is None:
                 results = [self._op_sequential_result(op) for op in flat]
             idx = 0
@@ -1122,8 +1116,6 @@ class DeviceStagePlayer:
                     self._apply_group_results(key, ops, rs)
                 except Exception:  # noqa: BLE001 — per-group isolation
                     self._swallow()
-        self.t_store += t_store_this
-        self.t_host += (time.perf_counter() - t_dev) - t_store_this
 
     def _finish_delete(self, key: Tuple[str, str], out: Optional[dict]) -> None:
         """Complete a stage-driven delete: fully gone → release the

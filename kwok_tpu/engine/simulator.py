@@ -16,6 +16,7 @@ practice since runs are restartable from snapshots.
 
 from __future__ import annotations
 
+import contextlib
 import datetime
 import hashlib
 import json
@@ -42,6 +43,7 @@ from kwok_tpu.ops.tick import (
     scatter_rows,
     tick,
 )
+from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.utils.patch import apply_patch
 
 DEFAULT_EPOCH = datetime.datetime(2026, 1, 1, tzinfo=datetime.timezone.utc)
@@ -52,6 +54,58 @@ DEFAULT_EPOCH = datetime.datetime(2026, 1, 1, tzinfo=datetime.timezone.utc)
 #: epoch forward and rebases every timer column so long record/replay
 #: runs never approach the edge.
 REBASE_AT_MS = 2**30
+
+
+#: first uses of a device program's shape key, by what made the shape
+#: new: every one is a trace + lower + compile (or a persistent-cache
+#: fetch) that the calling tick thread waits for
+_NEW_SHAPES = _telemetry.counter(
+    "kwok_device_new_shapes_total",
+    help="first uses of a device program shape (program: run_ticks_collect/"
+    "scatter_rows/lease_tick/upload; cause: num_ticks/scatter_width/"
+    "capacity/signatures/first)",
+    labelnames=("kind", "program", "cause"),
+)
+_TICKS = _telemetry.counter(
+    "kwok_device_ticks_total",
+    help="device sub-ticks dispatched",
+    labelnames=("kind",),
+)
+
+
+class ShapeLog:
+    """Which shape keys of the device programs this process has used.
+
+    A jit cache is per process, so the seen set is shared by every
+    owner; what a new key differs in from the owner's *last* key names
+    the cause.  A key is a tuple whose components line up with
+    ``parts`` (the cause labels, in the order they are compared)."""
+
+    _seen: Dict[str, set] = {}
+
+    def __init__(self, kind: str):
+        self.kind = kind
+        self._last: Dict[str, tuple] = {}
+
+    def first_use(self, program: str, key: tuple, parts: Tuple[str, ...]):
+        """The context to call ``program`` in: nothing for a key used
+        before, else a ``compile`` stage, counted under the cause found."""
+        last, self._last[program] = self._last.get(program), key
+        seen = self._seen.setdefault(program, set())
+        if key in seen:
+            return _USED_BEFORE
+        seen.add(key)
+        cause = "first"
+        if last is not None:
+            for part, a, b in zip(parts, last, key):
+                if a != b:
+                    cause = part
+                    break
+        _NEW_SHAPES.inc(1, self.kind, program, cause)
+        return _telemetry.stage(self.kind, "compile", overlay=True)
+
+
+_USED_BEFORE = contextlib.nullcontext()
 
 
 def default_env_funcs() -> Dict[str, Callable]:
@@ -108,8 +162,12 @@ class DeviceSimulator:
         seed: int = 0,
         env_funcs: Optional[Dict[str, Callable]] = None,
         mesh=None,
+        kind: str = "",
     ):
         self.cset = CompiledStageSet(stages)
+        #: the resource kind this simulator plays: a label, nothing else
+        self.kind = kind
+        self._shapes = ShapeLog(kind)
         #: optional jax.sharding.Mesh: rows sharded across its devices,
         #: stage tensors replicated (SURVEY §2.9/§7 step 7 scale-out).
         #: The tick is row-parallel, so the only collective is the
@@ -309,18 +367,21 @@ class DeviceSimulator:
             # duplicate scatters carry identical values, so padding with
             # a repeated real row is deterministic
             rows = np.concatenate([rows, np.full(pad - k, rows[0], np.int32)])
-        self._soa = scatter_rows(
-            self._soa,
-            jnp.asarray(rows),
-            jnp.asarray(self.features[rows]),
-            jnp.asarray(self.sig[rows]),
-            jnp.asarray(self.ovc[rows]),
-            jnp.asarray(self.stage[rows]),
-            jnp.asarray(self.fire_at[rows]),
-            jnp.asarray(self.active[rows]),
-            jnp.asarray(self.rematch[rows]),
-            jnp.asarray(self.del_ts[rows]),
-        )
+        with self._shapes.first_use(
+            "scatter_rows", (self.capacity, len(rows)), ("capacity", "scatter_width")
+        ):
+            self._soa = scatter_rows(
+                self._soa,
+                jnp.asarray(rows),
+                jnp.asarray(self.features[rows]),
+                jnp.asarray(self.sig[rows]),
+                jnp.asarray(self.ovc[rows]),
+                jnp.asarray(self.stage[rows]),
+                jnp.asarray(self.fire_at[rows]),
+                jnp.asarray(self.active[rows]),
+                jnp.asarray(self.rematch[rows]),
+                jnp.asarray(self.del_ts[rows]),
+            )
         self._rematch_pending = True
 
     def _invalidate_device(self) -> None:
@@ -461,22 +522,26 @@ class DeviceSimulator:
         if self._soa is not None:
             self._flush_pending()
         if self._soa is None:
-            self._soa = SoA(
-                features=jnp.asarray(self.features),
-                sig=jnp.asarray(self.sig),
-                ovc=jnp.asarray(self.ovc),
-                stage=jnp.asarray(self.stage),
-                fire_at=jnp.asarray(self.fire_at),
-                active=jnp.asarray(self.active),
-                rematch=jnp.asarray(self.rematch),
-                del_ts=jnp.asarray(self.del_ts),
-                now=self._dev_now if self._dev_now is not None else jnp.int32(0),
-                key=(
-                    self._dev_key
-                    if self._dev_key is not None
-                    else jax.random.PRNGKey(self._seed)
-                ),
-            )
+            # a first upload of a shape also compiles jnp.asarray's converts
+            with self._shapes.first_use(
+                "upload", self.features.shape, ("capacity",)
+            ):
+                self._soa = SoA(
+                    features=jnp.asarray(self.features),
+                    sig=jnp.asarray(self.sig),
+                    ovc=jnp.asarray(self.ovc),
+                    stage=jnp.asarray(self.stage),
+                    fire_at=jnp.asarray(self.fire_at),
+                    active=jnp.asarray(self.active),
+                    rematch=jnp.asarray(self.rematch),
+                    del_ts=jnp.asarray(self.del_ts),
+                    now=self._dev_now if self._dev_now is not None else jnp.int32(0),
+                    key=(
+                        self._dev_key
+                        if self._dev_key is not None
+                        else jax.random.PRNGKey(self._seed)
+                    ),
+                )
             self._rematch_pending = bool(self.rematch.any())
             if self.mesh is not None:
                 from kwok_tpu.parallel.mesh import place
@@ -519,6 +584,7 @@ class DeviceSimulator:
             for _ in range(n_ticks):
                 soa, out = self._tick_fn(dt_ms)(params, soa)
                 outs.append(np.asarray(out.fired_stage))
+            _TICKS.inc(n_ticks, self.kind)
             self._soa = soa
             stages_np = np.stack(outs) if outs else np.empty((0, 0), np.int32)
             self._now_host = t0_ms + dt_ms * n_ticks
@@ -544,7 +610,15 @@ class DeviceSimulator:
             self._rebase()
         t0_ms = self._now_host
         params, soa = self.to_device()
-        new_soa, stages = run_ticks_collect(params, soa, dt_ms, n_ticks)
+        # what the program is specialised on: the static arguments, the
+        # rows, and the stage tensors' shapes (eff_mode is [SIG, S, C],
+        # ov_w [OVC, S]: a new signature or override class grows them)
+        key = (n_ticks, self.capacity, params.eff_mode.shape + params.ov_w.shape, dt_ms)
+        with self._shapes.first_use(
+            "run_ticks_collect", key, ("num_ticks", "capacity", "signatures")
+        ):
+            new_soa, stages = run_ticks_collect(params, soa, dt_ms, n_ticks)
+        _TICKS.inc(n_ticks, self.kind)
         self._soa = new_soa
         self._now_host = t0_ms + dt_ms * n_ticks
         # pessimistic: fired rows are not visible until the fetch

@@ -233,6 +233,10 @@ class Server:
         # (VERDICT r04 missing-#5)
         r.add("GET", "/debug/pprof/profile", self._debug_profile)
         r.add("GET", "/debug/pprof/goroutine", self._debug_threads)
+        # the device's own profile (jax.profiler), where this process
+        # holds a device: the stage spans of utils/telemetry.stage lie
+        # in it beside the device's plane
+        r.add("POST", "/debug/device/trace", self._debug_device_trace)
 
     #: types set_configs accepts, for pre-validation in replace_configs
     _CONFIG_TYPES = (
@@ -440,6 +444,34 @@ class Server:
             )
         ]
         req.reply(200, "\n".join(lines) + "\n")
+
+    def _debug_device_trace(self, req: "_Request", **params) -> None:
+        """``POST /debug/device/trace?seconds=N&dir=<path>``: N seconds
+        of ``jax.profiler`` trace written under ``dir``, taken in this
+        request's thread; answers with the directory.  503 in a process
+        that never imported jax (the host backend): nothing to trace."""
+        jax = sys.modules.get("jax")
+        if jax is None:
+            req.reply(503, "this process runs no device backend\n")
+            return
+        out = (req.query.get("dir") or [""])[0]
+        try:
+            seconds = float((req.query.get("seconds") or ["2"])[0])
+        except (TypeError, ValueError):
+            seconds = -1.0
+        if not out or seconds < 0:
+            req.reply(400, "want ?seconds=N&dir=<path>\n")
+            return
+        try:
+            jax.profiler.start_trace(out)
+        except RuntimeError as exc:  # one session at a time, says jax
+            req.reply(409, f"{exc}\n")
+            return
+        try:
+            time.sleep(min(seconds, 60.0))
+        finally:
+            jax.profiler.stop_trace()
+        req.reply(200, out + "\n")
 
     def _discovery(self, req: "_Request", **params) -> None:
         targets = []

@@ -138,6 +138,15 @@ def device_info() -> Dict[str, object]:
     }
 
 
+def memory_stats() -> Dict[str, int]:
+    """``memory_stats()`` of the local device with the highest peak;
+    {} where the backend keeps none (XLA:CPU)."""
+    import jax
+
+    stats = [d.memory_stats() or {} for d in jax.local_devices()]
+    return max(stats, key=lambda s: s.get("peak_bytes_in_use") or 0, default={})
+
+
 def pinned_platforms() -> List[str]:
     """The platforms ``JAX_PLATFORMS`` names, in order; [] when unset."""
     return [

@@ -17,6 +17,11 @@ in-tree substrate every control-plane hot path observes into:
   :meth:`Telemetry.expose` to its existing exposition, so one scrape
   sees both the synthetic CR-driven metrics and the observed SLO
   series;
+- :class:`CounterFamily` — the monotone counterpart (shape changes,
+  device ticks), same label discipline, same registry;
+- :func:`stage` — the one span helper of the device tick threads: a
+  ``jax.profiler.TraceAnnotation`` on the profiler's clock plus an
+  observation into ``kwok_tick_stage_seconds``;
 - :class:`FlightRecorder` — a bounded in-memory ring of recent
   per-tick stage breakdowns and slow-request samples (each carrying
   its trace id as an exemplar), served at ``/debug/flightrecorder`` so
@@ -48,6 +53,7 @@ from __future__ import annotations
 
 import bisect
 import os
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -56,6 +62,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from kwok_tpu.utils.locks import make_lock
 
 __all__ = [
+    "CounterFamily",
     "DEFAULT_BUCKETS",
     "FlightRecorder",
     "HistogramFamily",
@@ -65,8 +72,11 @@ __all__ = [
     "flight_recorder",
     "histogram",
     "journey",
+    "counter",
     "registry",
     "set_enabled",
+    "stage",
+    "tick_stage_family",
 ]
 
 #: default latency bounds (seconds): sub-ms store appends up to
@@ -141,15 +151,14 @@ class HistogramFamily:
 
     # ------------------------------------------------------------- observe
 
-    def observe(self, value: float, *labelvalues: str) -> None:
+    def observe(self, value: float, *labelvalues: str, in_sum: float = 0.0) -> None:
         """Record one observation (seconds).  Extra/missing label
         values are normalized to the declared width so a bad call site
-        degrades to a visible mismatch, not a crash on the hot path."""
+        degrades to a visible mismatch, not a crash on the hot path.
+        ``in_sum`` is what :meth:`add_running` put into the sum already
+        while the observed thing was going on."""
         if not _STATE.enabled:
             return
-        lv = tuple(str(v) for v in labelvalues)
-        if len(lv) != len(self.labelnames):
-            lv = (lv + ("",) * len(self.labelnames))[: len(self.labelnames)]
         v = float(value)
         if v < 0.0:
             # monotonic races (ring eviction, clock source swap in
@@ -157,17 +166,31 @@ class HistogramFamily:
             v = 0.0
         idx = bisect.bisect_left(self.bounds, v)
         with self._mut:
-            child = self._children.get(lv)
-            if child is None:
-                if len(self._children) >= self.max_children:
-                    self.overflowed += 1
-                    lv = (_OTHER,) * len(self.labelnames) if self.labelnames else ()
-                    child = self._children.get(lv)
-                if child is None:
-                    child = self._children[lv] = _Child(len(self.bounds))
+            child = self._child_locked(labelvalues)
             child.counts[idx] += 1
-            child.sum += v
+            child.sum += v - in_sum
             child.count += 1
+
+    def add_running(self, seconds: float, *labelvalues: str) -> None:
+        """Seconds of something still going on, into the sum alone: a
+        scrape then sees the time of an operation that outlasts it, and
+        the observation that ends it passes the total as ``in_sum``."""
+        if not _STATE.enabled:
+            return
+        with self._mut:
+            self._child_locked(labelvalues).sum += seconds
+
+    def _child_locked(self, labelvalues) -> _Child:
+        lv = _label_values(self.labelnames, labelvalues)
+        child = self._children.get(lv)
+        if child is None:
+            if len(self._children) >= self.max_children:
+                self.overflowed += 1
+                lv = (_OTHER,) * len(self.labelnames) if self.labelnames else ()
+                child = self._children.get(lv)
+            if child is None:
+                child = self._children[lv] = _Child(len(self.bounds))
+        return child
 
     # ------------------------------------------------------------ querying
 
@@ -228,11 +251,7 @@ class HistogramFamily:
         """Prometheus text lines (HELP/TYPE + per-child bucket/sum/
         count), cumulative per le like any real histogram."""
         snap = self.snapshot()
-        lines: List[str] = []
-        if self.help:
-            esc = self.help.replace("\\", "\\\\").replace("\n", "\\n")
-            lines.append(f"# HELP {self.name} {esc}")
-        lines.append(f"# TYPE {self.name} histogram")
+        lines = _head_lines(self.name, self.help, "histogram")
         for lv in sorted(snap):
             data = snap[lv]
             base = ",".join(
@@ -252,6 +271,74 @@ class HistogramFamily:
             lines.append(f"{self.name}_sum{lab} {_fmt(data['sum'])}")
             lines.append(f"{self.name}_count{lab} {data['count']}")
         return lines
+
+
+class CounterFamily:
+    """A monotone counter with a fixed label-name set: the same label
+    discipline, child cap and lock scope as :class:`HistogramFamily`."""
+
+    def __init__(
+        self,
+        name: str,
+        help: str = "",
+        labelnames: Sequence[str] = (),
+        max_children: int = MAX_CHILDREN,
+    ):
+        self.name = name
+        self.help = (help or "").strip()
+        self.labelnames: Tuple[str, ...] = tuple(labelnames)
+        self.max_children = int(max_children)
+        self._mut = make_lock("utils.telemetry.CounterFamily._mut")
+        self._children: Dict[Tuple[str, ...], float] = {}
+
+    def inc(self, amount: float, *labelvalues: str) -> None:
+        if not _STATE.enabled:
+            return
+        lv = _label_values(self.labelnames, labelvalues)
+        with self._mut:
+            if lv not in self._children and len(self._children) >= self.max_children:
+                lv = (_OTHER,) * len(self.labelnames)
+            self._children[lv] = self._children.get(lv, 0) + amount
+
+    def snapshot(self) -> Dict[Tuple[str, ...], float]:
+        with self._mut:
+            return dict(self._children)
+
+    def total_count(self) -> int:
+        # a counter has no distribution to summarize (Telemetry.summary)
+        return 0
+
+    def clear(self) -> None:
+        with self._mut:
+            self._children.clear()
+
+    def expose_lines(self) -> List[str]:
+        snap = self.snapshot()
+        lines = _head_lines(self.name, self.help, "counter")
+        for lv in sorted(snap):
+            base = ",".join(
+                f'{k}="{_escape(v)}"' for k, v in zip(self.labelnames, lv)
+            )
+            lab = f"{{{base}}}" if base else ""
+            lines.append(f"{self.name}{lab} {_fmt(snap[lv])}")
+        return lines
+
+
+def _label_values(labelnames: Tuple[str, ...], values) -> Tuple[str, ...]:
+    """Label values as strings, padded or cut to the declared width."""
+    lv = tuple(str(v) for v in values)
+    if len(lv) != len(labelnames):
+        lv = (lv + ("",) * len(labelnames))[: len(labelnames)]
+    return lv
+
+
+def _head_lines(name: str, help: str, kind: str) -> List[str]:
+    lines: List[str] = []
+    if help:
+        esc = help.replace("\\", "\\\\").replace("\n", "\\n")
+        lines.append(f"# HELP {name} {esc}")
+    lines.append(f"# TYPE {name} {kind}")
+    return lines
 
 
 def _escape(v: str) -> str:
@@ -555,6 +642,7 @@ class Telemetry:
 
     def __init__(self):
         self._mut = make_lock("utils.telemetry.Telemetry._mut")
+        #: histogram and counter families alike, by series name
         self._families: Dict[str, HistogramFamily] = {}
         self.recorder = FlightRecorder()
         self.journey = JourneyRecorder()
@@ -581,6 +669,25 @@ class Telemetry:
                 )
             return fam
 
+    def counter(
+        self,
+        name: str,
+        help: str = "",
+        labelnames: Sequence[str] = (),
+        max_children: int = MAX_CHILDREN,
+    ) -> CounterFamily:
+        """Get-or-create, like :meth:`histogram`."""
+        with self._mut:
+            fam = self._families.get(name)
+            if fam is None:
+                fam = self._families[name] = CounterFamily(
+                    name,
+                    help=help,
+                    labelnames=labelnames,
+                    max_children=max_children,
+                )
+            return fam
+
     def families(self) -> List[HistogramFamily]:
         with self._mut:
             return list(self._families.values())
@@ -588,6 +695,7 @@ class Telemetry:
     def expose(self) -> str:
         """Prometheus text for every observed family (appended to the
         host process's existing /metrics exposition)."""
+        account_open_stages()
         lines: List[str] = []
         for fam in sorted(self.families(), key=lambda f: f.name):
             lines.extend(fam.expose_lines())
@@ -658,6 +766,18 @@ def histogram(
     )
 
 
+def counter(
+    name: str,
+    help: str = "",
+    labelnames: Sequence[str] = (),
+    max_children: int = MAX_CHILDREN,
+) -> CounterFamily:
+    """Shortcut onto the process-global registry."""
+    return _REGISTRY.counter(
+        name, help=help, labelnames=labelnames, max_children=max_children
+    )
+
+
 def flight_recorder() -> FlightRecorder:
     return _REGISTRY.recorder
 
@@ -676,3 +796,232 @@ def set_enabled(on: bool) -> bool:
 
 def enabled() -> bool:
     return _STATE.enabled
+
+
+# ------------------------------------------------------------------- stages
+
+#: the innermost open :func:`stage` of every thread, by thread ident:
+#: a stage's parent, and what a profiler session that starts finds open
+_TOPS: Dict[int, "stage"] = {}
+_TICK_STAGE: Optional[HistogramFamily] = None
+#: a stage closing against a scrape that accounts for the open ones
+_OPEN_MUT = make_lock("utils.telemetry._OPEN_MUT")
+#: ``jax.profiler.TraceAnnotation`` once this process is seen to have jax
+_ANNOTATION = None
+
+
+def tick_stage_family() -> HistogramFamily:
+    """``kwok_tick_stage_seconds``, registered by the first stage that
+    closes: a process with no device tick thread (the apiserver) exposes
+    no empty family."""
+    global _TICK_STAGE
+    if _TICK_STAGE is None:
+        _TICK_STAGE = histogram(
+            "kwok_tick_stage_seconds",
+            help="device tick thread seconds by stage (self time; compile "
+            "overlays the stage it stalls)",
+            labelnames=("kind", "stage"),
+        )
+    return _TICK_STAGE
+
+
+def _annotation():
+    """The annotation class if ``jax`` is among the imported modules (it is
+    never imported from here: the apiserver and ``kwokctl`` stay without)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        jax = sys.modules.get("jax")
+        try:
+            _ANNOTATION = jax.profiler.TraceAnnotation
+        except AttributeError:  # no jax, or one half imported
+            return None
+    return _ANNOTATION
+
+
+class stage:
+    """One stage of a device tick thread, timed once and shown twice.
+
+    ``with stage(kind, name) as sp:`` enters a
+    ``jax.profiler.TraceAnnotation`` named ``kwok/<kind>/<name>`` if this
+    process has imported ``jax`` already, so the span lies on the
+    ``/host:CPU`` plane of the profile that holds the device's plane, on
+    one clock; with no profiler session that is a flag test.  On exit
+    ``sp.elapsed`` holds the ``time.perf_counter()`` seconds (callers
+    feed their own accumulators from it: one clock per stage) and, while
+    :func:`enabled`, ``kwok_tick_stage_seconds{kind,stage}`` observes the
+    stage's self time: ``elapsed`` less the stages nested in it on this
+    thread.  An ``overlay`` stage (``compile``) is not taken from its
+    parent: the parent stays inclusive of it and a sum over all stages
+    subtracts the overlay.
+
+    A stage can last seconds (a bulk the apiserver is slow to answer),
+    longer than a profiler session and a tenth of a benchmark's window:
+    :class:`_SessionKeeper` keeps it in the trace of a session that it
+    outlasts, :func:`account_open_stages` in the sums a scrape reads.
+
+    Wrap batched operations only, never a row; ``kind`` and ``name``
+    come from bounded sets (a resource kind, a literal)."""
+
+    __slots__ = (
+        "kind", "name", "overlay", "elapsed", "nested",
+        "_t0", "_ann", "_parent", "_slice", "_in_sum",
+    )
+
+    def __init__(self, kind: str, name: str, overlay: bool = False):
+        self.kind = kind
+        self.name = name
+        self.overlay = overlay
+        self.elapsed = 0.0
+        #: seconds of the stages nested directly in this one, overlays apart
+        self.nested = 0.0
+        self._ann = None
+        #: the keeper's running slice of this stage, in a session
+        self._slice = None
+        #: self time a scrape has put into the family's sum already
+        self._in_sum = 0.0
+
+    @property
+    def span_name(self) -> str:
+        return f"kwok/{self.kind}/{self.name}"
+
+    def __enter__(self) -> "stage":
+        # the stage's own bookkeeping is timed with it: what lies
+        # between two stages of a loop is then the loop's alone
+        self._t0 = time.perf_counter()
+        ann = _annotation()
+        if ann is not None:
+            self._ann = ann(self.span_name)
+            self._ann.__enter__()
+            if _KEEPER.in_session:
+                # its first slice now, not at the keeper's next look: the
+                # session may end before this stage does
+                self._slice = ann(self.span_name)
+                self._slice.__enter__()
+            elif ann.is_enabled():
+                _KEEPER.session_seen(ann)
+        me = threading.get_ident()
+        self._parent = _TOPS.get(me)
+        _TOPS[me] = self
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        parent = self._parent
+        me = threading.get_ident()
+        with _OPEN_MUT:
+            # against a scrape or the keeper walking the open stages
+            if parent is None:
+                _TOPS.pop(me, None)
+            else:
+                _TOPS[me] = parent
+            running, self._slice = self._slice, None
+            self.elapsed = time.perf_counter() - self._t0
+            if parent is not None and not self.overlay:
+                parent.nested += self.elapsed
+        if running is not None:
+            running.__exit__(None, None, None)
+        if _STATE.enabled:
+            tick_stage_family().observe(
+                self.elapsed - self.nested, self.kind, self.name, in_sum=self._in_sum
+            )
+
+
+def _open_stages():
+    """Every open stage of every thread, each thread's innermost first.
+    Call with ``_OPEN_MUT`` held: no stage closes meanwhile."""
+    for top in list(_TOPS.values()):
+        st = top
+        while st is not None:
+            yield st
+            st = st._parent
+
+
+def account_open_stages() -> None:
+    """Put the self time so far of every open stage into
+    ``kwok_tick_stage_seconds``' sums (every ``/metrics`` scrape does):
+    a stage is observed as it ends, and one that lasts seconds would
+    else move whole from one side of a scrape to the other."""
+    if not _STATE.enabled or not _TOPS:
+        return
+    fam = tick_stage_family()
+    with _OPEN_MUT:
+        now = time.perf_counter()
+        inner = None  # the stage nested in the next one of the walk
+        for st in _open_stages():
+            so_far = now - st._t0
+            own = so_far - st.nested
+            if inner is not None and inner._parent is st and not inner.overlay:
+                own -= now - inner._t0
+            fam.add_running(own - st._in_sum, st.kind, st.name)
+            st._in_sum = own
+            inner = st
+
+
+class _SessionKeeper:
+    """Keeps the stages that outlast a profiler session in its trace.
+
+    The profiler records an annotation only if it saw it open *and*
+    close.  The Pod player of a loaded daemon sits in one ``store_bulk``
+    for seconds; a session of two seconds that opens and closes inside
+    it would hold no span of that thread at all.  So while a session is
+    on, one thread cuts every open stage of every thread into slices:
+    each ``SLICE_S`` it closes the stage's running annotation and opens
+    the next, under the stage's own name, and the stage's own thread
+    closes the last one as the stage ends (an annotation may be stopped
+    from another thread; it lands on the line of the thread that stops
+    it).  The slices tile a stage from its start, or from the keeper's
+    first look if it was open before, to its end or the session's.
+
+    Nothing polls outside a session: the thread sleeps on an event that
+    the first stage to open under a session sets (a flag test per
+    stage), and goes back to it when the session ends."""
+
+    SLICE_S = 0.05
+
+    def __init__(self):
+        self.in_session = False
+        self._wake = threading.Event()
+        self._thread: Optional[threading.Thread] = None
+        self._ann = None
+
+    def session_seen(self, ann) -> None:
+        self._ann = ann
+        with _OPEN_MUT:
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="kwok-stage-keeper", daemon=True
+                )
+                self._thread.start()
+        self._wake.set()
+
+    def _cut(self, restart: bool) -> None:
+        """Close every open stage's running slice and, with ``restart``,
+        open its next."""
+        done = []
+        with _OPEN_MUT:
+            for st in _open_stages():
+                if st._slice is not None:
+                    done.append(st._slice)
+                    st._slice = None
+                if restart:
+                    st._slice = self._ann(st.span_name)
+                    st._slice.__enter__()
+        for running in done:
+            running.__exit__(None, None, None)
+
+    def _run(self) -> None:
+        while True:
+            self._wake.wait()
+            self._wake.clear()
+            self.in_session = True
+            try:
+                while self._ann.is_enabled():
+                    self._cut(restart=True)
+                    time.sleep(self.SLICE_S)
+            finally:
+                self.in_session = False
+                self._cut(restart=False)
+
+
+_KEEPER = _SessionKeeper()

@@ -484,8 +484,11 @@ def linked_trace_ids(spans: List[dict]) -> set:
 
 #: attribution priority when spans overlap: the innermost work wins
 #: the instant (an apiserver PATCH nested inside a bind span is commit
-#: work; the remainder of the bind is scheduling work)
-_ATTRIBUTION_PRIORITY = ("commit", "sched", "stage", "client", "other")
+#: work; the remainder of the bind is scheduling work).  ``stage`` is
+#: over ``sched``: the two never nest, and a play span that opens while
+#: a bind span is still open (the scheduler's tail after its PATCH
+#: committed, in another process) is the work downstream of it
+_ATTRIBUTION_PRIORITY = ("commit", "stage", "sched", "client", "other")
 
 
 def build_journey(spans: List[dict]) -> dict:
@@ -502,7 +505,7 @@ def build_journey(spans: List[dict]) -> dict:
     consumer-pickup: delivery lag plus consumer queueing and stage
     delays), ``sched``/``stage``/``client`` the respective spans' own
     busy time with nested-span instants going to the innermost work
-    (priority commit > sched > stage > client)."""
+    (priority commit > stage > sched > client)."""
     spans = [s for s in spans if _span_ns(s, "startTimeUnixNano") > 0]
     spans.sort(key=lambda s: _span_ns(s, "startTimeUnixNano"))
     if not spans:
@@ -553,14 +556,16 @@ def build_journey(spans: List[dict]) -> dict:
             breakdown["watch"] += seg
     total_s = (t_end - t0) / 1e9
     queue_s = min(queue_s, breakdown["commit"])
-    breakdown["queue"] = round(queue_s, 6)
-    breakdown["commit"] = round(breakdown["commit"] - queue_s, 6)
+    breakdown["queue"] = queue_s
+    breakdown["commit"] -= queue_s
+    # to the nanosecond the spans are stamped in, not the microsecond:
+    # seven terms rounded apart would miss the total by microseconds
     for st in breakdown:
-        breakdown[st] = round(breakdown[st], 6)
+        breakdown[st] = round(breakdown[st], 9)
     return {
         "hops": hops,
         "breakdown_s": breakdown,
-        "total_s": round(total_s, 6),
+        "total_s": round(total_s, 9),
         "t0_ns": t0,
     }
 

@@ -286,6 +286,14 @@ def test_one_trace_from_create_through_bind(collector):
         try:
             client = ClusterClient(srv.url)
             client.create(_node(0))
+            # the commit ring carries a write's context only while a
+            # watcher exists: a pod created before the scheduler's watch
+            # is open reaches it through its LIST, with no trace to
+            # continue (seen under -n 6, where the watch opens late)
+            assert _wait(lambda: bool(store._state("Pod").watchers), 20.0)
+            # and a pod that arrives before the node is refused (an Event,
+            # no PATCH) and bound two seconds later under a trace of its own
+            assert _wait(lambda: len(sched._nodes) >= 1, 20.0)
             with tracer.span("client.create-pod") as sp:
                 client.create(_pod("journeyed"))
                 trace_id = sp.trace_id
@@ -297,7 +305,17 @@ def test_one_trace_from_create_through_bind(collector):
             assert _wait(bound, 20.0), "pod never bound"
         finally:
             sched.stop()
-    tracer.flush()
+    # the apiserver's span closes after the response is written, so a
+    # pod read as bound may have its PATCH span still open in the
+    # handler thread: flush until it has been exported
+    def exported():
+        tracer.flush()
+        got = TraceStore.get(cstore, trace_id)
+        return got is not None and "apiserver.PATCH" in {
+            s["name"] for s in got["spans"]
+        }
+
+    _wait(exported, 10.0)
     tr = TraceStore.get(cstore, trace_id)
     assert tr is not None
     names = sorted(s["name"] for s in tr["spans"])

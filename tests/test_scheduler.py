@@ -129,7 +129,9 @@ def test_respects_pod_count_cap(sched_store):
     store.create(make_node("tiny", pods="2"))
     for i in range(3):
         store.create(make_pod(f"p{i}"))
-    time.sleep(1.0)
+    # under co-load the scheduler may need more than a fixed second
+    wait_until(lambda: sum(1 for n in bound_nodes(store).values() if n == "tiny") >= 2)
+    time.sleep(0.5)  # and the third must stay unbound
     nodes = bound_nodes(store)
     assert sum(1 for n in nodes.values() if n == "tiny") == 2
     assert sum(1 for n in nodes.values() if n is None) == 1

@@ -458,6 +458,53 @@ def test_debug_profile_samples_all_threads(server):
         assert "serve_forever" not in text
 
 
+def test_debug_device_trace_writes_a_profile(server, tmp_path):
+    """POST /debug/device/trace?seconds=N&dir=<path>: a jax.profiler
+    trace from inside the process that holds the device, with the tick
+    threads' stage annotations in it."""
+    import glob
+    import os
+    import urllib.parse
+
+    import jax  # noqa: F401 — the process "runs a device backend"
+
+    from kwok_tpu.utils import telemetry
+
+    _, port = server
+    assert get(port, "/debug/device/trace?seconds=0.1", method="POST")[0] == 400
+    assert get(port, "/debug/device/trace?seconds=x&dir=/tmp/t", method="POST")[0] == 400
+    stop = threading.Event()
+
+    def staged():
+        while not stop.is_set():
+            with telemetry.stage("TSrv", "device_tick"):
+                time.sleep(0.005)
+
+    t = threading.Thread(target=staged, daemon=True)
+    t.start()
+    out = str(tmp_path / "prof")
+    try:
+        status, data = get(
+            port,
+            "/debug/device/trace?seconds=0.2&dir=" + urllib.parse.quote(out),
+            method="POST",
+        )
+    finally:
+        stop.set()
+        t.join()
+    assert status == 200 and data.decode().strip() == out
+    (path,) = glob.glob(os.path.join(out, "**", "*.xplane.pb"), recursive=True)
+    from jax.profiler import ProfileData
+
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(path).planes
+        for line in plane.lines
+        for ev in line.events
+    }
+    assert "kwok/TSrv/device_tick" in names
+
+
 def test_debug_pprof_goroutine_alias(server):
     _, port = server
     status, data = get(port, "/debug/pprof/goroutine")

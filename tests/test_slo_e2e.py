@@ -30,7 +30,11 @@ FAMILIES = (
     "kwok_scheduler_bind_seconds",
     "kwok_gang_admit_seconds",
     "kwok_tick_stage_seconds",
+    "kwok_lease_renew_delay_seconds",
 )
+#: and on the apiserver daemon's own /metrics (its save loop is
+#: cmd/apiserver.py's, so a real process serves it)
+APISERVER_DAEMON_FAMILIES = ("kwok_apiserver_save_seconds",)
 
 
 def _node(i, topo):
@@ -180,6 +184,21 @@ def test_metrics_serves_every_observed_family(cluster):
             break
     assert fired > 0, "device player never fired a transition"
 
+    # --- lease renewals over the lane: fire time to the write's return
+    from kwok_tpu.controllers.device_lease import DeviceLeaseLane
+    from kwok_tpu.controllers.node_lease_controller import NodeLeaseController
+
+    leases = NodeLeaseController(store, "slo-e2e", lease_duration_seconds=40)
+    lane = DeviceLeaseLane(leases, capacity=16)
+    leases.attach_device_lane(lane)
+    leases.start()
+    try:
+        leases.try_hold("node-0")
+        assert _wait(lambda: len(lane) == 1), "lease not handed to the lane"
+        assert lane.tick(lane.renew_ms + 100) == 1
+    finally:
+        leases.stop()
+
     # --- the scrape: every family present with nonzero counts
     text = urllib.request.urlopen(url + "/metrics", timeout=10).read().decode()
     counts = _family_counts(text)
@@ -273,3 +292,42 @@ def test_flightrecorder_and_stats_latency(cluster):
     req_row = lat.get("kwok_apiserver_request_duration_seconds")
     assert req_row and req_row["count"] >= 1
     assert "p99_s" in req_row and "p50_s" in req_row
+
+
+def test_apiserver_daemon_serves_its_own_families(tmp_path):
+    """The families only ``python -m kwok_tpu.cmd.apiserver`` observes:
+    its periodic save, one observation a save."""
+    import os
+    import subprocess
+    import sys
+
+    from kwok_tpu.cluster.client import ClusterClient
+    from kwok_tpu.ctl.components import free_port
+
+    port = free_port()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.Popen(
+        [sys.executable, "-m", "kwok_tpu.cmd.apiserver", "--port", str(port),
+         "--state-file", str(tmp_path / "state.json"), "--save-interval", "0.2"],
+        stdout=open(tmp_path / "apiserver.log", "ab"), stderr=subprocess.STDOUT,
+        env={**os.environ, "PYTHONPATH": root}, start_new_session=True,
+    )
+    try:
+        client = ClusterClient(f"http://127.0.0.1:{port}")
+        assert client.wait_ready(30)
+        client.create(_pod("saved"))
+
+        def counts():
+            text = urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/metrics", timeout=10).read().decode()
+            return _family_counts(text), text
+
+        assert _wait(lambda: all(counts()[0].get(f, 0) > 0
+                                 for f in APISERVER_DAEMON_FAMILIES)), counts()[0]
+        text = counts()[1]
+        assert "# TYPE kwok_apiserver_save_seconds histogram" in text
+        # a process with no device tick thread exposes no empty stage family
+        assert "kwok_tick_stage_seconds" not in text
+    finally:
+        proc.terminate()
+        proc.wait(timeout=20)

@@ -423,3 +423,231 @@ def test_standby_gang_engine_drops_admit_anchor_on_bound_echo():
     assert key in engine._gang_seen  # one member still pending
     engine.observe("MODIFIED", member("b", node="n1"))
     assert key not in engine._gang_seen
+
+
+# ------------------------------------------------- CounterFamily / stage
+
+
+def test_counter_family_counts_exposes_and_disarms():
+    reg = Telemetry()
+    c = reg.counter("t_shapes_total", help="h", labelnames=("program", "cause"))
+    assert reg.counter("t_shapes_total") is c
+    c.inc(1, "tick", "first")
+    c.inc(2, "tick", "first")
+    c.inc(1, "scatter", "capacity")
+    prev = telemetry.set_enabled(False)
+    try:
+        c.inc(5, "tick", "first")
+    finally:
+        telemetry.set_enabled(prev)
+    assert c.snapshot() == {("tick", "first"): 3, ("scatter", "capacity"): 1}
+    text = reg.expose()
+    assert "# TYPE t_shapes_total counter" in text
+    assert 't_shapes_total{program="tick",cause="first"} 3' in text
+    assert "t_shapes_total" not in reg.summary()
+    reg.reset()
+    assert c.snapshot() == {}
+
+
+def _stage_sums(kind):
+    fam = telemetry.tick_stage_family()
+    return {
+        lv[1]: (d["sum"], d["count"])
+        for lv, d in fam.snapshot().items()
+        if lv[0] == kind
+    }
+
+
+def test_stage_observes_self_time_and_nests():
+    import time
+
+    with telemetry.stage("TStage", "outer") as outer:
+        time.sleep(0.02)
+        with telemetry.stage("TStage", "inner") as inner:
+            time.sleep(0.03)
+        # an overlay (compile) is not taken from the stage it stalls
+        with telemetry.stage("TStage", "compile", overlay=True) as comp:
+            time.sleep(0.01)
+    got = _stage_sums("TStage")
+    assert {k: n for k, (_s, n) in got.items()} == {"outer": 1, "inner": 1, "compile": 1}
+    assert inner.elapsed >= 0.03 and comp.elapsed >= 0.01
+    assert outer.elapsed >= inner.elapsed + comp.elapsed + 0.02
+    assert outer.nested == pytest.approx(inner.elapsed)
+    assert got["inner"][0] == pytest.approx(inner.elapsed)
+    assert got["outer"][0] == pytest.approx(outer.elapsed - inner.elapsed)
+    # every instant once: the stages less the overlay make the wall time
+    total = sum(s for s, _n in got.values()) - got["compile"][0]
+    assert total == pytest.approx(outer.elapsed)
+    # stages of another thread do not nest in this one's
+    with telemetry.stage("TStage", "outer") as again:
+        def elsewhere():
+            with telemetry.stage("TStage", "inner"):
+                pass
+
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join()
+    assert again.nested == 0.0
+
+
+def test_stage_disabled_keeps_its_clock_and_observes_nothing():
+    before = _stage_sums("TOff")
+    prev = telemetry.set_enabled(False)
+    try:
+        with telemetry.stage("TOff", "x") as sp:
+            pass
+    finally:
+        telemetry.set_enabled(prev)
+    # the accumulators the callers feed from ``elapsed`` go on; the
+    # family saw one attribute check and no observation
+    assert sp.elapsed > 0.0
+    assert _stage_sums("TOff") == before == {}
+
+
+@pytest.mark.parametrize(
+    "code",
+    [
+        "from kwok_tpu.utils import telemetry\n"
+        "with telemetry.stage('Pod', 'ingest') as sp: pass\n"
+        "assert sp.elapsed > 0",
+        "import kwok_tpu.cmd.apiserver",
+        "import kwok_tpu.cmd.kwokctl",
+        "import runpy; runpy.run_path('benchmarks/run.py', run_name='bench_run')",
+    ],
+    ids=["stage", "apiserver", "kwokctl", "benchmarks_run"],
+)
+def test_no_jax_in_a_process_that_had_none(code):
+    """``stage`` looks jax up in ``sys.modules`` and never imports it:
+    the apiserver, kwokctl and the benchmark's parent stay without."""
+    import os
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, "-c", code + "\nimport sys\nassert 'jax' not in sys.modules, 'jax imported'"],
+        cwd=root, env={**os.environ, "PYTHONPATH": root},
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_stage_annotates_on_the_profilers_clock(tmp_path):
+    """With jax imported and a profiler session on, a stage is an event
+    ``kwok/<kind>/<name>`` of the host plane in the ``.xplane.pb``."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.stage("TProf", "device_tick"):
+            jax.numpy.zeros(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(path).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+    }
+    assert "kwok/TProf/device_tick" in names
+
+
+def test_a_stage_that_outlasts_a_profiler_session_is_in_its_trace(tmp_path):
+    """The profiler drops an annotation it did not see open and close,
+    and a bulk of seconds outlasts a trace of two: while a session is on,
+    the keeper cuts the open stages into slices under their own names.
+    Another thread's stages (the Node player's, at tick cadence) are what
+    notices the session; nothing polls outside one."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+
+    stop = threading.Event()
+
+    def ticking():
+        while not stop.is_set():
+            with telemetry.stage("TKeepNode", "pace_wait"):
+                time.sleep(0.01)
+
+    def blocked():
+        with telemetry.stage("TKeepPod", "host_drain"):
+            with telemetry.stage("TKeepPod", "store_bulk"):
+                stop.wait(10.0)
+
+    threads = [threading.Thread(target=f) for f in (ticking, blocked)]
+    for t in threads:
+        t.start()
+    try:
+        time.sleep(0.1)
+        assert not telemetry._KEEPER.in_session
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            time.sleep(0.5)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        stop.set()
+        for t in threads:
+            t.join()
+    deadline = time.monotonic() + 5
+    while telemetry._KEEPER.in_session and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert not telemetry._KEEPER.in_session  # asleep again
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seconds = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("kwok/TKeepPod/"):
+                    seconds[ev.name] = seconds.get(ev.name, 0.0) + ev.duration_ns / 1e9
+    # both stages of the blocked thread's chain, for most of the session
+    assert set(seconds) == {"kwok/TKeepPod/host_drain", "kwok/TKeepPod/store_bulk"}
+    assert all(0.3 <= s <= 0.6 for s in seconds.values()), seconds
+
+
+def test_a_scrape_sees_the_time_of_a_stage_still_open():
+    """A stage is observed as it ends; one that lasts seconds would move
+    whole from one side of a scrape to the other.  Every exposition puts
+    the open stages' self time so far into the sums, and the observation
+    that ends a stage adds only the rest."""
+    import time
+
+    entered, leave = threading.Event(), threading.Event()
+    spans = {}
+
+    def blocked():
+        with telemetry.stage("TOpen", "host_drain") as spans["outer"]:
+            time.sleep(0.02)
+            with telemetry.stage("TOpen", "store_bulk") as spans["inner"]:
+                entered.set()
+                leave.wait(10.0)
+
+    t = threading.Thread(target=blocked)
+    t.start()
+    try:
+        assert entered.wait(5.0)
+        time.sleep(0.05)
+        telemetry.registry().expose()  # a scrape
+        first = _stage_sums("TOpen")
+        assert first["store_bulk"][0] >= 0.05 and first["store_bulk"][1] == 0
+        assert 0.02 <= first["host_drain"][0] < 0.05 and first["host_drain"][1] == 0
+        time.sleep(0.05)
+        telemetry.registry().expose()
+        second = _stage_sums("TOpen")
+        assert second["store_bulk"][0] >= first["store_bulk"][0] + 0.05
+        # the outer stage's self time does not grow while the inner one runs
+        assert second["host_drain"][0] == pytest.approx(first["host_drain"][0], abs=0.005)
+    finally:
+        leave.set()
+        t.join()
+    done = _stage_sums("TOpen")
+    outer, inner = spans["outer"], spans["inner"]
+    assert done["store_bulk"] == (pytest.approx(inner.elapsed), 1)
+    assert done["host_drain"] == (pytest.approx(outer.elapsed - inner.elapsed), 1)

@@ -1,0 +1,202 @@
+"""The device tick threads account for themselves (ISSUE 26): every
+instant of ``DeviceStagePlayer``'s loop lies in one stage of
+``kwok_tick_stage_seconds`` (utils/telemetry.stage), a program shape
+used for the first time is counted with what made it new, the lease
+lane times a renewal to the return of its write (the apiserver's save
+histogram is in test_slo_e2e.py, with the other served families)."""
+
+import time
+
+import pytest
+
+from kwok_tpu.cluster.store import ResourceStore
+from kwok_tpu.controllers.device_player import DeviceStagePlayer
+from kwok_tpu.engine import simulator
+from kwok_tpu.stages import load_builtin
+from kwok_tpu.utils import telemetry
+from kwok_tpu.utils.clock import FakeClock
+
+NEW_STAGES = ("ingest", "compile", "post_tick", "pace_wait")
+
+
+def make_pod(name):
+    return {
+        "apiVersion": "v1",
+        "kind": "Pod",
+        "metadata": {"name": name, "namespace": "default", "uid": f"uid-{name}"},
+        "spec": {"nodeName": "node-0", "containers": [{"name": "app", "image": "x"}]},
+        "status": {},
+    }
+
+
+def make_player(store, capacity, clock=None):
+    from kwok_tpu.controllers.pod_controller import PodEnv
+
+    env = PodEnv()
+    return DeviceStagePlayer(
+        store, "Pod", load_builtin("pod-fast"), capacity=capacity, tick_ms=20,
+        clock=clock, funcs_for=env.funcs, on_delete=env.release,
+    )
+
+
+def stage_table(kind="Pod"):
+    fam = telemetry.tick_stage_family()
+    return {lv[1]: (d["sum"], d["count"]) for lv, d in fam.snapshot().items() if lv[0] == kind}
+
+
+def shapes(kind="Pod"):
+    fam = telemetry.registry().counter("kwok_device_new_shapes_total")
+    return {lv[1:]: n for lv, n in fam.snapshot().items() if lv[0] == kind}
+
+
+def ticks_total():
+    return telemetry.registry().counter("kwok_device_ticks_total").snapshot().get(("Pod",), 0)
+
+
+@pytest.fixture(autouse=True)
+def fresh():
+    """Shape keys are remembered for the process, as the jit cache is,
+    and the series are the process's: a test that counts first uses and
+    sums stages starts from none."""
+    saved = {k: set(v) for k, v in simulator.ShapeLog._seen.items()}
+    simulator.ShapeLog._seen.clear()
+    reg = telemetry.registry()
+    telemetry.tick_stage_family().clear()
+    reg.counter("kwok_device_new_shapes_total").clear()
+    yield
+    simulator.ShapeLog._seen.clear()
+    simulator.ShapeLog._seen.update(saved)
+
+
+@pytest.mark.parametrize("paced", [True, False], ids=["paced", "unpaced"])
+def test_the_stages_of_the_tick_thread_make_its_wall_time(paced):
+    """The clock is injected: a paced loop waits for the test to
+    advance it."""
+    store = ResourceStore()
+    clock = FakeClock(1000.0)
+    player = make_player(store, capacity=16, clock=clock)
+    posts = []
+    player.post_tick = posts.append
+    def waited():
+        return stage_table().get("pace_wait", (0.0, 0))[1]
+
+    t0 = time.perf_counter()
+    player.start(paced=paced)
+    try:
+        for i in range(40):
+            store.create(make_pod(f"pod-{i}"))
+        # virtual time at a quarter of real time: once the first
+        # programs have compiled, a paced loop is ahead of its schedule
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline and (
+            player.transitions < 40 or (paced and waited() < 3)
+        ):
+            time.sleep(0.01)
+            clock.advance(0.0025)
+    finally:
+        player._done.set()
+        clock.advance(0.02)  # wake a paced wait
+        player.stop()
+    wall = time.perf_counter() - t0
+    assert player.transitions >= 40 and posts
+    table = stage_table()
+    want = set(NEW_STAGES) | {"device_tick", "host_drain", "host_build", "store_bulk"}
+    if not paced:
+        want.discard("pace_wait")
+    assert want <= {k for k, (_s, n) in table.items() if n > 0}, table
+    # every stage reports self time but that compile overlays the stage
+    # it stalls: the sum less the overlay is the thread's wall time
+    total = sum(s for s, _n in table.values()) - table["compile"][0]
+    assert total == pytest.approx(wall, rel=0.05), (table, wall)
+    # and the accumulators bench.py reads are fed from the same clocks
+    assert player.t_device == pytest.approx(table["device_tick"][0])
+    assert player.t_store == pytest.approx(table["store_bulk"][0])
+    assert player.t_build == pytest.approx(table["host_build"][0])
+    assert player.t_host - player.t_build == pytest.approx(table["host_drain"][0])
+
+
+@pytest.mark.parametrize("cause", ["num_ticks", "capacity"])
+def test_a_new_shape_is_counted_once_with_its_cause(cause):
+    store = ResourceStore()
+    player = make_player(store, capacity=8)
+    sim = player.sim
+    for i in range(4):
+        sim.admit(make_pod(f"pod-{i}"))
+    player.step_batch(20, 1)
+    first = shapes()
+    assert first == {("upload", "first"): 1, ("run_ticks_collect", "first"): 1}
+    ticks0 = ticks_total()
+    if cause == "num_ticks":
+        player.step_batch(20, 3)
+        ticked = 3
+    else:
+        for i in range(4, 12):  # past 8 rows: the SoA doubles
+            sim.admit(make_pod(f"pod-{i}"))
+        assert sim.capacity == 64
+        player.step_batch(20, 1)
+        ticked = 1
+    grown = {k: n - first.get(k, 0) for k, n in shapes().items() if n != first.get(k, 0)}
+    want = {("run_ticks_collect", cause): 1}
+    if cause == "capacity":
+        want[("upload", "capacity")] = 1
+        # the rows admitted before the doubling reach the device first,
+        # so that the download before the re-upload does not lose them
+        want[("scatter_rows", "first")] = 1
+    assert grown == want
+    assert stage_table()["compile"][1] == 2 + len(want)
+    # a repeat of either shape is no new shape and no compile stage
+    before = (shapes(), stage_table()["compile"][1])
+    player.step_batch(20, 3 if cause == "num_ticks" else 1)
+    assert (shapes(), stage_table()["compile"][1]) == before
+    assert ticks_total() - ticks0 == 2 * ticked
+
+
+def test_a_scatter_of_a_new_width_names_its_cause():
+    player = make_player(ResourceStore(), capacity=64)
+    sim = player.sim
+    sim.admit(make_pod("pod-0"))
+    player.step_batch(20, 1)
+    for width in (1, 3, 3):  # padded to 1, 4, 4 rows
+        for i in range(width):
+            sim.admit(make_pod(f"pod-{width}-{i}-{time.monotonic_ns()}"))
+        player.step_batch(20, 1)
+    got = shapes()
+    assert got[("scatter_rows", "first")] == 1
+    assert got[("scatter_rows", "scatter_width")] == 1
+
+
+def test_lease_delay_runs_to_the_return_of_the_write():
+    from kwok_tpu.controllers.device_lease import DeviceLeaseLane
+
+    class SlowCtrl:
+        renew_interval = 10.0
+        renew_jitter = 0.04
+
+        def renew_batch(self, names):
+            time.sleep(0.05)
+            return [n for n in names if n == "lost"]
+
+        def reacquire(self, name):
+            pass
+
+    fam = telemetry.registry().histogram("kwok_lease_renew_delay_seconds")
+    before = fam.snapshot().get((), {"sum": 0.0, "count": 0})
+    lane = DeviceLeaseLane(SlowCtrl(), capacity=16)
+    for name in ("a", "b", "lost"):
+        lane.register(name)
+    assert lane.tick(10_000 + 300) == 2  # 0.3 s past the scheduled time
+    after = fam.snapshot()[()]
+    # the two renewed leases: the lane's lag plus the write's round trip
+    assert after["count"] - before["count"] == 2
+    mean = (after["sum"] - before["sum"]) / 2
+    assert 0.3 + 0.05 <= mean < 0.3 + 0.05 + 5.0
+    assert list(lane.renew_lags) == pytest.approx([0.3] * 3)
+    assert shapes("Node").get(("lease_tick", "first")) == 1
+    assert stage_table("Node")["compile"][1] >= 1
+
+
+def test_memory_stats_of_a_backend_that_keeps_none():
+    from kwok_tpu.utils import accel
+
+    # XLA:CPU has no memory_stats: the gauge is then absent, not 0
+    assert accel.memory_stats() == {}
