@@ -32,6 +32,13 @@ REST surface (kind-keyed rather than group/version-keyed; our
 - ``GET/PUT/PATCH/DELETE /r/{plural}/{name}``     single object; query
   params ``namespace`` ``subresource``; PATCH type from Content-Type
   (application/{merge-patch,json-patch,strategic-merge-patch}+json)
+- ``POST /bulk``, ``POST /txn``            many mutations in one
+  round trip (``ResourceStore.bulk``; all-or-nothing ``transact``)
+- ``POST /status-batch``                   ``{kind, items}``, items
+  ``[namespace, name, status(, resourceVersion)]``: the columnar status
+  commit (``ResourceStore.apply_status_batch``) a device player drains
+  its fired rows through; answers ``{"rvs": [...]}``, one number an item
+  (the new resourceVersion, 0 not found, -1 refused as stale), no object
 - ``GET  /stats``                          resourceVersion + counts
 
 Impersonation rides the ``Impersonate-User`` header (reference
@@ -119,6 +126,7 @@ _ROUTE_HEADS = frozenset(
         "apis",
         "bulk",
         "txn",
+        "status-batch",
         "shards",
         "state",
         "stats",
@@ -148,6 +156,28 @@ def _route_kind(head: str, rest: list) -> str:
             return parts[2] if len(parts) >= 3 else "namespaces"
         return parts[0]
     return head
+
+
+def _status_items(raw) -> list:
+    """A ``/status-batch`` body's items as the tuples the store's
+    committers index without looking: ``[namespace, name, status]`` or
+    ``[namespace, name, status, resourceVersion]``."""
+    items = []
+    for it in raw or []:
+        if not (
+            isinstance(it, list)
+            and len(it) in (3, 4)
+            and (it[0] is None or isinstance(it[0], str))
+            and isinstance(it[1], str)
+            and isinstance(it[2], dict)
+            and (len(it) == 3 or it[3] is None or isinstance(it[3], str))
+        ):
+            raise ValueError(
+                "a status-batch item is [namespace, name, status] or "
+                "[namespace, name, status, resourceVersion]"
+            )
+        items.append(tuple(it))
+    return items
 
 
 def _traced(fn):
@@ -815,6 +845,24 @@ class _Handler(BaseHTTPRequestHandler):
                     (body or {}).get("ops") or [], as_user=self._user()
                 )
                 self._send_json(200, {"results": results})
+            elif head == "status-batch" and self._tenant is None:
+                # the columnar sibling of a /bulk of status patches
+                # (module docstring); inside _dispatch like /bulk.  A
+                # tenant's slice has no such lane: its proxy would hand
+                # the host's namespaces through unmapped
+                body = body or {}
+                results = self.store.apply_status_batch(
+                    body.get("kind") or "", _status_items(body.get("items"))
+                )
+                self._send_json(
+                    200,
+                    {
+                        "rvs": [
+                            r[0] if r else (0 if r is None else -1)
+                            for r in results
+                        ]
+                    },
+                )
             elif head == "shards" and len(rest) == 2 and rest[1] in ("bulk", "txn"):
                 # per-shard direct-dispatch lanes (KUBEDIRECT shape,
                 # kwok_tpu/cluster/sharding/dispatch.py): the caller

@@ -888,6 +888,31 @@ class ClusterClient:
         )
         return data.get("results", [])
 
+    def apply_status_batch(self, kind: str, items, exclude=None) -> list:
+        """The columnar status commit across the wire (``POST
+        /status-batch``; see ResourceStore.apply_status_batch): one
+        request replaces the ``status`` of every item ``(namespace,
+        name, status[, resourceVersion])`` in one locked pass of the
+        server's store, with one WAL record and one burst of watch
+        events.  Results align with items, in the in-process shape:
+        ``(resourceVersion, None)`` for a committed row (no object is
+        echoed: the sender has the object, the status it sent, and now
+        the resourceVersion), None where the object does not exist,
+        False where the server refused the row because the object is
+        not at the resourceVersion the item named.
+
+        ``exclude`` is accepted for the in-process signature and
+        ignored: the server does not know which of its watch streams is
+        the caller's, so the caller still receives its own echoes and
+        drops them by resourceVersion."""
+        data = self._request(
+            "POST", "/status-batch", body={"kind": kind, "items": items}
+        )
+        return [
+            (rv, None) if rv > 0 else (None if rv == 0 else False)
+            for rv in data["rvs"]
+        ]
+
     # --------------------------------------------------------------- watch
 
     def watch(

@@ -833,9 +833,9 @@ class ShardedStore:
     def apply_status_batch(
         self,
         kind: str,
-        items: List[Tuple[Optional[str], str, dict]],
+        items: List[tuple],
         exclude=None,
-    ) -> List[Optional[Tuple[int, dict]]]:
+    ) -> list:
         rt = self._rtype(kind)
         n = len(self._shards)
         if not rt.namespaced or n == 1:
@@ -847,7 +847,7 @@ class ShardedStore:
         for i, item in enumerate(items):
             shard = shard_of(True, rt.kind, item[0], n)
             groups.setdefault(shard, []).append((i, item))
-        results: List[Optional[Tuple[int, dict]]] = [None] * len(items)
+        results: list = [None] * len(items)
         for shard in sorted(groups):
             pairs = groups[shard]
             out = self._shards[shard].apply_status_batch(

@@ -53,6 +53,26 @@ _LOG = get_logger("device-player")
 #: time), compile (engine/simulator.py) overlays the stage it stalls.
 _stage = _telemetry.stage
 
+#: rows in one ``store.apply_status_batch`` call (2,048).  A commit
+#: hands every status watcher its events in one push, and a watcher more
+#: than ``WATCH_HIGH_WATER`` events behind is evicted: at 8,192 rows a
+#: request one chip run of two never saw its standing pods Running
+#: (PERF.md §6, PR 27); a quarter leaves a consumer three bursts of room.  In process the row dicts also stay in the CPU cache across
+#: build, commit and confirm at this size
+_COMMIT_ROWS = ResourceStore.WATCH_HIGH_WATER // 4
+
+#: one observation a commit request, valued with the rows it committed:
+#: ``path`` is ``batch`` (``apply_status_batch``, and the fused lane that
+#: stands in for it in process) or ``slow`` (``_drain_slow``'s bulk).
+#: ``_sum`` over ``kwok_stage_transitions_total`` is the share of played
+#: rows each path carried, ``_sum`` over ``_count`` the rows a request
+_H_COMMIT_ROWS = _telemetry.histogram(
+    "kwok_status_commit_rows",
+    help="rows committed by one status commit request of a device player",
+    buckets=(1, 4, 16, 64, 256, 1024, 2048, 4096, 8192, 16384, 65536),
+    labelnames=("kind", "path"),
+)
+
 #: live players for the interpreter-exit safety net: a daemon tick
 #: thread killed mid-XLA-dispatch at teardown aborts the whole process
 #: ("terminate called ... FATAL: exception not rethrown", rc=134), so
@@ -179,20 +199,6 @@ class DeviceStagePlayer:
         #: the sig key).
         self._plans: Dict[Tuple[int, int], Optional[RenderPlan]] = {}
         self._fast_ok = not self.sim.cset._read_paths
-        self._store_has_batch = hasattr(store, "apply_status_batch")
-        # one-time capability probe (duck-typed stores may implement
-        # the batch without the exclude kwarg)
-        self._batch_has_exclude = False
-        if self._store_has_batch:
-            import inspect
-
-            try:
-                self._batch_has_exclude = (
-                    "exclude"
-                    in inspect.signature(store.apply_status_batch).parameters
-                )
-            except (TypeError, ValueError):
-                self._batch_has_exclude = False
         # in-process stores hand back stored instances from bulk
         # (immutable by contract): the slow-path drain adopts them into
         # row mirrors, so skipping the deep copy of every result is the
@@ -477,11 +483,13 @@ class DeviceStagePlayer:
           (merge patches on the status subresource, no finalizers, no
           delete, no recorder-bound event): the patch is rebuilt from
           the cross-row plan (sentinel substitution, no gotpl render)
-          and the whole tick's rows commit through ONE
-          ``store.apply_status_batch`` call.
+          and the tick's rows commit through ``store.apply_status_batch``,
+          ``_COMMIT_ROWS`` a call, in this process or over the wire.
         - **slow path** — everything else keeps the per-row semantics:
           grouped ops through ``store.bulk``, sequential fallback for
-          order-dependent shapes."""
+          order-dependent shapes.  A fast row the store refuses (its
+          object was written by somebody else since the mirror read it)
+          goes this way too, as a merge patch."""
         from kwok_tpu.utils.trace import get_tracer
 
         tracer = get_tracer()
@@ -685,20 +693,21 @@ class DeviceStagePlayer:
         columnar status batch, the rest through the legacy group path.
         Rows are grouped by (stage, sig) so each group resolves its
         RenderPlan and tick binding once and the inner loop is pure
-        per-row substitution."""
+        per-row substitution.  The same drain runs whether the store is
+        in this process or behind the apiserver: rows are built, then
+        committed and confirmed in requests of ``_COMMIT_ROWS``."""
         cset = self.sim.cset
         stage_delete = cset.stage_delete
         sigs = self.sim.sig
         objects = self.sim.objects
         slow: List[Transition] = []
         fast_rows: List[int] = []
-        fast_items: List[Tuple[Optional[str], str, dict]] = []
-        fast_patches: List[dict] = []
+        #: (namespace, name, new status, the mirror's resourceVersion)
+        fast_items: List[tuple] = []
         now_s: Optional[str] = None
-        # the native per-row loops need the in-process columnar commit:
-        # the remote degrade path re-sends patches, which the Python
-        # loop still collects
-        use_c = _FAST is not None and self._store_has_batch
+        # the native per-row loops; without them the Python loop below
+        # builds the same items
+        use_c = _FAST is not None
         self._grow_row_arrays()
         srow = st[rows]
         sigrow = sigs[rows]
@@ -708,33 +717,16 @@ class DeviceStagePlayer:
         sig_l = sigrow[order].tolist()
         n = len(rows_l)
         vals_cache = self._vals_cache
-        # Chunked commit (native path): at large populations the row
-        # dicts fall out of CPU cache between the build pass, the store
-        # commit, and the confirm pass — running all three over ~2k-row
-        # chunks keeps each row's dict graph hot across the pipeline
-        # (the per-chunk store-call overhead is amortized to nothing).
-        chunk = 2048 if use_c else 0
+        chunk = _COMMIT_ROWS
 
         def _flush_locked() -> None:
             nonlocal fast_rows, fast_items
             if not fast_items:
                 return
-            exclude = (
-                self._informer.active_watcher if self._batch_has_exclude else None
-            )
-            with _stage(self.kind, "store_bulk") as sp:
-                if exclude is not None:
-                    results = self.store.apply_status_batch(
-                        self.kind, fast_items, exclude=exclude
-                    )
-                else:
-                    results = self.store.apply_status_batch(self.kind, fast_items)
-            self.t_store += sp.elapsed
-            self._confirm_native_locked(
-                results, fast_rows, fast_items, exclude is not None
-            )
-            fast_rows = []
-            fast_items = []
+            batch_rows, batch_items = fast_rows, fast_items
+            fast_rows, fast_items = [], []
+            for row in self._commit_batch_locked(batch_rows, batch_items):
+                slow.append(self._make_transition(row, int(st[row]), t_ms))
 
         with self._mut:
             i = 0
@@ -791,10 +783,10 @@ class DeviceStagePlayer:
                         and not plan.has_null
                         and plan.all_top_plain
                     )
-                    for k in range(0, len(group), chunk or len(group)):
+                    for k in range(0, len(group), chunk):
                         if k and self._done.is_set() and self._past_abort_grace():
                             break
-                        sub = group[k : k + chunk] if chunk else group
+                        sub = group[k : k + chunk]
                         if fused_ok and self._fused_chunk(
                             sub, s_idx, comp, bound, plan, row_vals_cb, t_ms, slow
                         ):
@@ -820,7 +812,7 @@ class DeviceStagePlayer:
                         self.transitions += noops
                         for row in slow_rows:
                             slow.append(self._make_transition(row, s_idx, t_ms))
-                        if chunk and len(fast_items) >= chunk:
+                        if len(fast_items) >= chunk:
                             _flush_locked()
                     continue
                 transitions_local = 0
@@ -854,23 +846,55 @@ class DeviceStagePlayer:
                     meta = obj.get("metadata") or {}
                     fast_rows.append(row)
                     fast_items.append(
-                        (meta.get("namespace"), meta.get("name") or "", new_status)
+                        (
+                            meta.get("namespace"),
+                            meta.get("name") or "",
+                            new_status,
+                            meta.get("resourceVersion"),
+                        )
                     )
-                    fast_patches.append(patch)
                 self.transitions += transitions_local
-            if chunk:
-                _flush_locked()
-
-        if fast_items:
-            # only the non-native path reaches here: with use_c the
-            # chunked _flush_locked above always drains fast_items
-            with _stage(self.kind, "store_bulk") as sp:
-                results = self._store_status_batch(fast_items, fast_patches)
-            self.t_store += sp.elapsed
-            self._confirm_batch_python(results, fast_rows, fast_items)
+                if len(fast_items) >= chunk:
+                    _flush_locked()
+            _flush_locked()
 
         if slow:
             self._drain_slow(slow)
+
+    def _commit_batch_locked(self, rows: List[int], items: List[tuple]) -> List[int]:
+        """One ``store.apply_status_batch`` request for the built rows
+        and its accounting (``self._mut`` held).  Returns the rows the
+        store refused because their object is no longer at the
+        resourceVersion the status was merged onto (somebody else wrote
+        in between): the caller plays those as merge patches."""
+        exclude = self._informer.active_watcher
+        sp = _stage(self.kind, "store_bulk")
+        try:
+            with sp:
+                results = self.store.apply_status_batch(
+                    self.kind, items, exclude=exclude
+                )
+        except Exception:  # noqa: BLE001 — the store did not take the
+            # batch (degraded storage, an apiserver away past the retry
+            # budget): match the rows again from their mirrors, so that
+            # they fire again instead of waiting on a write nobody made
+            self._swallow()
+            results = None
+        self.t_store += sp.elapsed
+        if results is None:
+            objects = self.sim.objects
+            for row in rows:
+                if objects[row] is not None:
+                    self.sim.refresh_row(row)
+            return []
+        if _FAST is not None:
+            n_ok, refused = self._confirm_native_locked(
+                results, rows, items, exclude is not None
+            )
+        else:
+            n_ok, refused = self._confirm_python_locked(results, rows, items)
+        _H_COMMIT_ROWS.observe(n_ok, self.kind, "batch")
+        return [rows[idx] for idx in refused]
 
     def _fused_chunk(
         self, sub, s_idx, comp, bound, plan, row_vals_cb, t_ms, slow
@@ -922,6 +946,8 @@ class DeviceStagePlayer:
             self.t_build += sp.elapsed
         self.transitions += n_ok
         self.patches += n_ok
+        if n_ok:
+            _H_COMMIT_ROWS.observe(n_ok, self.kind, "batch")
         objects = self.sim.objects
         for row in slow_rows:
             if objects[row] is not None:
@@ -934,17 +960,18 @@ class DeviceStagePlayer:
 
     def _confirm_native_locked(
         self, results, fast_rows, fast_items, own_cache: bool
-    ) -> None:
+    ) -> Tuple[int, List[int]]:
         """Adopt a status-batch's results via the C loop (self._mut
         held); when the store excluded our watcher (own_cache) AND the
         cache is a real mirror (hand-wired CacheGetter — the start()
         path uses a StoreBackedGetter with nothing to maintain), also
         maintain it here (under its lock — the informer thread still
-        applies non-batch events to it)."""
+        applies non-batch events to it).  Returns the rows committed
+        and the indexes of the refused ones."""
         cache = self.cache if own_cache and hasattr(self.cache, "_items") else None
         if cache is not None:
             with cache._mut:
-                n_ok, releases, fallback_idx = _FAST.confirm_batch(
+                n_ok, releases, fallback_idx, refused_idx = _FAST.confirm_batch(
                     results,
                     fast_rows,
                     fast_items,
@@ -953,7 +980,7 @@ class DeviceStagePlayer:
                     cache._items,
                 )
         else:
-            n_ok, releases, fallback_idx = _FAST.confirm_batch(
+            n_ok, releases, fallback_idx, refused_idx = _FAST.confirm_batch(
                 results,
                 fast_rows,
                 fast_items,
@@ -966,48 +993,61 @@ class DeviceStagePlayer:
         for key in releases:
             self._release_locked(key)
         objects = self.sim.objects
-        sim = self.sim
         for idx in fallback_idx:
             # echo carried more than our status write: full refresh
             row = fast_rows[idx]
-            if objects[row] is None:
-                continue
-            _, new_obj = results[idx]
-            old = objects[row]
-            objects[row] = new_obj
-            sim.refresh_row(row)
-            if not self._render_identity_same(old, new_obj):
-                self._drop_render_cache(row)
+            if objects[row] is not None:
+                self._adopt_changed_locked(row, results[idx][1])
+        return n_ok, refused_idx
 
-    def _confirm_batch_python(self, results, fast_rows, fast_items) -> None:
-        with self._mut:
-            objects = self.sim.objects
-            written = self._written_rv
-            sim = self.sim
-            for row, item, res in zip(fast_rows, fast_items, results):
-                if res is False:
-                    continue  # store error, surfaced already
-                if res is None:
-                    self._release_locked((item[0] or "", item[1]))
-                    continue
-                rv, new_obj = res
-                written[row] = str(rv)
-                self.transitions += 1
-                self.patches += 1
-                if objects[row] is None:
-                    continue
-                # confirm_row guards against an interleaved external
-                # write (e.g. a scheduler spec patch committed between
-                # our object read and the store batch): the store's
-                # echo carries it, and since _written_rv now covers
-                # its rv, this is the only place it can be noticed —
-                # fall back to a full feature re-extraction
-                if not sim.confirm_row(row, new_obj):
-                    old = objects[row]
-                    objects[row] = new_obj
-                    sim.refresh_row(row)
-                    if not self._render_identity_same(old, new_obj):
-                        self._drop_render_cache(row)
+    def _confirm_python_locked(
+        self, results, fast_rows, fast_items
+    ) -> Tuple[int, List[int]]:
+        """``_confirm_native_locked`` without the native unit."""
+        objects = self.sim.objects
+        written = self._written_rv
+        n_ok = 0
+        refused_idx: List[int] = []
+        for idx, (row, item, res) in enumerate(zip(fast_rows, fast_items, results)):
+            if res is False:
+                refused_idx.append(idx)
+                continue
+            if res is None:
+                self._release_locked((item[0] or "", item[1]))
+                continue
+            rv, new_obj = res
+            written[row] = str(rv)
+            n_ok += 1
+            old = objects[row]
+            if old is None:
+                continue
+            if new_obj is None:
+                # a store across the wire echoes no object, and took
+                # the row only because the mirror was current: the new
+                # mirror is the old one, the status sent, the rv given
+                new_obj = dict(old)
+                new_obj["status"] = item[2]
+                new_obj["metadata"] = dict(old["metadata"], resourceVersion=str(rv))
+            # confirm_row guards against an interleaved external
+            # write (e.g. a scheduler spec patch committed between
+            # our object read and the store batch): the store's
+            # echo carries it, and since _written_rv now covers
+            # its rv, this is the only place it can be noticed —
+            # fall back to a full feature re-extraction
+            if not self.sim.confirm_row(row, new_obj):
+                self._adopt_changed_locked(row, new_obj)
+        self.transitions += n_ok
+        self.patches += n_ok
+        return n_ok, refused_idx
+
+    def _adopt_changed_locked(self, row: int, new_obj: dict) -> None:
+        """The store's object differs from the mirror beyond our own
+        status write: take it and extract the row's features again."""
+        old = self.sim.objects[row]
+        self.sim.objects[row] = new_obj
+        self.sim.refresh_row(row)
+        if not self._render_identity_same(old, new_obj):
+            self._drop_render_cache(row)
 
     def _make_transition(self, row: int, s_idx: int, t_ms: int) -> Transition:
         cset = self.sim.cset
@@ -1023,50 +1063,6 @@ class DeviceStagePlayer:
             deleted=bool(cset.stage_delete[s_idx]),
             event=event,
         )
-
-    def _store_status_batch(self, items, patches):
-        """Commit the fast rows; returns aligned results:
-        (rv, object) | None (NotFound) | False (error, skip row)."""
-        if self._store_has_batch:
-            return self.store.apply_status_batch(self.kind, items)
-        # remote store: the columnar call degrades to a bulk of status
-        # merge patches (the server applies the merge, so its echo, not
-        # our precomputed status, is authoritative)
-        ops = [
-            {
-                "verb": "patch",
-                "kind": self.kind,
-                "name": name,
-                "namespace": ns,
-                "data": {"status": patch},
-                "patch_type": "merge",
-                "subresource": "status",
-            }
-            for (ns, name, _), patch in zip(items, patches)
-        ]
-        try:
-            results = self.store.bulk(ops)
-        except Exception:  # noqa: BLE001 — drop to per-op on bulk failure
-            results = [self._op_sequential_result(op) for op in ops]
-        out = []
-        for r in results:
-            if r.get("status") == "ok" and r.get("object") is not None:
-                o = r["object"]
-                try:
-                    rv = int((o.get("metadata") or {}).get("resourceVersion") or 0)
-                except (TypeError, ValueError):
-                    rv = 0
-                out.append((rv, o))
-            elif r.get("reason") == "NotFound":
-                out.append(None)
-            else:
-                print(
-                    f"device status batch op failed: {r.get('reason')}: "
-                    f"{r.get('error')}",
-                    file=sys.stderr,
-                )
-                out.append(False)
-        return out
 
     def _drain_slow(self, transitions: List[Transition]) -> None:
         """Legacy per-transition drain (deletes, finalizers, events,
@@ -1108,6 +1104,7 @@ class DeviceStagePlayer:
             self.t_store += sp.elapsed
             if results is None:
                 results = [self._op_sequential_result(op) for op in flat]
+            played = self.transitions
             idx = 0
             for key, ops in groups:
                 rs = results[idx : idx + len(ops)]
@@ -1116,6 +1113,7 @@ class DeviceStagePlayer:
                     self._apply_group_results(key, ops, rs)
                 except Exception:  # noqa: BLE001 — per-group isolation
                     self._swallow()
+            _H_COMMIT_ROWS.observe(self.transitions - played, self.kind, "slow")
 
     def _finish_delete(self, key: Tuple[str, str], out: Optional[dict]) -> None:
         """Complete a stage-driven delete: fully gone → release the

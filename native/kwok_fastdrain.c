@@ -233,6 +233,33 @@ py_build(PyObject *self, PyObject *args)
 
 /* -------------------------------------------------------- status_commit */
 
+/* A status item may carry, as a 4th element, the resourceVersion its
+ * status was rendered against (the sender's mirror of the object).  A
+ * stored object at any other resourceVersion was written by somebody
+ * else in between, and replacing its status wholesale would drop what
+ * that writer set: the row is refused (result False) and the sender
+ * plays it again as a merge patch.  1 = stale, 0 = current or no base
+ * given, -1 = error set. */
+static int
+stale_base(PyObject *item, PyObject *cur)
+{
+    if (PyTuple_GET_SIZE(item) < 4)
+        return 0;
+    PyObject *base = PyTuple_GET_ITEM(item, 3);
+    if (base == Py_None)
+        return 0;
+    PyObject *meta = PyDict_GetItemWithError(cur, s_metadata);
+    if (!meta || !PyDict_Check(meta))
+        return PyErr_Occurred() ? -1 : 0; /* the commit raises KeyError */
+    PyObject *rv = PyDict_GetItemWithError(meta, s_resourceVersion);
+    if (!rv)
+        return PyErr_Occurred() ? -1 : 1;
+    if (rv == base)
+        return 0;
+    int eq = PyObject_RichCompareBool(rv, base, Py_EQ);
+    return eq < 0 ? -1 : !eq;
+}
+
 static PyObject *
 py_status_commit(PyObject *self, PyObject *args)
 {
@@ -248,7 +275,7 @@ py_status_commit(PyObject *self, PyObject *args)
     if (!results || !evs)
         goto fail;
     for (Py_ssize_t i = 0; i < n; i++) {
-        PyObject *item = PyList_GET_ITEM(items, i); /* (ns, name, status) */
+        PyObject *item = PyList_GET_ITEM(items, i); /* (ns, name, status[, base rv]) */
         PyObject *ns = PyTuple_GET_ITEM(item, 0);
         PyObject *name = PyTuple_GET_ITEM(item, 1);
         PyObject *status = PyTuple_GET_ITEM(item, 2);
@@ -268,6 +295,15 @@ py_status_commit(PyObject *self, PyObject *args)
             if (PyList_Append(results, Py_None) < 0)
                 goto fail;
             continue;
+        }
+        {
+            int stale = stale_base(item, cur);
+            if (stale) {
+                Py_DECREF(key);
+                if (stale < 0 || PyList_Append(results, Py_False) < 0)
+                    goto fail;
+                continue;
+            }
         }
         PyObject *newobj = PyDict_Copy(cur);
         if (!newobj) {
@@ -378,7 +414,7 @@ py_status_commit_inplace(PyObject *self, PyObject *args)
     if (!results)
         return NULL;
     for (Py_ssize_t i = 0; i < n; i++) {
-        PyObject *item = PyList_GET_ITEM(items, i); /* (ns, name, status) */
+        PyObject *item = PyList_GET_ITEM(items, i); /* (ns, name, status[, base rv]) */
         PyObject *ns = PyTuple_GET_ITEM(item, 0);
         PyObject *name = PyTuple_GET_ITEM(item, 1);
         PyObject *status = PyTuple_GET_ITEM(item, 2);
@@ -398,6 +434,14 @@ py_status_commit_inplace(PyObject *self, PyObject *args)
             if (PyList_Append(results, Py_None) < 0)
                 goto fail;
             continue;
+        }
+        {
+            int stale = stale_base(item, cur);
+            if (stale) {
+                if (stale < 0 || PyList_Append(results, Py_False) < 0)
+                    goto fail;
+                continue;
+            }
         }
         PyObject *meta = PyDict_GetItemWithError(cur, s_metadata);
         if (!meta || !PyDict_Check(meta)) {
@@ -543,8 +587,9 @@ err:
  * build the patch, merge it onto the current status (wholesale-replace
  * shortcut when the plan allows; merge_cb = apply_merge_patch
  * otherwise), optionally drop pure no-ops, and append
- * (ns, name, new_status) to fast_items.  Rows whose build/merge raises
- * land in slow_rows for the per-row fallback path. */
+ * (ns, name, new_status, the mirror's resourceVersion) to fast_items.
+ * Rows whose build/merge raises land in slow_rows for the per-row
+ * fallback path. */
 static PyObject *
 py_fast_group(PyObject *self, PyObject *args)
 {
@@ -710,7 +755,16 @@ py_fast_group(PyObject *self, PyObject *args)
             }
             name = s_empty;
         }
-        PyObject *item = PyTuple_Pack(3, ns, name, new_status);
+        /* the mirror's resourceVersion: what new_status was merged onto */
+        PyObject *base = PyDict_GetItemWithError(meta, s_resourceVersion);
+        if (!base) {
+            if (PyErr_Occurred()) {
+                Py_DECREF(new_status);
+                goto err;
+            }
+            base = Py_None;
+        }
+        PyObject *item = PyTuple_Pack(4, ns, name, new_status, base);
         Py_DECREF(new_status);
         if (!item)
             goto err;
@@ -1003,17 +1057,58 @@ eq_field(PyObject *a, PyObject *b)
  * loop after _store_status_batch in device_player._drain_tick):
  *
  *   confirm_batch(results, rows, items, objects, written, cache)
- *     -> (n_ok, releases, fallback_idx)
+ *     -> (n_ok, releases, fallback_idx, refused_idx)
  *
  * Per result: None -> the object is gone, its (ns, name) key lands in
- * releases; (rv, obj) -> record the written resourceVersion, adopt the
+ * releases; False -> the store refused the row (its object is not at the
+ * resourceVersion the status was rendered against), the result index
+ * lands in refused_idx for the per-row merge-patch path; (rv, obj) ->
+ * record the written resourceVersion, adopt the
  * store's echo into the row mirror when nothing beyond status changed
  * (pointer-first compare on spec/labels/annotations/ownerReferences/
  * deletionTimestamp/finalizers), else report the result index in
- * fallback_idx for a full host re-extract.  ``cache`` (may be None) is
+ * fallback_idx for a full host re-extract; (rv, None) -> a store across
+ * the wire echoes no object: the new mirror is the old one with the
+ * status that was sent and the resourceVersion that came back (the store
+ * committed only because the mirror was current, so nothing else can
+ * differ).  ``cache`` (may be None) is
  * the informer mirror to maintain directly when the store excluded our
  * own watcher from event delivery; entries only move forward in
  * resourceVersion. */
+/* the row mirror after a status commit that echoed no object */
+static PyObject *
+mirror_after(PyObject *old, PyObject *status, PyObject *rv_obj)
+{
+    PyObject *om = PyDict_GetItemWithError(old, s_metadata);
+    if (!om || !PyDict_Check(om)) {
+        if (!PyErr_Occurred())
+            PyErr_SetString(PyExc_KeyError, "metadata");
+        return NULL;
+    }
+    PyObject *new_obj = PyDict_Copy(old);
+    PyObject *nm = PyDict_Copy(om);
+    PyObject *rvs = PyObject_Str(rv_obj);
+    if (!new_obj || !nm || !rvs ||
+        PyDict_SetItem(nm, s_resourceVersion, rvs) < 0 ||
+        PyDict_SetItem(new_obj, s_metadata, nm) < 0 ||
+        PyDict_SetItem(new_obj, s_status, status) < 0)
+        Py_CLEAR(new_obj);
+    Py_XDECREF(nm);
+    Py_XDECREF(rvs);
+    return new_obj;
+}
+
+static int
+append_index(PyObject *list, Py_ssize_t i)
+{
+    PyObject *idx = PyLong_FromSsize_t(i);
+    if (!idx)
+        return -1;
+    int rc = PyList_Append(list, idx);
+    Py_DECREF(idx);
+    return rc;
+}
+
 static PyObject *
 py_confirm_batch(PyObject *self, PyObject *args)
 {
@@ -1027,9 +1122,12 @@ py_confirm_batch(PyObject *self, PyObject *args)
     long long n_ok = 0;
     PyObject *releases = PyList_New(0);
     PyObject *fallbacks = PyList_New(0);
-    if (!releases || !fallbacks)
+    PyObject *refused = PyList_New(0);
+    PyObject *built = NULL; /* owned: mirror_after()'s, one row at a time */
+    if (!releases || !fallbacks || !refused)
         goto err;
     for (Py_ssize_t i = 0; i < n; i++) {
+        Py_CLEAR(built);
         PyObject *res = PyList_GET_ITEM(results, i);
         PyObject *row_obj = PyList_GET_ITEM(rows, i);
         if (res == Py_None) {
@@ -1049,11 +1147,27 @@ py_confirm_batch(PyObject *self, PyObject *args)
             Py_DECREF(key);
             continue;
         }
-        if (res == Py_False)
-            continue; /* store error, surfaced already */
+        if (res == Py_False) {
+            if (append_index(refused, i) < 0)
+                goto err;
+            continue;
+        }
         PyObject *rv_obj = PyTuple_GET_ITEM(res, 0);
         PyObject *new_obj = PyTuple_GET_ITEM(res, 1);
         n_ok++;
+        Py_ssize_t row = PyLong_AsSsize_t(row_obj);
+        if (row < 0 && PyErr_Occurred())
+            goto err;
+        PyObject *old = PyList_GET_ITEM(objects, row);
+        if (new_obj == Py_None) {
+            if (old == Py_None)
+                continue;
+            built = mirror_after(
+                old, PyTuple_GET_ITEM(PyList_GET_ITEM(items, i), 2), rv_obj);
+            if (!built)
+                goto err;
+            new_obj = built;
+        }
         PyObject *nm = PyDict_GetItemWithError(new_obj, s_metadata);
         if (!nm || !PyDict_Check(nm)) {
             if (PyErr_Occurred())
@@ -1066,14 +1180,10 @@ py_confirm_batch(PyObject *self, PyObject *args)
                 goto err;
             rvs = Py_None;
         }
-        Py_ssize_t row = PyLong_AsSsize_t(row_obj);
-        if (row < 0 && PyErr_Occurred())
-            goto err;
         /* written is row-indexed (list), like vals_cache */
         Py_INCREF(rvs);
         if (PyList_SetItem(written, row, rvs) < 0) /* steals */
             goto err;
-        PyObject *old = PyList_GET_ITEM(objects, row);
         if (cache) {
             PyObject *ns = PyDict_GetItemWithError(nm, s_namespace);
             if (!ns || ns == Py_None) {
@@ -1160,21 +1270,17 @@ py_confirm_batch(PyObject *self, PyObject *args)
                 Py_DECREF(new_obj);
                 goto err;
             }
-        } else {
-            PyObject *idx = PyLong_FromSsize_t(i);
-            if (!idx)
-                goto err;
-            if (PyList_Append(fallbacks, idx) < 0) {
-                Py_DECREF(idx);
-                goto err;
-            }
-            Py_DECREF(idx);
+        } else if (append_index(fallbacks, i) < 0) {
+            goto err;
         }
     }
-    return Py_BuildValue("(LNN)", n_ok, releases, fallbacks);
+    Py_XDECREF(built);
+    return Py_BuildValue("(LNNN)", n_ok, releases, fallbacks, refused);
 err:
+    Py_XDECREF(built);
     Py_XDECREF(releases);
     Py_XDECREF(fallbacks);
+    Py_XDECREF(refused);
     return NULL;
 }
 
@@ -1258,7 +1364,7 @@ static PyMethodDef Methods[] = {
      "merge_cb, fast_rows, fast_items) -> (noops, slow_rows)"},
     {"confirm_batch", py_confirm_batch, METH_VARARGS,
      "confirm_batch(results, rows, items, objects, written, cache) -> "
-     "(n_ok, releases, fallback_idx)"},
+     "(n_ok, releases, fallback_idx, refused_idx)"},
     {"status_commit_inplace", py_status_commit_inplace, METH_VARARGS,
      "status_commit_inplace(objects, items, rv_start, namespaced) -> "
      "(results, last_rv)"},

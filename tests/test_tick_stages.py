@@ -195,6 +195,57 @@ def test_lease_delay_runs_to_the_return_of_the_write():
     assert stage_table("Node")["compile"][1] >= 1
 
 
+def commit_rows(kind="Pod"):
+    fam = telemetry.registry().histogram("kwok_status_commit_rows")
+    return {lv[1]: (d["sum"], d["count"]) for lv, d in fam.snapshot().items() if lv[0] == kind}
+
+
+@pytest.mark.parametrize("lane", ["fused", "staged", "wire"])
+def test_commit_rows_counts_every_committed_row_once_under_its_path(lane):
+    """``kwok_status_commit_rows{kind,path}``: one observation a request,
+    valued with the rows it committed.  Five pods turn Running through
+    the batch (the verb, or the in-place lane that stands in for it when
+    no watcher looks); the sixth was written by somebody else after the
+    player read it, is refused there and goes through ``_drain_slow``."""
+    import contextlib
+
+    from kwok_tpu.cluster.apiserver import APIServer
+    from kwok_tpu.cluster.client import ClusterClient
+    from kwok_tpu.cluster.informer import InformerEvent
+
+    telemetry.registry().histogram("kwok_status_commit_rows").clear()
+    store = ResourceStore()
+    with contextlib.ExitStack() as stack:
+        handle = store
+        if lane == "wire":
+            handle = ClusterClient(stack.enter_context(APIServer(store)).url)
+        for i in range(6):
+            handle.create(make_pod(f"pod-{i}"))
+        if lane == "staged":
+            stack.callback(store.watch("Pod").stop)  # status interest: no in-place lane
+        player = make_player(handle, capacity=8)
+        for obj in handle.list("Pod")[0]:
+            player.events.add(InformerEvent("ADDED", obj))
+        player._drain_events()
+        if lane != "fused":
+            # in process with nobody watching, the in-place lane skips a row
+            # whose mirror is not the stored instance and waits for its event
+            handle.patch("Pod", "pod-5", {"status": {"qosClass": "Burstable"}}, "merge",
+                         namespace="default", subresource="status")
+        for _ in range(40):
+            player.step(100)
+            if player.transitions >= 6:
+                break
+        assert player.transitions == 6
+    got = commit_rows()
+    if lane == "fused":
+        assert got == {"batch": (6.0, 1)}
+    else:
+        assert got == {"batch": (5.0, 1), "slow": (1.0, 1)}
+    assert sum(rows for rows, _n in got.values()) == player.transitions
+    assert store.get("Pod", "pod-5", namespace="default")["status"]["phase"] == "Running"
+
+
 def test_memory_stats_of_a_backend_that_keeps_none():
     from kwok_tpu.utils import accel
 
