@@ -35,6 +35,7 @@ from kwok_tpu.engine.simulator import DEFAULT_EPOCH, DeviceSimulator, Transition
 from kwok_tpu.native.fastdrain import load as _load_fastdrain
 from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.utils.clock import Clock, RealClock
+from kwok_tpu.utils.expression import parse_rfc3339
 from kwok_tpu.utils.log import get_logger
 from kwok_tpu.utils.patch import apply_merge_patch as _merge_patch
 from kwok_tpu.utils.patch import is_noop_patch
@@ -49,8 +50,10 @@ _LOG = get_logger("device-player")
 #: (utils/telemetry.stage): a TraceAnnotation ``kwok/<kind>/<name>`` on
 #: the profiler's clock and ``kwok_tick_stage_seconds{kind,stage}``.
 #: Outermost: ingest, device_tick, host_drain, post_tick, pace_wait;
-#: host_build and store_bulk nest in host_drain (which reports self
-#: time), compile (engine/simulator.py) overlays the stage it stalls.
+#: host_build and store_bulk (the status batch) and slow_build and
+#: slow_commit (``_drain_slow``: the per-row Python around its bulk, and
+#: the bulk) nest in host_drain, which reports self time; compile
+#: (engine/simulator.py) overlays the stage it stalls.
 _stage = _telemetry.stage
 
 #: rows in one ``store.apply_status_batch`` call (2,048).  A commit
@@ -71,6 +74,18 @@ _H_COMMIT_ROWS = _telemetry.histogram(
     help="rows committed by one status commit request of a device player",
     buckets=(1, 4, 16, 64, 256, 1024, 2048, 4096, 8192, 16384, 65536),
     labelnames=("kind", "path"),
+)
+
+#: per object whose stage-driven delete the store acknowledged as gone:
+#: seconds from its ``metadata.deletionTimestamp`` (the store stamps whole
+#: seconds, rounded down: a reading is up to a second long, half of one
+#: in the mean) to that acknowledgement, on the player's clock
+_H_DELETE_TO_GONE = _telemetry.histogram(
+    "kwok_delete_to_gone_seconds",
+    help="deletionTimestamp of an object to the acknowledgement that its "
+    "stage-driven delete left nothing behind",
+    buckets=(0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 240.0),
+    labelnames=("kind",),
 )
 
 #: live players for the interpreter-exit safety net: a daemon tick
@@ -1067,33 +1082,36 @@ class DeviceStagePlayer:
     def _drain_slow(self, transitions: List[Transition]) -> None:
         """Legacy per-transition drain (deletes, finalizers, events,
         non-status patches): grouped ops through store.bulk with the
-        sequential fallback."""
+        sequential fallback.  ``slow_build`` is the Python a row on both
+        sides of the bulk, ``slow_commit`` the bulk."""
         can_bulk = hasattr(self.store, "bulk")
         groups: List[Tuple[Tuple[str, str], List[dict]]] = []
-        for j, tr in enumerate(transitions):
-            if (
-                (j & 0xFF) == 0xFF
-                and self._done.is_set()
-                and self._past_abort_grace()
-            ):
-                break  # shutdown: unplayed transitions re-fire on restart
-            try:
-                g = self._collect_ops(tr) if can_bulk else None
-                if g is not None:
-                    key, ops = g
-                    if ops:
-                        groups.append((key, ops))
-                else:
-                    self._play_transition(tr)
-            except Exception:  # noqa: BLE001 — one bad row must not stop the drain
-                self._swallow()
-        if groups:
+        played = self.transitions
+        with _stage(self.kind, "slow_build"):
+            for j, tr in enumerate(transitions):
+                if (
+                    (j & 0xFF) == 0xFF
+                    and self._done.is_set()
+                    and self._past_abort_grace()
+                ):
+                    break  # shutdown: unplayed transitions re-fire on restart
+                try:
+                    g = self._collect_ops(tr) if can_bulk else None
+                    if g is not None:
+                        key, ops = g
+                        if ops:
+                            groups.append((key, ops))
+                    else:
+                        self._play_transition(tr)
+                except Exception:  # noqa: BLE001 — one bad row must not stop the drain
+                    self._swallow()
             flat = [
                 {k: v for k, v in op.items() if k != "_fin"}
                 for _, ops in groups
                 for op in ops
             ]
-            with _stage(self.kind, "store_bulk") as sp:
+        if groups:
+            with _stage(self.kind, "slow_commit") as sp:
                 try:
                     if self._bulk_no_copy:
                         results = self.store.bulk(flat, copy_results=False)
@@ -1102,17 +1120,20 @@ class DeviceStagePlayer:
                 except Exception:  # noqa: BLE001 — drop to per-op on bulk failure
                     results = None
             self.t_store += sp.elapsed
-            if results is None:
-                results = [self._op_sequential_result(op) for op in flat]
-            played = self.transitions
-            idx = 0
-            for key, ops in groups:
-                rs = results[idx : idx + len(ops)]
-                idx += len(ops)
-                try:
-                    self._apply_group_results(key, ops, rs)
-                except Exception:  # noqa: BLE001 — per-group isolation
-                    self._swallow()
+            with _stage(self.kind, "slow_build"):
+                if results is None:
+                    results = [self._op_sequential_result(op) for op in flat]
+                idx = 0
+                for key, ops in groups:
+                    rs = results[idx : idx + len(ops)]
+                    idx += len(ops)
+                    try:
+                        self._apply_group_results(key, ops, rs)
+                    except Exception:  # noqa: BLE001 — per-group isolation
+                        self._swallow()
+        if groups or self.transitions != played:
+            # whatever was played: through the bulk, by _play_transition,
+            # or with nothing to send
             _H_COMMIT_ROWS.observe(self.transitions - played, self.kind, "slow")
 
     def _finish_delete(self, key: Tuple[str, str], out: Optional[dict]) -> None:
@@ -1120,10 +1141,19 @@ class DeviceStagePlayer:
         row; terminating (finalizers pending) → refresh from the
         store's result.  Counts the transition either way."""
         self.transitions += 1
-        if out is None:
-            self._release(key)
-        else:
+        if out is not None:
             self._refresh(key, out)
+            return
+        with self._mut:
+            row = self._rows.get(key)
+            gone = self.sim.objects[row] if row is not None else None
+            self._release_locked(key)
+        asked = ((gone or {}).get("metadata") or {}).get("deletionTimestamp")
+        at = parse_rfc3339(asked) if isinstance(asked, str) else None
+        if at is not None:
+            _H_DELETE_TO_GONE.observe(
+                max(self.clock.now() - at.timestamp(), 0.0), self.kind
+            )
 
     #: timestamp that can never occur in real renders (pre-epoch)
     _NOW_SENTINEL = "1987-06-05T04:03:02.000001Z"

@@ -19,11 +19,14 @@ from kwok_tpu.utils.clock import FakeClock
 NEW_STAGES = ("ingest", "compile", "post_tick", "pace_wait")
 
 
-def make_pod(name):
+def make_pod(name, finalizers=()):
+    meta = {"name": name, "namespace": "default", "uid": f"uid-{name}"}
+    if finalizers:
+        meta["finalizers"] = list(finalizers)
     return {
         "apiVersion": "v1",
         "kind": "Pod",
-        "metadata": {"name": name, "namespace": "default", "uid": f"uid-{name}"},
+        "metadata": meta,
         "spec": {"nodeName": "node-0", "containers": [{"name": "app", "image": "x"}]},
         "status": {},
     }
@@ -68,12 +71,15 @@ def fresh():
     simulator.ShapeLog._seen.update(saved)
 
 
-@pytest.mark.parametrize("paced", [True, False], ids=["paced", "unpaced"])
-def test_the_stages_of_the_tick_thread_make_its_wall_time(paced):
+@pytest.mark.parametrize("paced,deletes", [(True, False), (False, False), (False, True)],
+                         ids=["paced", "unpaced", "unpaced-deletes"])
+def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes):
     """The clock is injected: a paced loop waits for the test to
-    advance it."""
-    store = ResourceStore()
+    advance it.  With ``deletes`` the pods carry a finalizer and are
+    deleted once Running: ``pod-delete`` plays them through ``_drain_slow``,
+    whose Python and whose bulk are two more stages of the sum."""
     clock = FakeClock(1000.0)
+    store = ResourceStore(clock=clock)  # one clock: a deletionTimestamp is the player's time
     player = make_player(store, capacity=16, clock=clock)
     posts = []
     player.post_tick = posts.append
@@ -84,13 +90,18 @@ def test_the_stages_of_the_tick_thread_make_its_wall_time(paced):
     player.start(paced=paced)
     try:
         for i in range(40):
-            store.create(make_pod(f"pod-{i}"))
+            store.create(make_pod(f"pod-{i}", ("kwok.x-k8s.io/fake",) if deletes else ()))
         # virtual time at a quarter of real time: once the first
         # programs have compiled, a paced loop is ahead of its schedule
         deadline = time.monotonic() + 30
+        asked = False
         while time.monotonic() < deadline and (
-            player.transitions < 40 or (paced and waited() < 3)
+            player.transitions < (80 if deletes else 40) or (paced and waited() < 3)
         ):
+            if deletes and not asked and player.transitions >= 40:
+                asked = True
+                for i in range(40):
+                    store.delete("Pod", f"pod-{i}", namespace="default")
             time.sleep(0.01)
             clock.advance(0.0025)
     finally:
@@ -98,11 +109,14 @@ def test_the_stages_of_the_tick_thread_make_its_wall_time(paced):
         clock.advance(0.02)  # wake a paced wait
         player.stop()
     wall = time.perf_counter() - t0
-    assert player.transitions >= 40 and posts
+    assert player.transitions >= (80 if deletes else 40) and posts
     table = stage_table()
     want = set(NEW_STAGES) | {"device_tick", "host_drain", "host_build", "store_bulk"}
     if not paced:
         want.discard("pace_wait")
+    if deletes:
+        want |= {"slow_build", "slow_commit"}
+        assert store.list("Pod")[0] == []
     assert want <= {k for k, (_s, n) in table.items() if n > 0}, table
     # every stage reports self time but that compile overlays the stage
     # it stalls: the sum less the overlay is the thread's wall time
@@ -110,9 +124,10 @@ def test_the_stages_of_the_tick_thread_make_its_wall_time(paced):
     assert total == pytest.approx(wall, rel=0.05), (table, wall)
     # and the accumulators bench.py reads are fed from the same clocks
     assert player.t_device == pytest.approx(table["device_tick"][0])
-    assert player.t_store == pytest.approx(table["store_bulk"][0])
+    slow_build, slow_commit = (table.get(k, (0.0, 0))[0] for k in ("slow_build", "slow_commit"))
+    assert player.t_store == pytest.approx(table["store_bulk"][0] + slow_commit)
     assert player.t_build == pytest.approx(table["host_build"][0])
-    assert player.t_host - player.t_build == pytest.approx(table["host_drain"][0])
+    assert player.t_host - player.t_build == pytest.approx(table["host_drain"][0] + slow_build)
 
 
 @pytest.mark.parametrize("cause", ["num_ticks", "capacity"])
