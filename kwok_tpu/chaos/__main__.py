@@ -283,6 +283,20 @@ def run_corruption_smoke(seed: int = 42, pods: int = 24) -> dict:
                 for i in range(1, pods, 7)
             ],
         )
+        # the delete batch's record type under the same faults
+        track(
+            store.apply_delete_batch,
+            "Pod",
+            [
+                (
+                    "default",
+                    f"smoke-{i}",
+                    store.get("Pod", f"smoke-{i}")["metadata"]["resourceVersion"],
+                )
+                for i in range(2, pods, 11)
+                if i % 5
+            ],
+        )
         live = store.dump_state()
 
         # ---- point-in-time recovery: byte-identical rebuild ---------

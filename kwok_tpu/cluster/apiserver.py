@@ -39,6 +39,12 @@ REST surface (kind-keyed rather than group/version-keyed; our
   commit (``ResourceStore.apply_status_batch``) a device player drains
   its fired rows through; answers ``{"rvs": [...]}``, one number an item
   (the new resourceVersion, 0 not found, -1 refused as stale), no object
+- ``POST /delete-batch``                   ``{kind, items}``, items
+  ``[namespace, name, resourceVersion]``: the columnar commit of
+  stage-driven deletes (``ResourceStore.apply_delete_batch``: finalizers
+  emptied, object gone, one DELETED event each); answers ``{"rvs":
+  [...]}`` in the status batch's code (the DELETED event's
+  resourceVersion, 0 not found, -1 refused as stale)
 - ``GET  /stats``                          resourceVersion + counts
 
 Impersonation rides the ``Impersonate-User`` header (reference
@@ -127,6 +133,7 @@ _ROUTE_HEADS = frozenset(
         "bulk",
         "txn",
         "status-batch",
+        "delete-batch",
         "shards",
         "state",
         "stats",
@@ -175,6 +182,25 @@ def _status_items(raw) -> list:
             raise ValueError(
                 "a status-batch item is [namespace, name, status] or "
                 "[namespace, name, status, resourceVersion]"
+            )
+        items.append(tuple(it))
+    return items
+
+
+def _delete_items(raw) -> list:
+    """A ``/delete-batch`` body's items as tuples: ``[namespace, name,
+    resourceVersion]``, the version the sender read the object at."""
+    items = []
+    for it in raw or []:
+        if not (
+            isinstance(it, list)
+            and len(it) == 3
+            and (it[0] is None or isinstance(it[0], str))
+            and isinstance(it[1], str)
+            and isinstance(it[2], str)
+        ):
+            raise ValueError(
+                "a delete-batch item is [namespace, name, resourceVersion]"
             )
         items.append(tuple(it))
     return items
@@ -862,6 +888,17 @@ class _Handler(BaseHTTPRequestHandler):
                             for r in results
                         ]
                     },
+                )
+            elif head == "delete-batch" and self._tenant is None:
+                # the status batch's sibling for stage-driven deletes,
+                # inside _dispatch and closed to a tenant's slice alike
+                body = body or {}
+                results = self.store.apply_delete_batch(
+                    body.get("kind") or "", _delete_items(body.get("items"))
+                )
+                self._send_json(
+                    200,
+                    {"rvs": [r or (0 if r is None else -1) for r in results]},
                 )
             elif head == "shards" and len(rest) == 2 and rest[1] in ("bulk", "txn"):
                 # per-shard direct-dispatch lanes (KUBEDIRECT shape,

@@ -913,6 +913,24 @@ class ClusterClient:
             for rv in data["rvs"]
         ]
 
+    def apply_delete_batch(self, kind: str, items, exclude=None) -> list:
+        """The columnar commit of stage-driven deletes across the wire
+        (``POST /delete-batch``; see ResourceStore.apply_delete_batch):
+        one request empties the finalizers of every item ``(namespace,
+        name, resourceVersion)`` and removes it, in one locked pass of
+        the server's store with one WAL record and one burst of DELETED
+        events.  Results align with items, in the in-process shape: the
+        DELETED event's resourceVersion, None where the object does not
+        exist, False where it is not at the resourceVersion the item
+        named.  ``exclude`` is ignored, as for the status batch."""
+        data = self._request(
+            "POST", "/delete-batch", body={"kind": kind, "items": items}
+        )
+        return [
+            rv if rv > 0 else (None if rv == 0 else False)
+            for rv in data["rvs"]
+        ]
+
     # --------------------------------------------------------------- watch
 
     def watch(

@@ -836,11 +836,26 @@ class ShardedStore:
         items: List[tuple],
         exclude=None,
     ) -> list:
+        return self._batch_by_shard("apply_status_batch", kind, items, exclude)
+
+    def apply_delete_batch(
+        self,
+        kind: str,
+        items: List[tuple],
+        exclude=None,
+    ) -> list:
+        return self._batch_by_shard("apply_delete_batch", kind, items, exclude)
+
+    def _batch_by_shard(
+        self, verb: str, kind: str, items: List[tuple], exclude
+    ) -> list:
+        """A batch verb (items lead with their namespace) shard by
+        shard, in shard order; results align with ``items``."""
         rt = self._rtype(kind)
         n = len(self._shards)
         if not rt.namespaced or n == 1:
             shard = self.shard_for(kind, None)
-            return self._shards[shard].apply_status_batch(
+            return getattr(self._shards[shard], verb)(
                 kind, items, exclude=self._exclude_for(exclude, shard)
             )
         groups: Dict[int, List[Tuple[int, Tuple]]] = {}
@@ -850,7 +865,7 @@ class ShardedStore:
         results: list = [None] * len(items)
         for shard in sorted(groups):
             pairs = groups[shard]
-            out = self._shards[shard].apply_status_batch(
+            out = getattr(self._shards[shard], verb)(
                 kind,
                 [it for _, it in pairs],
                 exclude=self._exclude_for(exclude, shard),

@@ -34,7 +34,7 @@ from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Union
 
-from kwok_tpu.cluster.wal import StorageDegraded, WalExhausted
+from kwok_tpu.cluster.wal import BATCH_RECORDS, StorageDegraded, WalExhausted
 from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.utils import trace as _trace
 from kwok_tpu.utils.clock import Clock, RealClock
@@ -1966,23 +1966,118 @@ class ResourceStore:
 
     def _wal_status_batch(self, kind: str, items, out) -> None:
         """One WAL record for a whole status batch; caller holds the
-        mutex.  ``items``/``out`` align per apply_status_batch.
+        mutex.  ``items``/``out`` align per apply_status_batch."""
+        self._wal_batch(
+            "status",
+            kind,
+            [
+                [item[0], item[1], item[2], res[0]]
+                for item, res in zip(items, out)
+                if res  # neither missing (None) nor refused (False)
+            ],
+        )
+
+    def _wal_batch(self, t: str, kind: str, committed: List[list]) -> None:
+        """One WAL record (``t``: ``status`` or ``delete``) for the
+        committed items of a batch, each ending in the resourceVersion
+        it was committed at; caller holds the mutex.
 
         A :class:`WalExhausted` here (reserve spent mid-batch) surfaces
         as StorageDegraded: the batch is committed in memory but its
         ack is refused, the same contract as bulk's deferred flush."""
-        pairs = [
-            [item[0], item[1], item[2], res[0]]
-            for item, res in zip(items, out)
-            if res  # neither missing (None) nor refused (False)
-        ]
-        if pairs:
+        if committed:
             try:
                 self._wal_put(
-                    {"t": "status", "rv": pairs[-1][3], "k": kind, "i": pairs}
+                    {"t": t, "rv": committed[-1][-1], "k": kind, "i": committed}
                 )
             except WalExhausted as exc:
                 raise StorageDegraded(exc.reason, str(exc)) from exc
+
+    def apply_delete_batch(
+        self,
+        kind: str,
+        items: List[tuple],
+        exclude: Optional[Watcher] = None,
+    ) -> list:
+        """Device-drain fast path for stage-driven deletes, the sibling
+        of :meth:`apply_status_batch`: empty ``metadata.finalizers`` and
+        remove many objects in one locked pass.
+
+        ``items``: ``[(namespace, name, resourceVersion)]``.  Per item,
+        in order: the DELETED event's resourceVersion for a removed
+        object, None when the key does not exist (NotFound), or False
+        when the object is stored at another resourceVersion than the
+        item names: somebody else wrote since the sender read it, and
+        what the sender reckoned from its copy (which finalizers are
+        left, whether a MODIFIED is due first) may no longer hold, so
+        the row is refused as the status batch refuses one and the
+        sender plays it op by op.
+
+        A committed item leaves exactly what ``patch`` of the
+        finalizers to none followed by ``delete`` leaves of a
+        terminating object: the key gone from the objects and the
+        indexes, and ONE DELETED event whose object is a copy of the
+        stored one without ``metadata.finalizers`` at one bumped
+        resourceVersion (the reap of ``_store_mutation``).  The events
+        land in the history ring and, after the pass, reach every
+        watcher but ``exclude`` in one push each.  One audit entry and,
+        with a log attached, one WAL record a batch, written and
+        flushed under this mutex before the call returns."""
+        with self._mut:
+            st = self._state(kind)
+            self._check_writable(kind)
+            namespaced = st.rtype.namespaced
+            out: list = []
+            evs: List[WatchEvent] = []
+            committed: List[list] = []
+            objects = st.objects
+            src = self._rv_source
+            for ns, name, want_rv in items:
+                key = ((ns or "default") if namespaced else "", name)
+                cur = objects.get(key)
+                if cur is None:
+                    out.append(None)
+                    continue
+                if cur["metadata"].get("resourceVersion") != want_rv:
+                    out.append(False)  # reckoned from an older object
+                    continue
+                gone = self._sans_finalizers(cur)
+                meta = gone["metadata"]
+                if src is None:
+                    self._rv += 1
+                else:
+                    self._rv = src.alloc()
+                rv = self._rv
+                meta["resourceVersion"] = str(rv)
+                del objects[key]
+                self._index_update(st, key, cur, None)
+                evs.append(WatchEvent(type=DELETED, object=gone, rv=rv))
+                committed.append([ns, name, rv])
+                out.append(rv)
+            if evs:
+                st.history.extend(evs)
+                self._audit.append(("delete-batch", f"{kind}:{len(evs)}", None))
+                if self._wal is not None:
+                    self._wal_batch("delete", kind, committed)
+                watchers = [w for w in st.watchers if w is not exclude]
+                if watchers and _telemetry.enabled():
+                    # one commit-time note a batch, as the status batch's
+                    self._note_commit(evs[-1].rv)
+                for w in watchers:
+                    w._push_batch(evs)
+            return out
+
+    @staticmethod
+    def _sans_finalizers(cur: dict) -> dict:
+        """A shallow copy of a stored object with a ``metadata`` of its
+        own and no finalizer in it, as a JSON patch that removes
+        ``/metadata/finalizers`` leaves it (an empty list, which no such
+        patch is made for, stays)."""
+        gone = dict(cur)
+        meta = gone["metadata"] = dict(cur["metadata"])
+        if meta.get("finalizers"):
+            del meta["finalizers"]
+        return gone
 
     def status_lane(self, kind: str, exclude: Optional[Watcher]):
         """Grant the caller the zero-copy status-commit lane for one
@@ -2737,10 +2832,10 @@ class ResourceStore:
                     continue
                 if t == "ev":
                     observed.add(rv)
-                elif t == "status":
+                elif t in BATCH_RECORDS:
                     for item in rec.get("i") or []:
                         try:
-                            observed.add(int(item[3]))
+                            observed.add(int(item[-1]))
                         except (LookupError, TypeError, ValueError):
                             pass
                 elif t == "txn":
@@ -2774,6 +2869,9 @@ class ResourceStore:
                     n += 1
                 elif t == "status":
                     self._replay_status(rec)
+                    n += 1
+                elif t == "delete":
+                    self._replay_delete(rec)
                     n += 1
             self._history_floor = max(self._history_floor, max(floor, 0))
             recovered_rv = self._rv
@@ -2851,6 +2949,24 @@ class ResourceStore:
             self._index_update(st, key, cur, new)
             st.history.append(WatchEvent(type=MODIFIED, object=new, rv=int(rv)))
             self._rv = max(self._rv, int(rv))
+
+    def _replay_delete(self, rec: dict) -> None:
+        try:
+            st = self._state(rec["k"])
+        except NotFound:
+            return
+        namespaced = st.rtype.namespaced
+        for ns, name, rv in rec["i"]:
+            self._rv = max(self._rv, int(rv))
+            key = ((ns or "default") if namespaced else "", name)
+            cur = st.objects.get(key)
+            if cur is None:
+                continue
+            gone = self._sans_finalizers(cur)
+            gone["metadata"]["resourceVersion"] = str(rv)
+            del st.objects[key]
+            self._index_update(st, key, cur, None)
+            st.history.append(WatchEvent(type=DELETED, object=gone, rv=int(rv)))
 
     # -------------------------------------------------------------------- stats
 

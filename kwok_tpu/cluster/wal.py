@@ -62,6 +62,10 @@ Record shapes (all carry ``rv``)::
 
     {"t": "ev", "rv": N, "u": uid_counter, "e": "ADDED|MODIFIED|DELETED", "o": {obj}}
     {"t": "status", "rv": N, "k": kind, "i": [[ns, name, status, rv], ...]}
+    {"t": "delete", "rv": N, "k": kind, "i": [[ns, name, rv], ...]}
+                                     # apply_delete_batch(): finalizers
+                                     # emptied, object gone, one DELETED
+                                     # event an item at its rv
     {"t": "type", "rv": N, "api_version": ..., "kind": ..., "plural": ..., "namespaced": ...}
     {"t": "reset", "rv": N}          # restore_state wiped the keyspace
     {"t": "txn", "rv": maxN, "recs": [ev, ...]}  # transact(): one frame,
@@ -108,6 +112,7 @@ __all__ = [
     "WriteAheadLog",
     "classify_os_error",
     "read_records",
+    "BATCH_RECORDS",
     "record_rvs",
     "scan",
     "scan_files",
@@ -410,12 +415,19 @@ def scan(path: str) -> WalScan:
     return scan_files(segment_files(path))
 
 
+#: the batch records: one frame, one item a committed object, the item's
+#: last element the resourceVersion it was committed at.  Every reader
+#: that walks a batch's items (:func:`record_rvs`, the store's replay,
+#: the PITR trim) tells a batch record by this
+BATCH_RECORDS = frozenset({"status", "delete"})
+
+
 def record_rvs(
     rec: Dict[str, Any], include_void: bool = False
 ) -> Iterator[int]:
     """Every resourceVersion one WAL record commits: the event's own
-    rv, each status-batch item's, each txn sub-event's.  The ONE walk
-    shared by retention/continuity accounting (fsck, the PITR rebuild)
+    rv, each status- or delete-batch item's, each txn sub-event's.  The
+    ONE walk shared by retention/continuity accounting (fsck, the PITR rebuild)
     and the DST durability probes — a record type added to the framing
     must be threaded here once, not per consumer.  ``include_void``
     adds allocated-then-rolled-back rvs (``ResourceStore._unbump``):
@@ -430,10 +442,10 @@ def record_rvs(
             yield int(rec.get("rv", 0) or 0)
         except (TypeError, ValueError):
             return
-    elif t == "status":
+    elif t in BATCH_RECORDS:
         for item in rec.get("i") or []:
             try:
-                yield int(item[3])
+                yield int(item[-1])
             except (LookupError, TypeError, ValueError):
                 continue
     elif t == "txn":

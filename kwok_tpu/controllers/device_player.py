@@ -38,6 +38,7 @@ from kwok_tpu.utils.clock import Clock, RealClock
 from kwok_tpu.utils.expression import parse_rfc3339
 from kwok_tpu.utils.log import get_logger
 from kwok_tpu.utils.patch import apply_merge_patch as _merge_patch
+from kwok_tpu.utils.patch import apply_patch as _apply_patch
 from kwok_tpu.utils.patch import is_noop_patch
 from kwok_tpu.utils.queue import Queue
 
@@ -50,23 +51,26 @@ _LOG = get_logger("device-player")
 #: (utils/telemetry.stage): a TraceAnnotation ``kwok/<kind>/<name>`` on
 #: the profiler's clock and ``kwok_tick_stage_seconds{kind,stage}``.
 #: Outermost: ingest, device_tick, host_drain, post_tick, pace_wait;
-#: host_build and store_bulk (the status batch) and slow_build and
-#: slow_commit (``_drain_slow``: the per-row Python around its bulk, and
-#: the bulk) nest in host_drain, which reports self time; compile
-#: (engine/simulator.py) overlays the stage it stalls.
+#: host_build and store_bulk (the status batch), delete_commit (the
+#: delete batch) and slow_build and slow_commit (``_drain_slow``: the
+#: per-row Python around its bulk, and the bulk) nest in host_drain,
+#: which reports self time; compile (engine/simulator.py) overlays the
+#: stage it stalls.
 _stage = _telemetry.stage
 
-#: rows in one ``store.apply_status_batch`` call (2,048).  A commit
-#: hands every status watcher its events in one push, and a watcher more
-#: than ``WATCH_HIGH_WATER`` events behind is evicted: at 8,192 rows a
-#: request one chip run of two never saw its standing pods Running
-#: (PERF.md §6, PR 27); a quarter leaves a consumer three bursts of room.  In process the row dicts also stay in the CPU cache across
-#: build, commit and confirm at this size
+#: rows in one ``store.apply_status_batch`` or ``apply_delete_batch``
+#: call (2,048).  A commit hands every watcher its events in one push,
+#: and a watcher more than ``WATCH_HIGH_WATER`` events behind is
+#: evicted: at 8,192 rows a request one chip run of two never saw its
+#: standing pods Running (PERF.md §6, PR 27); a quarter leaves a
+#: consumer three bursts of room.  In process the row dicts also stay in
+#: the CPU cache across build, commit and confirm at this size
 _COMMIT_ROWS = ResourceStore.WATCH_HIGH_WATER // 4
 
 #: one observation a commit request, valued with the rows it committed:
 #: ``path`` is ``batch`` (``apply_status_batch``, and the fused lane that
-#: stands in for it in process) or ``slow`` (``_drain_slow``'s bulk).
+#: stands in for it in process), ``delete`` (``apply_delete_batch``) or
+#: ``slow`` (``_drain_slow``'s bulk).
 #: ``_sum`` over ``kwok_stage_transitions_total`` is the share of played
 #: rows each path carried, ``_sum`` over ``_count`` the rows a request
 _H_COMMIT_ROWS = _telemetry.histogram(
@@ -213,6 +217,9 @@ class DeviceStagePlayer:
         #: are sentinel-substituted; spec/labels/annotations are part of
         #: the sig key).
         self._plans: Dict[Tuple[int, int], Optional[RenderPlan]] = {}
+        #: (stage_idx, finalizers, terminating) -> whether such a row of
+        #: a deleting stage goes by the delete batch (_delete_is_one_event)
+        self._delete_verdicts: Dict[tuple, bool] = {}
         self._fast_ok = not self.sim.cset._read_paths
         # in-process stores hand back stored instances from bulk
         # (immutable by contract): the slow-path drain adopts them into
@@ -500,11 +507,16 @@ class DeviceStagePlayer:
           the cross-row plan (sentinel substitution, no gotpl render)
           and the tick's rows commit through ``store.apply_status_batch``,
           ``_COMMIT_ROWS`` a call, in this process or over the wire.
+        - **delete path** — rows of a deleting stage whose whole outcome
+          is one DELETED event (``_delete_is_one_event``, read off the
+          row's mirror) commit as ``(namespace, name, resourceVersion)``
+          through ``store.apply_delete_batch``, ``_COMMIT_ROWS`` a call.
         - **slow path** — everything else keeps the per-row semantics:
           grouped ops through ``store.bulk``, sequential fallback for
-          order-dependent shapes.  A fast row the store refuses (its
+          order-dependent shapes.  A row either batch refuses (its
           object was written by somebody else since the mirror read it)
-          goes this way too, as a merge patch."""
+          goes this way too, as a merge patch or as a finalizer patch
+          and a delete."""
         from kwok_tpu.utils.trace import get_tracer
 
         tracer = get_tracer()
@@ -705,7 +717,9 @@ class DeviceStagePlayer:
 
     def _drain_tick(self, rows: np.ndarray, st: np.ndarray, t_ms: int) -> None:
         """Drain one sub-tick's fired rows: fast rows through the
-        columnar status batch, the rest through the legacy group path.
+        columnar status batch, deletes whose whole outcome is one
+        DELETED event through the delete batch, the rest through the
+        legacy group path.
         Rows are grouped by (stage, sig) so each group resolves its
         RenderPlan and tick binding once and the inner loop is pure
         per-row substitution.  The same drain runs whether the store is
@@ -719,6 +733,9 @@ class DeviceStagePlayer:
         fast_rows: List[int] = []
         #: (namespace, name, new status, the mirror's resourceVersion)
         fast_items: List[tuple] = []
+        gone_rows: List[int] = []
+        #: (namespace, name, the mirror's resourceVersion)
+        gone_items: List[tuple] = []
         now_s: Optional[str] = None
         # the native per-row loops; without them the Python loop below
         # builds the same items
@@ -743,6 +760,15 @@ class DeviceStagePlayer:
             for row in self._commit_batch_locked(batch_rows, batch_items):
                 slow.append(self._make_transition(row, int(st[row]), t_ms))
 
+        def _flush_gone_locked() -> None:
+            nonlocal gone_rows, gone_items
+            if not gone_items:
+                return
+            batch_rows, batch_items = gone_rows, gone_items
+            gone_rows, gone_items = [], []
+            for row in self._commit_delete_locked(batch_rows, batch_items):
+                slow.append(self._make_transition(row, int(st[row]), t_ms))
+
         with self._mut:
             i = 0
             while i < n:
@@ -765,15 +791,35 @@ class DeviceStagePlayer:
                         break
                 if rep is None:
                     continue
+                if stage_delete[s_idx]:
+                    for row in group:
+                        obj = objects[row]
+                        if obj is None:
+                            continue
+                        meta = obj.get("metadata") or {}
+                        if self._delete_is_one_event(s_idx, meta):
+                            gone_rows.append(row)
+                            gone_items.append(
+                                (
+                                    meta.get("namespace"),
+                                    meta.get("name") or "",
+                                    meta["resourceVersion"],
+                                )
+                            )
+                            if len(gone_items) >= chunk:
+                                _flush_gone_locked()
+                        else:
+                            slow.append(self._make_transition(row, s_idx, t_ms))
+                    continue
                 plan = None
-                if self._fast_ok and not stage_delete[s_idx]:
+                if self._fast_ok:
                     plan = self._plan_for(s_idx, sig, rep)
                 if plan is None or not plan.fast or (
                     plan.has_event and self.recorder is not None
                 ):
-                    # deletes, finalizer ops, recorder-bound events,
-                    # non-status patches: per-row path (which still
-                    # renders through the plan when one exists)
+                    # finalizer ops, recorder-bound events, non-status
+                    # patches: per-row path (which still renders
+                    # through the plan when one exists)
                     for row in group:
                         if objects[row] is not None:
                             slow.append(self._make_transition(row, s_idx, t_ms))
@@ -872,6 +918,7 @@ class DeviceStagePlayer:
                 if len(fast_items) >= chunk:
                     _flush_locked()
             _flush_locked()
+            _flush_gone_locked()
 
         if slow:
             self._drain_slow(slow)
@@ -910,6 +957,77 @@ class DeviceStagePlayer:
             n_ok, refused = self._confirm_python_locked(results, rows, items)
         _H_COMMIT_ROWS.observe(n_ok, self.kind, "batch")
         return [rows[idx] for idx in refused]
+
+    def _delete_is_one_event(self, s_idx: int, meta: dict) -> bool:
+        """Whether all that the per-row path would make of this fired
+        row of a deleting stage is one DELETED event, which is what a
+        delete-batch item makes: the stage's finalizer change leaves the
+        mirror's list empty; the mirror is terminating already, or no
+        finalizer changes (else a MODIFIED comes first); no event goes
+        to a recorder.  The store refuses an item whose object is not at
+        the mirror's resourceVersion, so the mirror's finalizers are the
+        stored ones where this reckoning is used (``self._mut`` held)."""
+        fins = meta.get("finalizers") or ()
+        terminating = meta.get("deletionTimestamp") is not None
+        key = (s_idx, tuple(fins), terminating)
+        verdict = self._delete_verdicts.get(key)
+        if verdict is None:
+            cset = self.sim.cset
+            effects = cset.lifecycle.effects(cset.compiled[s_idx])
+            verdict = False
+            if (
+                effects is not None
+                and effects.delete
+                and not (cset.stage_event[s_idx] >= 0 and self.recorder is not None)
+            ):
+                fin = effects.finalizers_patch(list(fins))
+                if fin is None:
+                    verdict = not fins
+                elif terminating:
+                    left = _apply_patch(
+                        {"metadata": {"finalizers": list(fins)}}, fin.data, fin.type
+                    )
+                    verdict = not left["metadata"].get("finalizers")
+            if len(self._delete_verdicts) >= 8192:
+                self._delete_verdicts.clear()  # coarse bound, as _plans
+            self._delete_verdicts[key] = verdict
+        return verdict and isinstance(meta.get("resourceVersion"), str)
+
+    def _commit_delete_locked(self, rows: List[int], items: List[tuple]) -> List[int]:
+        """One ``store.apply_delete_batch`` request for the rows of
+        deleting stages and its accounting (``self._mut`` held), the
+        twin of ``_commit_batch_locked``.  A row the store removed, or
+        did not find, is a transition played and a row released, as
+        ``_finish_delete`` with nothing left; returns the rows the store
+        refused because somebody else wrote their object since the
+        mirror was read: the caller plays those op by op."""
+        sp = _stage(self.kind, "delete_commit")
+        try:
+            with sp:
+                # no watcher is excluded: the informer's own DELETED is
+                # what runs on_delete and empties a mirrored cache
+                results = self.store.apply_delete_batch(self.kind, items)
+        except Exception:  # noqa: BLE001 — as _commit_batch_locked: the
+            # store did not take the batch; match the rows again from
+            # their mirrors, so that they fire again
+            self._swallow()
+            results = None
+        self.t_store += sp.elapsed
+        if results is None:
+            for row in rows:
+                self.sim.refresh_row(row)
+            return []
+        refused: List[int] = []
+        now = self.clock.now()
+        for row, item, res in zip(rows, items, results):
+            if res is False:
+                refused.append(row)
+            else:
+                self._gone_locked((item[0] or "", item[1]), now)
+        n_ok = len(rows) - len(refused)
+        self.transitions += n_ok
+        _H_COMMIT_ROWS.observe(n_ok, self.kind, "delete")
+        return refused
 
     def _fused_chunk(
         self, sub, s_idx, comp, bound, plan, row_vals_cb, t_ms, slow
@@ -1145,15 +1263,19 @@ class DeviceStagePlayer:
             self._refresh(key, out)
             return
         with self._mut:
-            row = self._rows.get(key)
-            gone = self.sim.objects[row] if row is not None else None
-            self._release_locked(key)
+            self._gone_locked(key, self.clock.now())
+
+    def _gone_locked(self, key: Tuple[str, str], now: float) -> None:
+        """The store acknowledged that a stage-driven delete left
+        nothing behind: release the row and observe how long the object
+        had been terminating (``self._mut`` held)."""
+        row = self._rows.get(key)
+        gone = self.sim.objects[row] if row is not None else None
+        self._release_locked(key)
         asked = ((gone or {}).get("metadata") or {}).get("deletionTimestamp")
         at = parse_rfc3339(asked) if isinstance(asked, str) else None
         if at is not None:
-            _H_DELETE_TO_GONE.observe(
-                max(self.clock.now() - at.timestamp(), 0.0), self.kind
-            )
+            _H_DELETE_TO_GONE.observe(max(now - at.timestamp(), 0.0), self.kind)
 
     #: timestamp that can never occur in real renders (pre-epoch)
     _NOW_SENTINEL = "1987-06-05T04:03:02.000001Z"

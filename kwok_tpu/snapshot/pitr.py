@@ -30,6 +30,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from kwok_tpu.cluster.store import ResourceStore
 from kwok_tpu.cluster.wal import (
+    BATCH_RECORDS,
     SEG_INFIX,
     SnapshotCorruption,
     _note_os_error,
@@ -123,8 +124,8 @@ class PitrArchive:
         seqs: Optional[List[Optional[int]]] = None,
     ) -> List[dict]:
         """Drop (parts of) records beyond the target resourceVersion —
-        status batches are trimmed per item, everything else is kept or
-        dropped whole.
+        status and delete batches are trimmed per item, everything else
+        is kept or dropped whole.
 
         The target state is "immediately after commit ``to_rv``", so a
         ``type`` record must also be excluded when it was *written
@@ -138,9 +139,9 @@ class PitrArchive:
                     continue
                 t = rec.get("t")
                 covered = False
-                if t == "status":
+                if t in BATCH_RECORDS:
                     covered = any(
-                        int(it[3]) <= to_rv for it in rec.get("i") or []
+                        int(it[-1]) <= to_rv for it in rec.get("i") or []
                     )
                 elif t == "txn":
                     covered = any(
@@ -175,17 +176,17 @@ class PitrArchive:
                 )
                 out.append(trimmed)
                 continue
-            if t == "status":
+            if t in BATCH_RECORDS:
                 items = [
                     it
                     for it in rec.get("i") or []
-                    if int(it[3]) <= to_rv
+                    if int(it[-1]) <= to_rv
                 ]
                 if not items:
                     continue
                 trimmed = dict(rec)
                 trimmed["i"] = items
-                trimmed["rv"] = int(items[-1][3])
+                trimmed["rv"] = int(items[-1][-1])
                 out.append(trimmed)
                 continue
             try:
@@ -207,8 +208,8 @@ class PitrArchive:
 
     @staticmethod
     def _covered_rvs(records) -> set:
-        """Every rv a record list commits (event, status-batch item,
-        txn sub-event, voided allocation)."""
+        """Every rv a record list commits (event, status- or
+        delete-batch item, txn sub-event, voided allocation)."""
         return {
             rv
             for rec in records

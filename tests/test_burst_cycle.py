@@ -1,7 +1,8 @@
 """Bursts of pods created, played to Running, deleted and gone, cycle
 after cycle, through a ``DeviceStagePlayer`` on ``pod-fast`` (ISSUE 28: the
 ``burst-1k`` cell of the benchmark at a size for the CPU).  Every burst pod
-carries a finalizer, so its delete is the ``pod-delete`` stage's: a
+carries a finalizer, so its delete is the ``pod-delete`` stage's: since
+ISSUE 29 one item of a delete batch (``apply_delete_batch``), before it a
 finalizer patch and a delete through ``_drain_slow``.  The store is in this
 process or behind a real apiserver over HTTP."""
 
@@ -202,7 +203,9 @@ def test_bursts_come_and_go_and_their_rows_are_used_again(flavor):
     assert new_shapes() == after_first
     commits = family("kwok_status_commit_rows")
     assert commits[("batch",)][0] == STANDING + created
-    assert commits[("slow",)][0] == created
+    # every delete went by the delete batch and none through _drain_slow
+    assert commits[("delete",)][0] == created
+    assert commits.get(("slow",), (0.0, 0))[0] == 0
     assert transitions == STANDING + 2 * created
     gone = family("kwok_delete_to_gone_seconds")[()]
     # the store stamps whole seconds, rounded down

@@ -76,8 +76,9 @@ def fresh():
 def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes):
     """The clock is injected: a paced loop waits for the test to
     advance it.  With ``deletes`` the pods carry a finalizer and are
-    deleted once Running: ``pod-delete`` plays them through ``_drain_slow``,
-    whose Python and whose bulk are two more stages of the sum."""
+    deleted once Running: ``pod-delete`` plays them through the delete
+    batch, whose commit is one more stage of the sum (``delete_commit``;
+    ``_drain_slow``'s two are in the sum when a row goes that way)."""
     clock = FakeClock(1000.0)
     store = ResourceStore(clock=clock)  # one clock: a deletionTimestamp is the player's time
     player = make_player(store, capacity=16, clock=clock)
@@ -115,7 +116,7 @@ def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes):
     if not paced:
         want.discard("pace_wait")
     if deletes:
-        want |= {"slow_build", "slow_commit"}
+        want |= {"delete_commit"}
         assert store.list("Pod")[0] == []
     assert want <= {k for k, (_s, n) in table.items() if n > 0}, table
     # every stage reports self time but that compile overlays the stage
@@ -124,8 +125,9 @@ def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes):
     assert total == pytest.approx(wall, rel=0.05), (table, wall)
     # and the accumulators bench.py reads are fed from the same clocks
     assert player.t_device == pytest.approx(table["device_tick"][0])
-    slow_build, slow_commit = (table.get(k, (0.0, 0))[0] for k in ("slow_build", "slow_commit"))
-    assert player.t_store == pytest.approx(table["store_bulk"][0] + slow_commit)
+    slow_build, slow_commit, delete_commit = (
+        table.get(k, (0.0, 0))[0] for k in ("slow_build", "slow_commit", "delete_commit"))
+    assert player.t_store == pytest.approx(table["store_bulk"][0] + slow_commit + delete_commit)
     assert player.t_build == pytest.approx(table["host_build"][0])
     assert player.t_host - player.t_build == pytest.approx(table["host_drain"][0] + slow_build)
 
@@ -221,8 +223,11 @@ def test_commit_rows_counts_every_committed_row_once_under_its_path(lane):
     valued with the rows it committed.  Five pods turn Running through
     the batch (the verb, or the in-place lane that stands in for it when
     no watcher looks); the sixth was written by somebody else after the
-    player read it, is refused there and goes through ``_drain_slow``."""
+    player read it, is refused there and goes through ``_drain_slow``.
+    Then all six are deleted: five go by the delete batch, the third path;
+    the one somebody else wrote meanwhile is refused there too."""
     import contextlib
+    import datetime
 
     from kwok_tpu.cluster.apiserver import APIServer
     from kwok_tpu.cluster.client import ClusterClient
@@ -235,10 +240,12 @@ def test_commit_rows_counts_every_committed_row_once_under_its_path(lane):
         if lane == "wire":
             handle = ClusterClient(stack.enter_context(APIServer(store)).url)
         for i in range(6):
-            handle.create(make_pod(f"pod-{i}"))
+            handle.create(make_pod(f"pod-{i}", ("kwok.x-k8s.io/fake",)))
         if lane == "staged":
             stack.callback(store.watch("Pod").stop)  # status interest: no in-place lane
         player = make_player(handle, capacity=8)
+        # as start() sets it: a deletionTimestamp is milliseconds from here
+        player.sim.epoch = datetime.datetime.now(datetime.timezone.utc)
         for obj in handle.list("Pod")[0]:
             player.events.add(InformerEvent("ADDED", obj))
         player._drain_events()
@@ -247,18 +254,36 @@ def test_commit_rows_counts_every_committed_row_once_under_its_path(lane):
             # whose mirror is not the stored instance and waits for its event
             handle.patch("Pod", "pod-5", {"status": {"qosClass": "Burstable"}}, "merge",
                          namespace="default", subresource="status")
-        for _ in range(40):
-            player.step(100)
-            if player.transitions >= 6:
-                break
-        assert player.transitions == 6
+
+        def play(until):
+            for _ in range(40):
+                player.step(100)
+                if player.transitions >= until:
+                    break
+            assert player.transitions == until
+
+        play(6)
+        running = commit_rows()
+        assert store.get("Pod", "pod-5", namespace="default")["status"]["phase"] == "Running"
+        for i in range(6):
+            handle.delete("Pod", f"pod-{i}", namespace="default")
+        for obj in handle.list("Pod")[0]:
+            assert obj["metadata"]["deletionTimestamp"]
+            player.events.add(InformerEvent("MODIFIED", obj))
+        player._drain_events()
+        if lane != "fused":
+            handle.patch("Pod", "pod-4", {"metadata": {"labels": {"tier": "gold"}}}, "merge",
+                         namespace="default")
+        play(12)
+        assert store.list("Pod")[0] == [] and not player._rows
     got = commit_rows()
     if lane == "fused":
-        assert got == {"batch": (6.0, 1)}
+        assert running == {"batch": (6.0, 1)}
+        assert got == {"batch": (6.0, 1), "delete": (6.0, 1)}
     else:
-        assert got == {"batch": (5.0, 1), "slow": (1.0, 1)}
+        assert running == {"batch": (5.0, 1), "slow": (1.0, 1)}
+        assert got == {"batch": (5.0, 1), "delete": (5.0, 1), "slow": (2.0, 2)}
     assert sum(rows for rows, _n in got.values()) == player.transitions
-    assert store.get("Pod", "pod-5", namespace="default")["status"]["phase"] == "Running"
 
 
 def test_memory_stats_of_a_backend_that_keeps_none():
