@@ -675,7 +675,8 @@ def build_callgraph(files: Iterable[SourceFile]) -> CallGraph:
                 hit = ctx.resolve_lock(node.func.value)
                 if hit:
                     # a raw acquire holds (conservatively) to the end of
-                    # the function — the _LaneGrant pattern holds past it
+                    # the function — an __enter__ that leaves the
+                    # release to __exit__ holds past it
                     acqs.append(
                         Acquisition(
                             hit[0], hit[1], node.lineno,

@@ -8,10 +8,9 @@ precedent):
 - **raw-acquire**: a bare ``X.acquire()`` call must be immediately
   followed by a ``try:`` whose ``finally`` releases the same lock (or
   be rewritten as ``with X:``).  The one sanctioned exception is a
-  lock deliberately held across a context-manager boundary
-  (``cluster/store.py`` ``_LaneGrant.__enter__`` holds the store mutex
-  until ``__exit__``), which carries an inline suppression explaining
-  itself.
+  lock deliberately held across a context-manager boundary (an
+  ``__enter__`` that acquires and leaves the release to ``__exit__``),
+  which carries an inline suppression explaining itself.
 - **blocking-under-lock**: inside a ``with <lock>:`` block, calls that
   can block on the outside world — ``time.sleep``, ``subprocess.*``,
   socket ``sendall``/``send``/``recv``/``connect``/``accept`` — stall

@@ -17,7 +17,8 @@ artifact:
   ``ResourceStore._mut`` are one node, the standard lock-order
   abstraction;
 - inside each lexical hold (a ``with <lock>:`` body, or a raw
-  ``.acquire()`` to end-of-function — the ``_LaneGrant`` pattern),
+  ``.acquire()`` to end-of-function — an ``__enter__`` that leaves
+  the release to ``__exit__``),
   every *direct* nested acquisition and every acquisition in any
   function **transitively reachable** through the call graph adds a
   may-hold-while-acquiring edge ``held -> acquired``, with the witness

@@ -14,7 +14,7 @@ Detection is lexical on the receiver: an attribute access ``X._name``
 identifier whose terminal name looks store-like — ``store``,
 ``_store``, ``client``, ``_client``, or any ``*store``/``*client``
 suffix.  Optional-capability *probes* stay legal: ``hasattr(store,
-"status_lane")``-style feature tests never name a private attribute.
+"bulk")``-style feature tests never name a private attribute.
 
 Shard internals are stricter: any ``X._shards`` / ``X._shard_*``
 access (the :class:`~kwok_tpu.cluster.sharding.router.ShardedStore`

@@ -61,8 +61,8 @@ class WatchOptions:
     predicate: Optional[Callable[[dict], bool]] = None
     #: False: this consumer does not need status-only batch events
     #: (Watcher.status_interest) — in-process stores then skip it on
-    #: status commits and keep the zero-copy lane eligible; remote
-    #: stores deliver everything (the wire has no such flag)
+    #: status commits; remote stores deliver everything (the wire has
+    #: no such flag)
     status_interest: bool = True
 
 

@@ -828,7 +828,7 @@ class ShardedStore:
             ops, as_user=as_user, copy_results=copy_results
         )
 
-    # ----------------------------------------------------------- status lane
+    # ---------------------------------------------------------- batch verbs
 
     def apply_status_batch(
         self,
@@ -879,13 +879,6 @@ class ShardedStore:
         if isinstance(exclude, MergedWatcher):
             return exclude.part_for(shard)
         return exclude
-
-    @contextlib.contextmanager
-    def status_lane(self, kind: str, exclude=None):
-        # the zero-copy splice lane assumes locally-allocated rvs; a
-        # shared sequence disables it per shard anyway, so the router
-        # answers "lane not grantable" and callers take the batch path
-        yield None
 
     # ------------------------------------------------------------ lifecycle
 

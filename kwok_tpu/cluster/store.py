@@ -428,8 +428,8 @@ class Watcher:
         #: False: this consumer declares it does not need status-only
         #: batch events (the GC controller's posture — it reads
         #: ownerReferences/deletionTimestamp, which status writes never
-        #: touch).  Status batches skip it, and it keeps the zero-copy
-        #: commit lane eligible; all other events flow normally.
+        #: touch).  Status batches skip it; all other events flow
+        #: normally.
         self.status_interest = status_interest
         #: undelivered-event bound; 0 disables eviction (bare Watcher
         #: construction in tests and tooling stays unbounded)
@@ -543,90 +543,6 @@ if _FAST is not None and hasattr(_FAST, "WatchEvent"):
 
 
 @dataclass
-class StatusLane:
-    """A granted zero-copy commit lane (see ResourceStore.status_lane):
-    the stored-objects dict to splice into, the resourceVersion counter
-    to advance (written back on exit), and the kind's namespacing (the
-    grantee derives store keys with the store's own convention)."""
-
-    objects: Dict[Tuple[str, str], dict]
-    rv: int
-    namespaced: bool
-
-
-class _LaneGrant:
-    """Context manager behind ResourceStore.status_lane: takes the
-    store mutex, yields a StatusLane when the zero-copy conditions hold
-    (else None), and on exit adopts the advanced resourceVersion plus
-    the history-gap marker.  A plain class (not @contextmanager) — the
-    drain requests a grant per chunk, so construction cost matters."""
-
-    __slots__ = ("store", "kind", "exclude", "lane", "st")
-
-    def __init__(self, store: "ResourceStore", kind: str, exclude):
-        self.store = store
-        self.kind = kind
-        self.exclude = exclude
-        self.lane: Optional[StatusLane] = None
-        self.st: Optional[_TypeState] = None
-
-    def __enter__(self) -> Optional[StatusLane]:
-        store = self.store
-        # deliberately manual: on a successful grant the mutex stays
-        # held across the with-body until __exit__ releases it (that IS
-        # the lane — the grantee splices store state under the lock);
-        # the except below covers the only path that must release here
-        store._mut.acquire()  # kwoklint: disable=lock-discipline
-        try:
-            try:
-                st = store._state(self.kind)
-            except NotFound:
-                return None
-            if (
-                self.exclude is None
-                # a WAL cannot observe statuses spliced in place — with
-                # durability on, status batches take the logging lanes
-                or store._wal is not None
-                # shared rv source (sharded store): the lane allocates
-                # rvs locally, which a cluster-wide sequence must see
-                or store._rv_source is not None
-                or any(p.startswith("status.") for p in st.indexes)
-                or any(
-                    w is not self.exclude
-                    and not w.stopped
-                    and w.status_interest
-                    for w in st.watchers
-                )
-                or time.monotonic() < st.lane_cooloff
-            ):
-                return None
-            self.st = st
-            self.lane = StatusLane(st.objects, store._rv, st.rtype.namespaced)
-            return self.lane
-        except BaseException:
-            store._mut.release()
-            raise
-
-    def __exit__(self, *exc) -> None:
-        store = self.store
-        try:
-            lane = self.lane
-            # forward only: a reentrant write during the lane (the
-            # store RLock re-enters from the grantee's thread) may have
-            # advanced the counter past the lane's view — never rewind
-            # below an already-issued resourceVersion
-            if lane is not None and lane.rv > store._rv:
-                n = lane.rv - store._rv
-                store._rv = lane.rv
-                self.st.inplace_rv = lane.rv
-                store._audit.append(
-                    ("patch-status-fused", f"{self.kind}:{n}", None)
-                )
-        finally:
-            store._mut.release()
-
-
-@dataclass
 class _TypeState:
     rtype: ResourceType
     history: deque
@@ -638,17 +554,6 @@ class _TypeState:
     #: lazily maintained sorted key list; invalidated on add/remove so
     #: paged walks don't re-sort the keyspace per page
     sorted_keys: Optional[List[Tuple[str, str]]] = None
-    #: gap marker for the zero-copy commit lane: status batches with no
-    #: event consumer mutate stored objects in place and append nothing
-    #: to history; a watch resume at/below this version would replay a
-    #: gapped (and possibly instance-mutated) window, so it gets
-    #: Expired and re-lists — the legal watch-cache-too-small answer
-    inplace_rv: int = 0
-    #: monotonic deadline until which the zero-copy lane must yield to
-    #: the copy lane: set when a watch resume hits the gap marker, so a
-    #: list-then-watch consumer's NEXT attempt finds real history
-    #: instead of being starved by a continuously-advancing marker
-    lane_cooloff: float = 0.0
 
 
 class ResourceStore:
@@ -691,9 +596,9 @@ class ResourceStore:
         #: drawn from the shared cluster-wide sequence so rvs stay
         #: globally unique and monotonic across shards.  ``self._rv``
         #: remains this store's high-water mark (the last rv it
-        #: allocated or replayed); the fastdrain batch allocators and
-        #: the zero-copy status lane assume local allocation and are
-        #: disabled while a source is attached.
+        #: allocated or replayed); the fastdrain batch allocators
+        #: assume local allocation and are disabled while a source is
+        #: attached.
         self._rv_source = rv_source
         #: test-only injected regression (`--dst-bug shard-void-leak`):
         #: a failed write's rollback skips the shared-sequence void
@@ -785,9 +690,7 @@ class ResourceStore:
         into one batched write landed before the *ack* but after the
         per-op events; a watcher that got ahead of a crash in that
         window is healed by the future-rv Expired in :meth:`watch`.
-        ``save_file`` compacts the log behind each snapshot.  Attaching
-        disables the zero-copy status lane — spliced-in-place statuses
-        would bypass the log."""
+        ``save_file`` compacts the log behind each snapshot."""
         with self._mut:
             self._wal = wal
 
@@ -1257,18 +1160,7 @@ class ResourceStore:
         the read-only handed-out-by-reference contract (_emit /
         apply_status_batch); used by the informer reflector, whose
         consumers never mutate (a deep copy of 1M pods per re-list was
-        most of the e2e setup cost).  Default stays deep-copied.
-
-        Tearing caveat (ADVICE r04 #4): the zero-copy commit lane
-        (status_lane / the in-place branch of apply_status_batch)
-        replaces a stored object's ``status`` and ``resourceVersion``
-        as two separate dict writes.  A ``copy=False`` snapshot read
-        OUTSIDE the store mutex can therefore observe the new status
-        paired with the old resourceVersion (each field is internally
-        consistent; the pair is not).  The lane only activates when no
-        status-interested watcher exists, so the exposed readers are
-        the rare debug/catch-up consumers — use the default deep copy
-        anywhere the status/resourceVersion pairing matters."""
+        most of the e2e setup cost).  Default stays deep-copied."""
         out = copy_json if copy else (lambda o: o)
         with self._mut:
             st = self._state(kind)
@@ -1623,8 +1515,7 @@ class ResourceStore:
         ``copy_result=False`` returns the stored instance itself (the
         handed-out-by-reference contract: treat as immutable) — the
         device drain's bulk path adopts results into its row mirrors,
-        where the instance is exactly what the fused commit wants and
-        a 1M-row create wave spends most of its time deep-copying."""
+        and a 1M-row create wave spends most of its time deep-copying."""
         meta = new.setdefault("metadata", {})
         old = st.objects.get(key)
         if old is not None:
@@ -1786,16 +1677,6 @@ class ResourceStore:
                         f"resourceVersion {since_rv} predates the store's "
                         f"history floor {self._history_floor}"
                     )
-                if since_rv < st.inplace_rv and status_interest:
-                    # the zero-copy lane left a gap below this version.
-                    # Yield the lane for a while so this consumer's
-                    # list-then-watch retry finds real history instead
-                    # of racing a continuously-advancing marker.
-                    st.lane_cooloff = time.monotonic() + 30.0
-                    raise Expired(
-                        f"resourceVersion {since_rv} is too old "
-                        "(compacted by the in-place commit lane)"
-                    )
                 hist = list(st.history)
                 if hist and hist[0].rv > since_rv + 1 and len(hist) == st.history.maxlen:
                     raise Expired(f"resourceVersion {since_rv} is too old")
@@ -1855,38 +1736,9 @@ class ResourceStore:
             if (
                 _FAST is not None
                 and not status_indexed
-                and self._wal is None  # in-place splices bypass the log
-                # the C committers allocate rvs locally from a start
+                # the C committer allocates rvs locally from a start
                 # value; a shared rv source (sharded store) must see
-                # every allocation, so both fast lanes stand down
-                and self._rv_source is None
-                and exclude is not None
-                and all(
-                    w is exclude or w.stopped or not w.status_interest
-                    for w in st.watchers
-                )
-                and time.monotonic() >= st.lane_cooloff
-            ):
-                # zero-copy lane: the only live watcher is the caller's
-                # own (excluded) one, so these events have no consumer —
-                # mutate stored objects in place, record the gap marker
-                # instead of history (see _TypeState.inplace_rv)
-                before_rv = self._rv
-                out, self._rv = _FAST.status_commit_inplace(
-                    st.objects, items, self._rv, namespaced
-                )
-                if self._rv != before_rv:
-                    # only a batch that actually mutated something
-                    # leaves a history gap — an all-missing batch must
-                    # not force consumers into spurious re-lists
-                    st.inplace_rv = self._rv
-                    self._audit.append(
-                        ("patch-status-batch", f"{kind}:{len(items)}", None)
-                    )
-                return out
-            if (
-                _FAST is not None
-                and not status_indexed
+                # every allocation
                 and self._rv_source is None
             ):
                 out, evs, self._rv = _FAST.status_commit(
@@ -2078,26 +1930,6 @@ class ResourceStore:
         if meta.get("finalizers"):
             del meta["finalizers"]
         return gone
-
-    def status_lane(self, kind: str, exclude: Optional[Watcher]):
-        """Grant the caller the zero-copy status-commit lane for one
-        chunk: a context manager yielding a :class:`StatusLane` (the
-        stored-objects dict plus the resourceVersion counter) with the
-        store mutex held, or ``None`` when the lane conditions do not
-        hold (a live watcher with status interest, a status index, or
-        the post-Expired cooloff).
-
-        This powers the fused native drain
-        (``kwok_fastdrain.fused_group`` via
-        ``DeviceStagePlayer._drain_tick``): build + commit + confirm in
-        one pass over each row.  The contract matches the in-place
-        branch of :meth:`apply_status_batch` — stored objects are
-        mutated in place, no events are delivered, and the history gap
-        marker (``inplace_rv``) expires any watcher resuming from an
-        older resourceVersion.  The grantee must only splice ``status``
-        and ``metadata.resourceVersion`` (from ``lane.rv``, one bump
-        per object) on instances it verified are the stored ones."""
-        return _LaneGrant(self, kind, exclude)
 
     def bulk(
         self,
@@ -2565,9 +2397,7 @@ class ResourceStore:
         ``copy=False`` shares the stored instances (the read-only
         handed-out-by-reference contract): the rv-consistent cut is
         taken under one brief mutex hold and serialization happens
-        outside the lock — the online-snapshot path.  Only safe while
-        the in-place status lane cannot run (a WAL is attached, or the
-        caller otherwise knows no lane grants are live)."""
+        outside the lock — the online-snapshot path."""
         out = copy_json if copy else (lambda o: o)
         with self._mut:
             types = []
@@ -2668,16 +2498,13 @@ class ResourceStore:
         """Snapshot to ``path`` with an embedded integrity checksum,
         then compact the WAL behind it.
 
-        Online consistent cut: with a WAL attached every mutation path
-        is copy-on-write (the in-place status lane is disabled), so the
-        state can be captured as shared references under one brief
-        mutex hold and serialized OUTSIDE the lock — writers are never
-        stalled for the disk write.  Without a WAL the in-place lane
-        may mutate stored objects, so the deep-copy capture is kept."""
+        Online consistent cut: every mutation path is copy-on-write,
+        so the state can be captured as shared references under one
+        brief mutex hold and serialized OUTSIDE the lock — writers are
+        never stalled for the disk write."""
         from kwok_tpu.cluster.wal import write_state_file
 
-        # kwoklint: disable=guarded-by — attach-once WAL slot, GIL-atomic identity read
-        state = self.dump_state(copy=self._wal is None)
+        state = self.dump_state(copy=False)
         write_state_file(path, state)
         self.compact_wal(int(state["resourceVersion"]))
 
@@ -2876,8 +2703,7 @@ class ResourceStore:
             self._history_floor = max(self._history_floor, max(floor, 0))
             recovered_rv = self._rv
             # every rv between the effective floor and the highest
-            # observed one corresponds to exactly one logged commit
-            # (the in-place lane is disabled while a WAL is attached);
+            # observed one corresponds to exactly one logged commit;
             # a hole is a lost (or never-durable) record — report it,
             # never guess
             base = max(boot_floor, reset_rv)

@@ -448,9 +448,7 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
         # live writers are never stalled for the disk write
         from kwok_tpu.cluster.wal import write_state_file
 
-        # (without a WAL the in-place status lane may mutate stored
-        # objects — keep the deep-copy capture there)
-        state = store.dump_state(copy=not args.wal_file)
+        state = store.dump_state(copy=False)
         try:
             write_state_file(args.state_file, state)
             if pitr is not None:
@@ -488,7 +486,7 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
         for i in range(store.shard_count):
             shard = store.shard_lane(i)
             g = store.resource_version
-            state = shard.dump_state(copy=not args.wal_file)
+            state = shard.dump_state(copy=False)
             if int(state.get("resourceVersion") or 0) > g:
                 print(
                     f"snapshot save deferred [shard {i}]: write raced "

@@ -68,9 +68,8 @@ _stage = _telemetry.stage
 _COMMIT_ROWS = ResourceStore.WATCH_HIGH_WATER // 4
 
 #: one observation a commit request, valued with the rows it committed:
-#: ``path`` is ``batch`` (``apply_status_batch``, and the fused lane that
-#: stands in for it in process), ``delete`` (``apply_delete_batch``) or
-#: ``slow`` (``_drain_slow``'s bulk).
+#: ``path`` is ``batch`` (``apply_status_batch``), ``delete``
+#: (``apply_delete_batch``) or ``slow`` (``_drain_slow``'s bulk).
 #: ``_sum`` over ``kwok_stage_transitions_total`` is the share of played
 #: rows each path carried, ``_sum`` over ``_count`` the rows a request
 _H_COMMIT_ROWS = _telemetry.histogram(
@@ -224,8 +223,7 @@ class DeviceStagePlayer:
         # in-process stores hand back stored instances from bulk
         # (immutable by contract): the slow-path drain adopts them into
         # row mirrors, so skipping the deep copy of every result is the
-        # create wave's single biggest win — and instance adoption is
-        # what re-arms the fused path's pointer-equality check
+        # create wave's single biggest win
         self._bulk_no_copy = False
         if hasattr(store, "bulk"):
             import inspect
@@ -240,18 +238,6 @@ class DeviceStagePlayer:
         #: (identity + env funcs; both row-stable) — dropped with the
         #: render cache on any identity change
         self._vals_cache: List[Optional[Dict]] = [None] * capacity
-        #: row-indexed store keys ((ns-or-default, name), the store's
-        #: own convention) for the fused drain: the one-pass native
-        #: build+commit+confirm (fused_group) probes the stored-objects
-        #: dict directly instead of shipping (ns, name, status) tuples
-        self._store_keys: List[Optional[Tuple[str, str]]] = [None] * capacity
-        self._fused = (
-            _FAST is not None
-            and hasattr(_FAST, "fused_group")
-            and isinstance(store, ResourceStore)
-            and hasattr(store, "status_lane")
-        )
-        self._namespaced: Optional[bool] = None
         #: in-flight macro-tick (stages device array, t0_ms, dt) for
         #: the overlapped step_pipelined path
         self._inflight = None
@@ -341,8 +327,6 @@ class DeviceStagePlayer:
             self._written_rv.extend([None] * (cap - len(self._written_rv)))
         if len(self._vals_cache) < cap:
             self._vals_cache.extend([None] * (cap - len(self._vals_cache)))
-        if len(self._store_keys) < cap:
-            self._store_keys.extend([None] * (cap - len(self._store_keys)))
 
     # ------------------------------------------------------------ event ingest
 
@@ -377,8 +361,6 @@ class DeviceStagePlayer:
                 del self._rows[key]
                 if row < len(self._written_rv):
                     self._written_rv[row] = None
-                if row < len(self._store_keys):
-                    self._store_keys[row] = None
                 self._drop_render_cache(row)
             if self.on_delete is not None:
                 self.on_delete(obj)
@@ -399,8 +381,6 @@ class DeviceStagePlayer:
             row = self.sim.admit(obj)
             self._rows[key] = row
             self._grow_row_arrays()
-            if self._fused:
-                self._store_keys[row] = self._store_key(meta)
             self._drop_render_cache(row)
         else:
             old = self.sim.objects[row]
@@ -832,26 +812,10 @@ class DeviceStagePlayer:
                     row_vals_cb = (
                         lambda obj, _p=plan: _p.row_vals(obj, self.funcs_for(obj))
                     )
-                    # one-pass fused drain: sound when timestamps make
-                    # no-ops impossible (has_now) and the merge is a
-                    # wholesale replace / top-level dict update
-                    # (all_top_plain, no nulls — the C loop slow-paths
-                    # anything else, so gating here keeps nested-dict
-                    # templates on the staged path that merges natively)
-                    fused_ok = (
-                        self._fused
-                        and plan.has_now
-                        and not plan.has_null
-                        and plan.all_top_plain
-                    )
                     for k in range(0, len(group), chunk):
                         if k and self._done.is_set() and self._past_abort_grace():
                             break
                         sub = group[k : k + chunk]
-                        if fused_ok and self._fused_chunk(
-                            sub, s_idx, comp, bound, plan, row_vals_cb, t_ms, slow
-                        ):
-                            continue
                         with _stage(self.kind, "host_build") as sp:
                             noops, slow_rows = _FAST.fast_group(
                                 objects,
@@ -1028,68 +992,6 @@ class DeviceStagePlayer:
         self.transitions += n_ok
         _H_COMMIT_ROWS.observe(n_ok, self.kind, "delete")
         return refused
-
-    def _fused_chunk(
-        self, sub, s_idx, comp, bound, plan, row_vals_cb, t_ms, slow
-    ) -> bool:
-        """One chunk through the fused native drain (build + in-place
-        store commit + confirm in a single C pass, the store's mutex
-        held via the granted zero-copy lane).  Returns False when the
-        lane is unavailable (live status watchers / status index /
-        cooloff) so the caller falls back to the staged path.  Called
-        with ``self._mut`` held (same order as the staged commit:
-        player lock, then store lock)."""
-        with self.store.status_lane(
-            self.kind, self._informer.active_watcher
-        ) as lane:
-            if lane is None:
-                return False
-            with _stage(self.kind, "host_build") as sp:
-                # reserve the chunk's whole rv range up front: if the C
-                # pass dies mid-chunk (MemoryError), the rows it already
-                # stamped must never collide with rvs a later commit
-                # re-issues — rv gaps are legal (the real apiserver's rvs
-                # are sparse), duplicates are not
-                rv_start = lane.rv
-                lane.rv = rv_start + len(sub)
-                n_ok, new_rv, slow_rows, release_rows, _skipped = _FAST.fused_group(
-                    self.sim.objects,
-                    self._store_keys,
-                    sub,
-                    s_idx,
-                    comp,
-                    bound,
-                    self._vals_cache,
-                    row_vals_cb,
-                    int(plan.all_top_plain),
-                    plan.top_plain,
-                    lane.objects,
-                    rv_start,
-                    self._written_rv,
-                )
-                # feed the actual consumption back: the C pass returned
-                # normally, so exactly new_rv - rv_start rows were stamped
-                # (the full reservation only matters on the exception
-                # path).  A fully-skipped chunk (n_ok == 0, all rows
-                # stale/slow/released) thus no longer advances store._rv
-                # or sets the inplace_rv history-gap marker — which would
-                # spuriously Expire watchers over a commit that wrote
-                # nothing (ADVICE r5 #1).
-                lane.rv = new_rv
-            self.t_build += sp.elapsed
-        self.transitions += n_ok
-        self.patches += n_ok
-        if n_ok:
-            _H_COMMIT_ROWS.observe(n_ok, self.kind, "batch")
-        objects = self.sim.objects
-        for row in slow_rows:
-            if objects[row] is not None:
-                slow.append(self._make_transition(row, s_idx, t_ms))
-        for row in release_rows:
-            obj = objects[row]
-            if obj is not None:
-                self._release_locked(self._key(obj))
-        return True
 
     def _confirm_native_locked(
         self, results, fast_rows, fast_items, own_cache: bool
@@ -1600,24 +1502,7 @@ class DeviceStagePlayer:
             self.sim.release(row)
             if row < len(self._written_rv):
                 self._written_rv[row] = None
-            if row < len(self._store_keys):
-                self._store_keys[row] = None
             self._drop_render_cache(row)
-
-    def _store_key(self, meta: dict) -> Tuple[str, str]:
-        """The store's own objects-dict key for this object (namespace
-        defaulting per the kind's scoping)."""
-        ns_flag = self._namespaced
-        if ns_flag is None:
-            try:
-                ns_flag = self.store.resource_type(self.kind).namespaced
-            except Exception:  # noqa: BLE001 — kind not registered yet
-                ns_flag = True
-            else:
-                self._namespaced = ns_flag
-        if ns_flag:
-            return (meta.get("namespace") or "default", meta.get("name") or "")
-        return ("", meta.get("name") or "")
 
     def _refresh(
         self,

@@ -124,8 +124,7 @@ class GCController:
             inf = Informer(self.store, rt.kind)
             # status-indifferent: GC reads ownerReferences /
             # deletionTimestamp / finalizers — never status.  In-process
-            # stores then skip this watcher on status batches, which
-            # keeps the drain's zero-copy commit lane eligible (the
+            # stores then skip this watcher on status batches (the
             # "GC must not become a second drain" contract,
             # VERDICT r03 next-#6)
             inf.watch(

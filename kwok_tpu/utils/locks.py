@@ -5,8 +5,8 @@ The static half of the concurrency gate
 (``kwok_tpu/analysis/lock_order.py``) derives the
 may-hold-while-acquiring graph lexically; this is the dynamic
 complement for the holds a lexical view cannot see — locks carried
-across context-manager boundaries (``cluster/store.py`` ``_LaneGrant``
-holds the store mutex from ``__enter__`` to ``__exit__``), receivers
+across context-manager boundaries (acquired in an ``__enter__``,
+released in the ``__exit__``), receivers
 too dynamic to type, and whatever the sharded-store refactor
 (ROADMAP.md:53-82) wires up at runtime.  Modeled on what the reference
 gets from ``go test -race`` in CI (PARITY.md:175): every chaos/DST run

@@ -388,95 +388,6 @@ fail:
     return NULL;
 }
 
-/* ------------------------------------------------- status_commit_inplace */
-
-/* The zero-copy commit lane: when the store has no event consumers for
- * this batch (the only live watcher is the excluded self-consumer),
- * there is nobody to hand instances to — so the stored object is
- * mutated IN PLACE (status replaced, resourceVersion bumped) with no
- * object/metadata copies, no event allocation, and no history append.
- * The store records a gap marker instead; watch resumes older than it
- * get Expired and re-list (legal watch semantics).
- *
- *   status_commit_inplace(objects, items, rv_start, namespaced)
- *     -> (results, last_rv)
- */
-static PyObject *
-py_status_commit_inplace(PyObject *self, PyObject *args)
-{
-    PyObject *objects, *items;
-    long long rv;
-    int namespaced;
-    if (!PyArg_ParseTuple(args, "OOLp", &objects, &items, &rv, &namespaced))
-        return NULL;
-    Py_ssize_t n = PyList_GET_SIZE(items);
-    PyObject *results = PyList_New(0);
-    if (!results)
-        return NULL;
-    for (Py_ssize_t i = 0; i < n; i++) {
-        PyObject *item = PyList_GET_ITEM(items, i); /* (ns, name, status[, base rv]) */
-        PyObject *ns = PyTuple_GET_ITEM(item, 0);
-        PyObject *name = PyTuple_GET_ITEM(item, 1);
-        PyObject *status = PyTuple_GET_ITEM(item, 2);
-        PyObject *keyns;
-        if (namespaced)
-            keyns = (ns != Py_None && PyObject_IsTrue(ns)) ? ns : s_default;
-        else
-            keyns = s_empty;
-        PyObject *key = PyTuple_Pack(2, keyns, name);
-        if (!key)
-            goto fail;
-        PyObject *cur = PyDict_GetItemWithError(objects, key);
-        Py_DECREF(key);
-        if (!cur) {
-            if (PyErr_Occurred())
-                goto fail;
-            if (PyList_Append(results, Py_None) < 0)
-                goto fail;
-            continue;
-        }
-        {
-            int stale = stale_base(item, cur);
-            if (stale) {
-                if (stale < 0 || PyList_Append(results, Py_False) < 0)
-                    goto fail;
-                continue;
-            }
-        }
-        PyObject *meta = PyDict_GetItemWithError(cur, s_metadata);
-        if (!meta || !PyDict_Check(meta)) {
-            if (!PyErr_Occurred())
-                PyErr_SetString(PyExc_KeyError, "metadata");
-            goto fail;
-        }
-        rv += 1;
-        PyObject *rvs = PyUnicode_FromFormat("%lld", rv);
-        if (!rvs)
-            goto fail;
-        if (PyDict_SetItem(meta, s_resourceVersion, rvs) < 0) {
-            Py_DECREF(rvs);
-            goto fail;
-        }
-        Py_DECREF(rvs);
-        if (PyDict_SetItem(cur, s_status, status) < 0)
-            goto fail;
-        {
-            PyObject *res = Py_BuildValue("(LO)", rv, cur);
-            if (!res)
-                goto fail;
-            if (PyList_Append(results, res) < 0) {
-                Py_DECREF(res);
-                goto fail;
-            }
-            Py_DECREF(res);
-        }
-    }
-    return Py_BuildValue("(NL)", results, rv);
-fail:
-    Py_DECREF(results);
-    return NULL;
-}
-
 /* --------------------------------------------------------- filter_stale */
 
 /* parse a resourceVersion string to int; returns 0 and sets *ok=0 when
@@ -782,260 +693,6 @@ err:
     return NULL;
 }
 
-/* ----------------------------------------------------------- fused_group */
-
-/* The one-pass drain: build + in-place store commit + confirm for one
- * (stage, sig) chunk on the zero-copy lane (device_player._drain_tick;
- * the store grants the lane — its mutex held — via
- * ResourceStore.status_lane).  Fuses what fast_group + apply_status_batch
- * + confirm_batch did in three passes, so each row's dict graph is
- * touched once while hot, and the intermediate (ns, name, status)
- * tuples, results lists and the second key probe disappear.
- *
- *   fused_group(objects, keys, rows, s_idx, comp, bound, vals_cache,
- *               row_vals_cb, all_top_plain, top_plain, store_objects,
- *               rv_start, written)
- *     -> (n_ok, new_rv, slow_rows, release_rows, skipped)
- *
- * Caller guarantees (gated in device_player): plan.has_now (no no-op
- * check needed — timestamps strictly increase) and not plan.has_null
- * (merge is wholesale replace or top-level dict update).  Per row the
- * store commit applies only when the row mirror IS the stored instance
- * (``store_objects[keys[row]] is objects[row]``); a mirror gone stale
- * under a concurrent external write is skipped (counted in ``skipped``)
- * — the informer event for that write refreshes the row next tick, and
- * committing through a stale mirror would strand the transition in an
- * object the store no longer owns.  Missing keys land in release_rows
- * (NotFound).  Build failures land in slow_rows for the per-row path. */
-static PyObject *
-py_fused_group(PyObject *self, PyObject *args)
-{
-    PyObject *objects, *keys, *rows, *s_idx, *comp, *bound, *vals_cache,
-        *row_vals_cb, *top_plain, *store_objects, *written;
-    int all_top_plain;
-    long long rv;
-    if (!PyArg_ParseTuple(args, "OOOOOOOOiOOLO", &objects, &keys, &rows,
-                          &s_idx, &comp, &bound, &vals_cache, &row_vals_cb,
-                          &all_top_plain, &top_plain, &store_objects, &rv,
-                          &written))
-        return NULL;
-    Py_ssize_t n = PyList_GET_SIZE(rows);
-    long long n_ok = 0, skipped = 0;
-    PyObject *slow_rows = PyList_New(0);
-    PyObject *release_rows = PyList_New(0);
-    if (!slow_rows || !release_rows)
-        goto err;
-    Py_ssize_t n_objects = PyList_GET_SIZE(objects);
-    Py_ssize_t n_keys = PyList_GET_SIZE(keys);
-    for (Py_ssize_t i = 0; i < n; i++) {
-        /* prefetch ahead: the row list is known, so the object-list
-         * slots and the object headers for upcoming rows can start
-         * their DRAM fetches now (the drain is memory-bound at 1M
-         * rows: every row's dict graph is cold) */
-        if (i + 8 < n) {
-            PyObject *r8 = PyList_GET_ITEM(rows, i + 8);
-            Py_ssize_t v8 = PyLong_AsSsize_t(r8);
-            if (v8 >= 0 && v8 < n_objects) {
-                __builtin_prefetch(&((PyListObject *)objects)->ob_item[v8]);
-                if (v8 < n_keys)
-                    __builtin_prefetch(&((PyListObject *)keys)->ob_item[v8]);
-            }
-        }
-        if (i + 4 < n) {
-            PyObject *r4 = PyList_GET_ITEM(rows, i + 4);
-            Py_ssize_t v4 = PyLong_AsSsize_t(r4);
-            if (v4 >= 0 && v4 < n_objects)
-                __builtin_prefetch(PyList_GET_ITEM(objects, v4));
-        }
-        PyErr_Clear(); /* PyLong_AsSsize_t above cannot fail on ints */
-        PyObject *row_obj = PyList_GET_ITEM(rows, i);
-        Py_ssize_t row = PyLong_AsSsize_t(row_obj);
-        if (row < 0 && PyErr_Occurred())
-            goto err;
-        if (row >= n_objects)
-            continue;
-        PyObject *obj = PyList_GET_ITEM(objects, row);
-        if (obj == Py_None)
-            continue;
-        PyObject *key = (row < n_keys) ? PyList_GET_ITEM(keys, row) : Py_None;
-        if (key == Py_None) {
-            if (PyList_Append(slow_rows, row_obj) < 0)
-                goto err;
-            continue;
-        }
-        PyObject *cur_store = PyDict_GetItemWithError(store_objects, key);
-        if (!cur_store) {
-            if (PyErr_Occurred())
-                goto err;
-            if (PyList_Append(release_rows, row_obj) < 0)
-                goto err;
-            continue;
-        }
-        if (cur_store != obj) {
-            /* The row mirror can be a deep COPY of the stored object
-             * (slow-path patch echoes return copies): same logical
-             * state, different instance.  Under the store lock, equal
-             * resourceVersions prove equal state — adopt the stored
-             * instance into the mirror (re-syncing future rounds to
-             * pointer equality) and commit through it.  A differing rv
-             * is a genuinely stale mirror (concurrent external write):
-             * skip; the informer event refreshes the row. */
-            PyObject *om = PyDict_GetItemWithError(obj, s_metadata);
-            PyObject *sm = PyDict_GetItemWithError(cur_store, s_metadata);
-            if (PyErr_Occurred())
-                goto err;
-            PyObject *orv = om && PyDict_Check(om)
-                                ? PyDict_GetItemWithError(om, s_resourceVersion)
-                                : NULL;
-            PyObject *srv = sm && PyDict_Check(sm)
-                                ? PyDict_GetItemWithError(sm, s_resourceVersion)
-                                : NULL;
-            if (PyErr_Occurred())
-                goto err;
-            if (!orv || !srv || !PyUnicode_Check(orv) ||
-                !PyUnicode_Check(srv) || PyUnicode_Compare(orv, srv) != 0) {
-                if (PyErr_Occurred())
-                    goto err;
-                skipped++;
-                continue;
-            }
-            Py_INCREF(cur_store);
-            if (PyList_SetItem(objects, row, cur_store) < 0) /* steals */
-                goto err;
-            obj = cur_store;
-        }
-        PyObject *patch; /* owned */
-        if (comp == Py_None) {
-            patch = bound;
-            Py_INCREF(patch);
-        } else {
-            if (row >= PyList_GET_SIZE(vals_cache)) {
-                PyErr_SetString(PyExc_IndexError,
-                                "vals_cache shorter than row index");
-                goto err;
-            }
-            PyObject *rowc = PyList_GET_ITEM(vals_cache, row);
-            if (rowc == Py_None) {
-                rowc = PyDict_New();
-                if (!rowc)
-                    goto err;
-                Py_INCREF(rowc);
-                if (PyList_SetItem(vals_cache, row, rowc) < 0) {
-                    Py_DECREF(rowc);
-                    goto err;
-                }
-                Py_DECREF(rowc);
-            }
-            PyObject *vals = PyDict_GetItemWithError(rowc, s_idx);
-            if (!vals) {
-                if (PyErr_Occurred())
-                    goto err;
-                vals = PyObject_CallFunctionObjArgs(row_vals_cb, obj, NULL);
-                if (!vals) {
-                    PyErr_Clear();
-                    if (PyList_Append(slow_rows, row_obj) < 0)
-                        goto err;
-                    continue;
-                }
-                if (PyDict_SetItem(rowc, s_idx, vals) < 0) {
-                    Py_DECREF(vals);
-                    goto err;
-                }
-                Py_DECREF(vals);
-            }
-            patch = build_node(comp, vals);
-            if (!patch) {
-                PyErr_Clear();
-                if (PyList_Append(slow_rows, row_obj) < 0)
-                    goto err;
-                continue;
-            }
-        }
-        PyObject *cur = PyDict_GetItemWithError(obj, s_status);
-        if (!cur && PyErr_Occurred()) {
-            Py_DECREF(patch);
-            goto err;
-        }
-        if (cur == Py_None)
-            cur = NULL;
-        PyObject *new_status; /* owned */
-        if (!cur || (PyDict_Check(cur) && PyDict_GET_SIZE(cur) == 0)) {
-            new_status = patch;
-            Py_INCREF(new_status);
-        } else if (all_top_plain && PyDict_Check(cur)) {
-            int subset = 1;
-            Py_ssize_t pos = 0;
-            PyObject *k, *v;
-            while (PyDict_Next(cur, &pos, &k, &v)) {
-                int in = PySet_Contains(top_plain, k);
-                if (in < 0) {
-                    Py_DECREF(patch);
-                    goto err;
-                }
-                if (!in) {
-                    subset = 0;
-                    break;
-                }
-            }
-            if (subset) {
-                new_status = patch;
-                Py_INCREF(new_status);
-            } else {
-                new_status = PyDict_Copy(cur);
-                if (!new_status || PyDict_Update(new_status, patch) < 0) {
-                    Py_XDECREF(new_status);
-                    Py_DECREF(patch);
-                    goto err;
-                }
-            }
-        } else {
-            /* non-dict or mixed shapes are excluded by the caller's
-             * gate (not has_null, all_top_plain) — but a hand-mutated
-             * status can still surprise; send it to the slow path */
-            Py_DECREF(patch);
-            if (PyList_Append(slow_rows, row_obj) < 0)
-                goto err;
-            continue;
-        }
-        Py_DECREF(patch);
-        /* in-place commit: bump rv, splice status — the mirror IS the
-         * stored instance (checked above), so there is no confirm pass */
-        PyObject *meta = PyDict_GetItemWithError(obj, s_metadata);
-        if (!meta || !PyDict_Check(meta)) {
-            Py_DECREF(new_status);
-            if (PyErr_Occurred())
-                goto err;
-            continue;
-        }
-        rv += 1;
-        PyObject *rvs = PyUnicode_FromFormat("%lld", rv);
-        if (!rvs) {
-            Py_DECREF(new_status);
-            goto err;
-        }
-        if (PyDict_SetItem(meta, s_resourceVersion, rvs) < 0 ||
-            PyDict_SetItem(obj, s_status, new_status) < 0) {
-            Py_DECREF(rvs);
-            Py_DECREF(new_status);
-            goto err;
-        }
-        Py_DECREF(new_status);
-        if (row < PyList_GET_SIZE(written)) {
-            if (PyList_SetItem(written, row, rvs) < 0) /* steals rvs */
-                goto err;
-        } else {
-            Py_DECREF(rvs);
-        }
-        n_ok++;
-    }
-    return Py_BuildValue("(LLNNL)", n_ok, rv, slow_rows, release_rows,
-                         skipped);
-err:
-    Py_XDECREF(slow_rows);
-    Py_XDECREF(release_rows);
-    return NULL;
-}
-
 /* -------------------------------------------------------- confirm_batch */
 
 /* missing-treated-as-None equality with a pointer shortcut: the store's
@@ -1235,8 +892,6 @@ py_confirm_batch(PyObject *self, PyObject *args)
             }
             Py_DECREF(key);
         }
-        if (old == new_obj)
-            continue; /* in-place lane: the row mirror IS the store's */
         if (old == Py_None)
             continue;
         PyObject *om = PyDict_GetItemWithError(old, s_metadata);
@@ -1354,10 +1009,6 @@ static PyMethodDef Methods[] = {
      "filter_stale(evs, rows, written) -> fresh events"},
     {"cache_apply", py_cache_apply, METH_VARARGS,
      "cache_apply(cache, evs) -> None"},
-    {"fused_group", py_fused_group, METH_VARARGS,
-     "fused_group(objects, keys, rows, s_idx, comp, bound, vals_cache, "
-     "row_vals_cb, all_top_plain, top_plain, store_objects, rv_start, "
-     "written) -> (n_ok, new_rv, slow_rows, release_rows, skipped)"},
     {"fast_group", py_fast_group, METH_VARARGS,
      "fast_group(objects, rows, s_idx, comp, bound, vals_cache, "
      "row_vals_cb, check_noop, has_null, all_top_plain, top_plain, "
@@ -1365,9 +1016,6 @@ static PyMethodDef Methods[] = {
     {"confirm_batch", py_confirm_batch, METH_VARARGS,
      "confirm_batch(results, rows, items, objects, written, cache) -> "
      "(n_ok, releases, fallback_idx, refused_idx)"},
-    {"status_commit_inplace", py_status_commit_inplace, METH_VARARGS,
-     "status_commit_inplace(objects, items, rv_start, namespaced) -> "
-     "(results, last_rv)"},
     {NULL, NULL, 0, NULL},
 };
 

@@ -244,14 +244,6 @@ def test_store_crash_points(tmp_path):
     s.delete("Pod", "a")
 
 
-def test_wal_disables_inplace_status_lane(tmp_path):
-    s = ResourceStore()
-    s.create(pod("a"))
-    s.attach_wal(WriteAheadLog(str(tmp_path / "w.jsonl"), fsync="off"))
-    with s.status_lane("Pod", exclude=object()) as lane:
-        assert lane is None  # zero-copy splices would bypass the log
-
-
 # ------------------------------------------------------------- client retries
 
 

@@ -261,7 +261,7 @@ def test_cluster_converges_under_seeded_fault_plan(tmp_path, monkeypatch):
         done = threading.Event()
         inf = Informer(client, "ConfigMap")
         cache = inf.watch_with_cache(WatchOptions(), events, done=done)
-        assert _wait(lambda: inf.relists == 1, 15)
+        assert _wait(lambda: inf.relists == 1, 60)
 
         # acked canaries, then the seeded kill: every one must survive
         for i in range(N_CANARIES):

@@ -19,8 +19,11 @@ from kwok_tpu.ctl.scale import scale
 from kwok_tpu.stages import default_node_stages, default_pod_stages
 
 
-def wait_until(cond, budget=10.0):
-    deadline = time.monotonic() + budget
+def wait_until(cond):
+    """One budget for every wait here: it returns as soon as ``cond``
+    holds, so a healthy run is no slower, and six test workers
+    compiling at once overran the 10-20 s each had before."""
+    deadline = time.monotonic() + 60.0
     while time.monotonic() < deadline:
         if cond():
             return True
@@ -228,43 +231,47 @@ def test_device_backend_lease_lanes_under_churn():
         scale(store, "node", 40)
         assert wait_until(
             lambda: store.count("Lease") == 40
-            and len(ctr.node_leases.held_nodes()) == 40,
-            20.0,
+            and len(ctr.node_leases.held_nodes()) == 40
         )
         lane = ctr.node_leases._lane
         assert lane is not None
-        assert wait_until(lambda: len(lane) == 40, 10.0), (
+        assert wait_until(lambda: len(lane) == 40), (
             "held leases not migrated onto the device lane"
         )
         # churn: add nodes mid-flight, delete some
         scale(store, "node", 10, name_prefix="late")
         for i in range(5):
             store.delete("Node", f"node-{i}")
-        assert wait_until(lambda: len(lane) == 45, 20.0), len(lane)
+        assert wait_until(lambda: len(lane) == 45), len(lane)
 
         # liveness: every remaining lease keeps renewing — renewTime
         # advances for all (budget absorbs XLA compile stalls on a
         # loaded machine; the cadence contract is checked via lag below)
-        before = {
-            (ln.get("metadata") or {}).get("name"): (ln.get("spec") or {}).get(
-                "renewTime"
-            )
-            for ln in store.list("Lease")[0]
-            if (ln.get("metadata") or {}).get("name") not in {
-                f"node-{i}" for i in range(5)
-            }
-        }
+        gone = {f"node-{i}" for i in range(5)}
 
-        def all_renewed():
-            after = {
+        def renew_times():
+            return {
                 (ln.get("metadata") or {}).get("name"): (ln.get("spec") or {}).get(
                     "renewTime"
                 )
                 for ln in store.list("Lease")[0]
+                if (ln.get("metadata") or {}).get("name") not in gone
             }
+
+        def all_renewed_since(before):
+            after = renew_times()
             return all(after.get(k) != v for k, v in before.items())
 
-        assert wait_until(all_renewed, 15.0), "leases stopped renewing"
+        before = renew_times()
+        assert wait_until(lambda: all_renewed_since(before)), "leases stopped renewing"
+        # the cadence below is that of a lane that runs: the first
+        # round's samples hold the tick thread's stalls while XLA
+        # compiled the players' programs (7 s for one tick with six
+        # test workers compiling beside it), which no lease of a warm
+        # daemon waits for.  Drop them and take a round of its own.
+        lane.renew_lags.clear()
+        before = renew_times()
+        assert wait_until(lambda: all_renewed_since(before)), "leases stopped renewing"
         # cadence: lag past each scheduled renew time (wall-anchored)
         # stays inside the expiry margin (duration 4s - interval 1s =
         # 3s of headroom) — lag absorbs tick-loop slowness on a loaded

@@ -89,33 +89,43 @@ def test_two_process_global_mesh_parity():
     assert all(n > 0 for n in locals_)
 
 
-def spawn_device_kwok(server_url, ident, lease_s=4):
-    return subprocess.Popen(
-        [
-            sys.executable,
-            "-m",
-            "kwok_tpu.cmd.kwok",
-            "--server",
-            server_url,
-            "--id",
-            ident,
-            "--backend",
-            "device",
-            "--node-lease-duration-seconds",
-            str(lease_s),
-            "--server-address",
-            "",
-            # sharding needs BOTH instances active: node-lease
-            # ownership partitions the rows; process-level leader
-            # election would park one instance as a standby
-            "--no-leader-elect",
-        ],
-        stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT,
-        text=True,
-        env={**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu"},
-        start_new_session=True,
-    )
+def spawn_device_kwok(server_url, ident, work_dir, lease_s=4):
+    """A device-backend daemon with what it leaves behind kept under
+    ``work_dir``: its own compile cache (a checkout's shared
+    ``.jax_compile_cache/`` answers with entries of other runs, and
+    XLA:CPU logs pages for each one it loads) and its output in a file
+    (nobody reads a pipe here, and a daemon blocks on a full one)."""
+    with open(os.path.join(work_dir, f"{ident}.log"), "w") as log:
+        return subprocess.Popen(
+            [
+                sys.executable,
+                "-m",
+                "kwok_tpu.cmd.kwok",
+                "--server",
+                server_url,
+                "--id",
+                ident,
+                "--backend",
+                "device",
+                "--node-lease-duration-seconds",
+                str(lease_s),
+                "--server-address",
+                "",
+                # sharding needs BOTH instances active: node-lease
+                # ownership partitions the rows; process-level leader
+                # election would park one instance as a standby
+                "--no-leader-elect",
+            ],
+            stdout=log,
+            stderr=subprocess.STDOUT,
+            env={
+                **os.environ,
+                "PYTHONPATH": REPO,
+                "JAX_PLATFORMS": "cpu",
+                "JAX_COMPILATION_CACHE_DIR": os.path.join(work_dir, "jax_cache"),
+            },
+            start_new_session=True,
+        )
 
 
 def make_node(name):
@@ -138,13 +148,13 @@ def make_pod(name, node):
     }
 
 
-def test_device_backend_shards_rows_and_survives_kill():
+def test_device_backend_shards_rows_and_survives_kill(tmp_path):
     """Two device-backend daemons split the nodes by lease ownership
     (each simulates only its own rows); killing one hands its rows to
     the survivor, which keeps driving them."""
     store = ResourceStore()
     with APIServer(store) as srv:
-        a = spawn_device_kwok(srv.url, "kwok-a")
+        a = spawn_device_kwok(srv.url, "kwok-a", str(tmp_path))
         b = None
         try:
             # phase 1: A owns the first node alone
@@ -162,7 +172,7 @@ def test_device_backend_shards_rows_and_survives_kill():
             # phase 2: B joins; new nodes land on B (A defers to B's
             # lease or vice versa — whichever grabs first, ownership is
             # EXCLUSIVE, which is the sharding invariant)
-            b = spawn_device_kwok(srv.url, "kwok-b")
+            b = spawn_device_kwok(srv.url, "kwok-b", str(tmp_path))
             time.sleep(2)
             for i in range(1, 5):
                 store.create(make_node(f"n{i}"))

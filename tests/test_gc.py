@@ -264,25 +264,32 @@ def test_live_cluster_owner_cascade_through_kcm_daemon(tmp_path, monkeypatch):
         kwokctl_main(["--name", name, "delete", "cluster"])
 
 
-def test_status_indifferent_gc_keeps_zero_copy_lane():
-    """A running GCController must not disable the drain's zero-copy
-    commit lane: its watches declare status indifference, so a status
-    batch excluded to its own writer still takes the in-place lane and
-    delivers nothing to GC."""
+def test_status_indifferent_gc_is_handed_no_status_batch(monkeypatch):
+    """A running GCController must not become a second drain: its
+    watches declare status indifference, so a status batch hands its
+    watcher nothing, while every other event still reaches it."""
     import time as _time
 
     store = ResourceStore()
     gc = GCController(store, resync_s=0.2).start()
     try:
         _time.sleep(0.5)  # GC informers subscribe
-        store.create(make_pod("p0"))
-        _time.sleep(0.3)  # the ADDED event reaches GC's watcher
-        w = store.watch("Pod")
         st = store._state("Pod")
-        inst = st.objects[("default", "p0")]
-        out = store.apply_status_batch(
-            "Pod", [("default", "p0", {"phase": "Running"})], exclude=w
-        )
-        assert out[0][1] is inst, "in-place lane must stay eligible with GC on"
+        (gc_watcher,) = [w for w in st.watchers if not w.status_interest]
+        handed = []
+        for name in ("_push", "_push_batch"):
+            monkeypatch.setattr(
+                gc_watcher, name, lambda evs, _name=name: handed.append(_name)
+            )
+        store.create(make_pod("p0"))
+        assert handed == ["_push"]
+        other = store.watch("Pod")
+        out = store.apply_status_batch("Pod", [("default", "p0", {"phase": "Running"})])
+        assert out[0][1]["status"] == {"phase": "Running"}
+        assert [ev.rv for ev in other.drain()] == [out[0][0]]
+        assert handed == ["_push"], "a status batch reached the GC's watcher"
+        store.patch("Pod", "p0", {"metadata": {"labels": {"a": "b"}}}, "merge",
+                    namespace="default")
+        assert handed == ["_push", "_push"]
     finally:
         gc.stop()

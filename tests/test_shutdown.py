@@ -26,11 +26,7 @@ from kwok_tpu.stages import load_builtin
 N = 20000
 
 class SlowStore(ResourceStore):
-    # status commits crawl; the zero-copy lane is denied, so the drain
-    # takes the staged path and a macro-tick outlives any bounded grace
-    def status_lane(self, kind, exclude):
-        from contextlib import nullcontext
-        return nullcontext(None)
+    # status commits crawl: a macro-tick outlives any bounded grace
     def apply_status_batch(self, kind, items, exclude=None):
         time.sleep(1.0)
         return super().apply_status_batch(kind, items, exclude=exclude)

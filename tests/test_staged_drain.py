@@ -1,8 +1,9 @@
-"""Fused native drain (kwok_fastdrain.fused_group + store.status_lane):
-the one-pass build/commit/confirm must preserve the staged pipeline's
-store-facing semantics (reference hot loop:
-pkg/kwok/controllers/pod_controller.go:196-360 — per-object patch with
-per-write resourceVersion, NotFound releasing the object)."""
+"""The staged drain against an in-process store (build, one
+``apply_status_batch``, confirm; a refused row played as a merge
+patch), with the native build/confirm loops and with their Python twins
+(reference hot loop: pkg/kwok/controllers/pod_controller.go:196-360 —
+per-object patch with per-write resourceVersion, NotFound releasing the
+object)."""
 
 import time
 
@@ -10,16 +11,30 @@ import pytest
 
 from kwok_tpu.cluster.informer import WatchOptions
 from kwok_tpu.cluster.store import ResourceStore
-from kwok_tpu.controllers.device_player import DeviceStagePlayer, _FAST
+from kwok_tpu.controllers import device_player
+from kwok_tpu.controllers.device_player import DeviceStagePlayer
 from kwok_tpu.controllers.pod_controller import PodEnv
 from kwok_tpu.stages import load_builtin
 
 from tests.test_controllers import make_pod
 
-pytestmark = pytest.mark.skipif(
-    _FAST is None or not hasattr(_FAST, "fused_group"),
-    reason="native fastdrain unavailable",
+
+@pytest.fixture(
+    autouse=True,
+    params=[
+        pytest.param(
+            "native",
+            marks=pytest.mark.skipif(
+                device_player._FAST is None, reason="native fastdrain unavailable"
+            ),
+        ),
+        "python",
+    ],
 )
+def drain_loops(request, monkeypatch):
+    if request.param == "python":
+        monkeypatch.setattr(device_player, "_FAST", None)
+    return request.param
 
 
 def make_player(store, capacity=16):
@@ -46,7 +61,7 @@ def drive(player, rounds=8):
         player.step_batch(100, 10)
 
 
-def test_fused_lane_commits_and_matches_store_state():
+def test_drain_commits_and_matches_store_state():
     store = ResourceStore()
     for i in range(4):
         store.create(chaos_pod(f"p{i}"))
@@ -58,20 +73,20 @@ def test_fused_lane_commits_and_matches_store_state():
     drive(player)
     assert player.transitions >= 8  # all 4 pods cycling
     # the store's objects carry coherent status + monotonically
-    # advancing resourceVersions written by the lane
+    # advancing resourceVersions written by the batch
     for i in range(4):
         obj = store.get("Pod", f"p{i}", namespace="default")
         assert obj["status"]["phase"] in ("Running", "Failed")
         assert int(obj["metadata"]["resourceVersion"]) > 4
-        # the row mirror IS (or equals) the stored instance
+        # the row mirror equals the stored instance
         row = player._rows[("default", f"p{i}")]
         assert player.sim.objects[row]["status"] == obj["status"]
     player._done.set()
 
 
-def test_fused_lane_denied_with_live_status_watcher():
-    """A second watcher with status interest must force the staged path
-    (events preserved for the consumer)."""
+def test_a_second_status_watcher_sees_the_transitions():
+    """A second watcher with status interest is handed the events of
+    every batch the player commits."""
     store = ResourceStore()
     for i in range(2):
         store.create(chaos_pod(f"p{i}"))
@@ -83,8 +98,7 @@ def test_fused_lane_denied_with_live_status_watcher():
     time.sleep(0.2)
     drive(player)
     assert player.transitions >= 4
-    # the external watcher saw the status transitions (staged path kept
-    # delivering events)
+    # the external watcher saw the status transitions
     events = list(w._events)
     assert any(
         (ev.object.get("status") or {}).get("phase") == "Failed"
@@ -94,9 +108,9 @@ def test_fused_lane_denied_with_live_status_watcher():
     player._done.set()
 
 
-def test_fused_lane_releases_rows_gone_from_store():
+def test_drain_releases_rows_gone_from_store():
     """A row whose object vanished from the store (external delete not
-    yet drained) must be released, like the staged path's NotFound."""
+    yet drained) must be released: the batch answers None for it."""
     store = ResourceStore()
     store.create(chaos_pod("p0"))
     player = make_player(store)
@@ -111,16 +125,17 @@ def test_fused_lane_releases_rows_gone_from_store():
     store.patch("Pod", "p0", {"metadata": {"finalizers": None}}, "merge",
                 namespace="default")
     store.delete("Pod", "p0", namespace="default")
-    player.events.drain()  # discard the DELETED event: fused must cope alone
+    player.events.drain()  # discard the DELETED event: the drain must cope alone
     drive(player, 12)
     assert ("default", "p0") not in player._rows
     player._done.set()
 
 
-def test_fused_skips_stale_mirror_until_event_refreshes():
+def test_a_stale_mirror_never_overwrites_an_external_write():
     """An external write replacing the stored instance between drains:
-    the fused pass must NOT commit through the stale mirror (the store
-    keeps the external write), and the informer event re-syncs."""
+    the batch must NOT commit through the stale mirror (the store
+    refuses the row by resourceVersion and keeps the external write),
+    and the informer event re-syncs."""
     store = ResourceStore()
     store.create(chaos_pod("p0"))
     player = make_player(store)
@@ -144,10 +159,10 @@ def test_fused_skips_stale_mirror_until_event_refreshes():
     player._done.set()
 
 
-def test_fused_drain_converges_under_external_interleaving():
-    """Stress the in-place lane's sharpest edges: external writers
+def test_drain_converges_under_external_interleaving():
+    """Stress the drain's sharpest edges: external writers
     patching labels/annotations, deleting pods, and re-creating them
-    WHILE the fused drain churns.  Invariants at the end: every
+    WHILE the drain churns.  Invariants at the end: every
     surviving pod's store object is coherent (status written by some
     stage, rv monotonic), the player's mirrors equal the store state,
     and no row leaked after deletes."""

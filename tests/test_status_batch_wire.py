@@ -195,18 +195,12 @@ def test_another_writers_status_field_and_label_survive_the_commit(flavor):
     with open_store(flavor) as (store, handle):
         for name in ("raced", "quiet"):
             handle.create(make_pod(name))
-        # a watcher with status interest: an in-process store then commits
-        # through apply_status_batch (copies, events), not the in-place lane
-        watcher = handle.watch("Pod")
-        try:
-            player = make_player(handle)
-            admit_all(player, handle)
-            handle.patch("Pod", "raced", {"metadata": {"labels": {"tier": "gold"}},
-                                          "status": {"qosClass": "Burstable"}},
-                         "merge", namespace="default")
-            assert step_until(player, lambda: player.transitions >= 2)
-        finally:
-            watcher.stop()
+        player = make_player(handle)
+        admit_all(player, handle)
+        handle.patch("Pod", "raced", {"metadata": {"labels": {"tier": "gold"}},
+                                      "status": {"qosClass": "Burstable"}},
+                     "merge", namespace="default")
+        assert step_until(player, lambda: player.transitions >= 2)
         raced = handle.get("Pod", "raced", namespace="default")
         assert raced["status"]["phase"] == "Running" and raced["status"]["podIP"]
         assert raced["status"]["qosClass"] == "Burstable"

@@ -253,7 +253,7 @@ def test_index_on_non_string_field():
     assert [o["metadata"]["name"] for o in items] == ["n0"]
 
 
-# ------------------------------------------------- zero-copy commit lane
+# ------------------------------------------------------- status batch
 
 
 def _mk_pod(name):
@@ -262,14 +262,16 @@ def _mk_pod(name):
             "spec": {"nodeName": "n"}, "status": {}}
 
 
-def test_status_batch_excluded_only_watcher_takes_inplace_lane():
-    """With the only live watcher excluded, the batch mutates stored
-    objects in place: same instance, bumped rv, gap marker set, nothing
-    appended to history."""
+def test_status_batch_with_only_the_excluded_watcher_still_commits_into_history():
+    """With the only live watcher excluded, that watcher is handed
+    nothing, and the commit is what it is with anybody watching: a new
+    stored instance at the returned rv, its event in history, replayed
+    to a watch that resumes from before it."""
     from kwok_tpu.cluster.store import ResourceStore
 
     store = ResourceStore()
-    store.create(_mk_pod("p0"))
+    created = store.create(_mk_pod("p0"))
+    rv0 = int(created["metadata"]["resourceVersion"])
     w = store.watch("Pod")
     st = store._state("Pod")
     inst_before = st.objects[("default", "p0")]
@@ -278,20 +280,22 @@ def test_status_batch_excluded_only_watcher_takes_inplace_lane():
         "Pod", [("default", "p0", {"phase": "Running"})], exclude=w
     )
     rv, obj = out[0]
-    assert obj is inst_before  # mutated in place, not replaced
-    assert obj["status"] == {"phase": "Running"}
-    assert obj["metadata"]["resourceVersion"] == str(rv)
-    assert len(st.history) == hist_before  # no events recorded
-    assert st.inplace_rv == rv
     assert w.drain() == []  # nothing delivered to the excluded watcher
+    assert obj is not inst_before and inst_before["status"] == {}
+    assert st.objects[("default", "p0")] is obj
+    assert obj["status"] == {"phase": "Running"}
+    assert obj["metadata"]["resourceVersion"] == str(rv) and rv > rv0
+    assert [ev.rv for ev in list(st.history)[hist_before:]] == [rv]
+    evs = store.watch("Pod", since_rv=rv0).drain()
+    assert [(ev.type, ev.rv, ev.object) for ev in evs] == [("MODIFIED", rv, obj)]
     # a GET still serves a fresh copy of the current state
     got = store.get("Pod", "p0", namespace="default")
     assert got["status"] == {"phase": "Running"} and got is not obj
 
 
-def test_status_batch_other_watcher_forces_copy_lane():
-    """Any other live watcher needs real event instances: the batch
-    must allocate new objects and deliver events."""
+def test_status_batch_delivers_to_every_watcher_but_the_excluded_one():
+    """Any other live watcher is handed the event of a newly allocated
+    object; the excluded one is not."""
     from kwok_tpu.cluster.store import ResourceStore
 
     store = ResourceStore()
@@ -308,34 +312,11 @@ def test_status_batch_other_watcher_forces_copy_lane():
     evs = other.drain()
     assert len(evs) == 1 and evs[0].object["status"] == {"phase": "Running"}
     assert mine.drain() == []  # exclusion still honored
-    assert st.inplace_rv == 0
 
 
-def test_watch_resume_below_gap_marker_expires():
-    """A resume at/below the in-place marker would cross the gapped
-    window: Expired, so the informer re-lists (reflector behavior)."""
-    import pytest
-
-    from kwok_tpu.cluster.store import Expired, ResourceStore
-
-    store = ResourceStore()
-    out = store.create(_mk_pod("p0"))
-    rv0 = int(out["metadata"]["resourceVersion"])
-    w = store.watch("Pod")
-    store.apply_status_batch(
-        "Pod", [("default", "p0", {"phase": "Running"})], exclude=w
-    )
-    with pytest.raises(Expired):
-        store.watch("Pod", since_rv=rv0)
-    # at/after the marker a resume is fine
-    marker = store._state("Pod").inplace_rv
-    w2 = store.watch("Pod", since_rv=marker)
-    assert w2.drain() == []
-
-
-def test_inplace_lane_then_external_patch_keeps_semantics():
-    """Interleaving the zero-copy lane with ordinary patches stays
-    consistent: the patch path is copy-on-write on top of the mutated
+def test_status_batch_then_external_patch_keeps_semantics():
+    """Interleaving status batches with ordinary patches stays
+    consistent: the patch path is copy-on-write on top of the batch's
     instance and emits a real event."""
     from kwok_tpu.cluster.store import ResourceStore
 
@@ -353,32 +334,45 @@ def test_inplace_lane_then_external_patch_keeps_semantics():
     assert len(evs) == 1 and evs[0].object["metadata"]["labels"] == {"a": "b"}
 
 
-def test_inplace_gap_expired_sets_lane_cooloff():
-    """A consumer racing the zero-copy lane must not be starved: the
-    Expired it receives forces the lane to yield, so its list-then-watch
-    retry succeeds against real history."""
-    import pytest
+@pytest.mark.parametrize("branch", ["native", "python"])
+def test_a_resume_from_before_a_status_batch_replays_all_of_it(branch, monkeypatch):
+    """Every row either commit branch takes is an event in history at
+    its own rv, whoever was or was not watching: a consumer that lists,
+    misses two batches (one with the committer's watcher excluded and
+    nobody else there) and resumes from its list's rv is handed each
+    committed row once, in order, and neither the missing nor the
+    refused one."""
+    from kwok_tpu.cluster import store as store_mod
 
-    from kwok_tpu.cluster.store import Expired, ResourceStore
-
-    store = ResourceStore()
-    out = store.create(_mk_pod("p0"))
-    rv0 = int(out["metadata"]["resourceVersion"])
-    w = store.watch("Pod")
-    store.apply_status_batch(
-        "Pod", [("default", "p0", {"phase": "Running"})], exclude=w
+    if branch == "python":
+        monkeypatch.setattr(store_mod, "_FAST", None)
+    elif store_mod._FAST is None:
+        pytest.skip("native fastdrain unavailable")
+    store = store_mod.ResourceStore()
+    for i in range(4):
+        store.create(_mk_pod(f"p{i}"))
+    _, listed_rv = store.list("Pod")
+    at = {p["metadata"]["name"]: p["metadata"]["resourceVersion"] for p in store.list("Pod")[0]}
+    mine = store.watch("Pod")
+    first = store.apply_status_batch(
+        "Pod",
+        [("default", f"p{i}", {"phase": "Running"}, at[f"p{i}"]) for i in range(4)]
+        + [("default", "gone", {"phase": "Running"}, "1")],
+        exclude=mine,
     )
-    with pytest.raises(Expired):
-        store.watch("Pod", since_rv=rv0)
-    st = store._state("Pod")
-    inst = st.objects[("default", "p0")]
-    # during the cooloff the lane yields: commits go copy-on-write and
-    # land in history, so the consumer's retry can resume
-    _, rv1 = store.list("Pod")
-    out = store.apply_status_batch(
-        "Pod", [("default", "p0", {"phase": "Failed"})], exclude=w
+    assert first[4] is None and mine.drain() == []
+    mine.stop()
+    second = store.apply_status_batch(
+        "Pod",
+        [
+            ("default", "p0", {"phase": "Failed"}, str(first[0][0])),
+            ("default", "p1", {"phase": "Failed"}, at["p1"]),  # stale: refused
+        ],
     )
-    assert out[0][1] is not inst  # copy lane while cooling off
-    w2 = store.watch("Pod", since_rv=rv1)
-    evs = w2.drain()
-    assert len(evs) == 1 and evs[0].object["status"] == {"phase": "Failed"}
+    assert second[1] is False
+    committed = [*first[:4], second[0]]
+    assert [rv for rv, _obj in committed] == list(range(listed_rv + 1, listed_rv + 6))
+    evs = store.watch("Pod", since_rv=listed_rv).drain()
+    assert [(ev.type, ev.rv) for ev in evs] == [("MODIFIED", rv) for rv, _obj in committed]
+    assert [ev.object for ev in evs] == [obj for _rv, obj in committed]
+    assert [ev.object["status"]["phase"] for ev in evs] == ["Running"] * 4 + ["Failed"]

@@ -217,12 +217,11 @@ def commit_rows(kind="Pod"):
     return {lv[1]: (d["sum"], d["count"]) for lv, d in fam.snapshot().items() if lv[0] == kind}
 
 
-@pytest.mark.parametrize("lane", ["fused", "staged", "wire"])
+@pytest.mark.parametrize("lane", ["staged", "wire"])
 def test_commit_rows_counts_every_committed_row_once_under_its_path(lane):
     """``kwok_status_commit_rows{kind,path}``: one observation a request,
     valued with the rows it committed.  Five pods turn Running through
-    the batch (the verb, or the in-place lane that stands in for it when
-    no watcher looks); the sixth was written by somebody else after the
+    the batch; the sixth was written by somebody else after the
     player read it, is refused there and goes through ``_drain_slow``.
     Then all six are deleted: five go by the delete batch, the third path;
     the one somebody else wrote meanwhile is refused there too."""
@@ -241,19 +240,14 @@ def test_commit_rows_counts_every_committed_row_once_under_its_path(lane):
             handle = ClusterClient(stack.enter_context(APIServer(store)).url)
         for i in range(6):
             handle.create(make_pod(f"pod-{i}", ("kwok.x-k8s.io/fake",)))
-        if lane == "staged":
-            stack.callback(store.watch("Pod").stop)  # status interest: no in-place lane
         player = make_player(handle, capacity=8)
         # as start() sets it: a deletionTimestamp is milliseconds from here
         player.sim.epoch = datetime.datetime.now(datetime.timezone.utc)
         for obj in handle.list("Pod")[0]:
             player.events.add(InformerEvent("ADDED", obj))
         player._drain_events()
-        if lane != "fused":
-            # in process with nobody watching, the in-place lane skips a row
-            # whose mirror is not the stored instance and waits for its event
-            handle.patch("Pod", "pod-5", {"status": {"qosClass": "Burstable"}}, "merge",
-                         namespace="default", subresource="status")
+        handle.patch("Pod", "pod-5", {"status": {"qosClass": "Burstable"}}, "merge",
+                     namespace="default", subresource="status")
 
         def play(until):
             for _ in range(40):
@@ -271,18 +265,13 @@ def test_commit_rows_counts_every_committed_row_once_under_its_path(lane):
             assert obj["metadata"]["deletionTimestamp"]
             player.events.add(InformerEvent("MODIFIED", obj))
         player._drain_events()
-        if lane != "fused":
-            handle.patch("Pod", "pod-4", {"metadata": {"labels": {"tier": "gold"}}}, "merge",
-                         namespace="default")
+        handle.patch("Pod", "pod-4", {"metadata": {"labels": {"tier": "gold"}}}, "merge",
+                     namespace="default")
         play(12)
         assert store.list("Pod")[0] == [] and not player._rows
     got = commit_rows()
-    if lane == "fused":
-        assert running == {"batch": (6.0, 1)}
-        assert got == {"batch": (6.0, 1), "delete": (6.0, 1)}
-    else:
-        assert running == {"batch": (5.0, 1), "slow": (1.0, 1)}
-        assert got == {"batch": (5.0, 1), "delete": (5.0, 1), "slow": (2.0, 2)}
+    assert running == {"batch": (5.0, 1), "slow": (1.0, 1)}
+    assert got == {"batch": (5.0, 1), "delete": (5.0, 1), "slow": (2.0, 2)}
     assert sum(rows for rows, _n in got.values()) == player.transitions
 
 

@@ -231,32 +231,36 @@ def test_cluster_with_tracing_component(tmp_path, monkeypatch):
             except (urllib.error.URLError, OSError):
                 return []
 
+        # the bind trace crosses processes: scheduler span + apiserver
+        # PATCH span with the same traceId.  Each component exports on
+        # its own timer, so the apiserver's half can reach the
+        # collector after the scheduler's: wait for the whole of it.
+        def bind_traces():
+            try:
+                traces = json.loads(
+                    urllib.request.urlopen(
+                        f"{turl}/api/traces?service=scheduler&limit=50", timeout=5
+                    ).read()
+                )["data"]
+            except (urllib.error.URLError, OSError):
+                return []
+            return [
+                t
+                for t in traces
+                if any(s["name"] == "schedule.bind" for s in t["spans"])
+            ]
+
+        def crossed():
+            return any(
+                {s["service"] for s in t["spans"]} >= {"scheduler", "apiserver"}
+                for t in bind_traces()
+            )
+
         deadline = time.monotonic() + 60
-        while time.monotonic() < deadline:
-            svc = services()
-            if {"apiserver", "scheduler"} <= set(svc):
-                break
+        while time.monotonic() < deadline and not crossed():
             time.sleep(0.5)
         assert {"apiserver", "scheduler"} <= set(services()), services()
-
-        # the bind trace crosses processes: scheduler span + apiserver
-        # PATCH span with the same traceId
-        traces = json.loads(
-            urllib.request.urlopen(
-                f"{turl}/api/traces?service=scheduler&limit=50", timeout=5
-            ).read()
-        )["data"]
-        bind_traces = [
-            t
-            for t in traces
-            if any(s["name"] == "schedule.bind" for s in t["spans"])
-        ]
-        assert bind_traces, [s["name"] for t in traces for s in t["spans"]]
-        crossed = any(
-            {s["service"] for s in t["spans"]} >= {"scheduler", "apiserver"}
-            for t in bind_traces
-        )
-        assert crossed, bind_traces
+        assert crossed(), bind_traces()
     finally:
         kwokctl_main(["--name", name, "delete", "cluster"])
 
