@@ -7,7 +7,10 @@ disk corruption — ``kwok_tpu/chaos/__init__.py:1``): the disk does not
 vocabulary), it *refuses*.  A :class:`FsPressure` shim installs into
 the write-ahead log's pressure seam
 (``kwok_tpu/cluster/wal.py:1`` ``WriteAheadLog.set_pressure``) and is
-consulted before every one of the log's own write/fsync syscalls:
+consulted before every one of the log's own write/fsync syscalls, and by
+the apiserver's snapshot child before each file it writes
+(``WriteAheadLog.guard_io``; ``kwok_tpu/snapshot/child.py:1``: the
+snapshot shares the log's disk, so a window refuses it too):
 
 - ``disk-full`` — every write raises ENOSPC until headroom is freed;
   releasing the WAL's preallocated emergency reserve credits the shim

@@ -119,6 +119,8 @@ __all__ = [
     "segment_files",
     "fsck",
     "fsck_sharded",
+    "encode_state",
+    "write_durable",
     "write_state_file",
     "read_state_file",
     "verify_state",
@@ -518,17 +520,35 @@ def state_crc(state: Dict[str, Any]) -> int:
     return zlib.crc32(_canonical(body)) & 0xFFFFFFFF
 
 
+def encode_state(state: Dict[str, Any]) -> bytes:
+    """The snapshot document of ``state``, serialised once: the
+    canonical body is what :func:`state_crc` sums, and the ``integrity``
+    block that carries the sum is spliced in as the body's last key."""
+    body = _canonical({k: v for k, v in state.items() if k != "integrity"})
+    crc = zlib.crc32(body) & 0xFFFFFFFF
+    sep = b"," if len(body) > 2 else b""
+    return body[:-1] + sep + b'"integrity":{"crc32":%d,"v":1}}' % crc
+
+
+def write_durable(path: str, data: bytes) -> None:
+    """Create ``path`` holding ``data`` and fsync it (no rename: the
+    caller commits the name)."""
+    fd = os.open(path, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o666)
+    try:
+        view = memoryview(data)
+        while view:
+            view = view[os.write(fd, view):]
+        os.fsync(fd)
+    finally:
+        os.close(fd)
+
+
 def write_state_file(path: str, state: Dict[str, Any]) -> None:
     """Atomically write a snapshot with an embedded integrity checksum
     (tmp → fsync → rename → directory fsync): a crash never leaves a
     truncated file, and a later bit flip is detected at load."""
-    doc = dict(state)
-    doc["integrity"] = {"v": 1, "crc32": state_crc(state)}
     tmp = f"{path}.tmp"
-    with open(tmp, "w", encoding="utf-8") as f:
-        json.dump(doc, f)
-        f.flush()
-        os.fsync(f.fileno())
+    write_durable(tmp, encode_state(state))
     os.replace(tmp, path)
     _fsync_dir(path)
 
@@ -634,6 +654,9 @@ class WriteAheadLog:
         #: for cheap compaction coverage checks; lazily rebuilt by a
         #: scan for segments discovered on open
         self._sealed_meta: Dict[str, Tuple[int, int, int]] = {}
+        #: archived path -> max rv of the segments compaction moved into
+        #: the archive since :meth:`take_archived` was last called
+        self._archived: Dict[str, int] = {}
         # a crash mid-append leaves a partial final line; appending
         # after it would MERGE the next record into the torn debris and
         # destroy it — repair (truncate the unterminated tail) before
@@ -813,6 +836,13 @@ class WriteAheadLog:
         p = self._pressure
         if p is not None:
             p.on_fsync()
+
+    def guard_io(self, nbytes: int) -> None:
+        """Ask the pressure shim for a write of ``nbytes`` and its
+        fsync, on behalf of a file that shares this log's disk (the
+        snapshot).  Takes no lock and touches no handle."""
+        self._guard_write(nbytes)
+        self._guard_fsync()
 
     def _write_frames(self, lines: List[str]) -> None:
         data = "".join(lines)
@@ -1273,15 +1303,25 @@ class WriteAheadLog:
         return remaining
 
     def _archive_segment(self, seg: str) -> None:
-        self._sealed_meta.pop(seg, None)
+        meta = self._sealed_meta.pop(seg, None)
         if self.archive_dir:
             os.makedirs(self.archive_dir, exist_ok=True)
             dst = os.path.join(self.archive_dir, os.path.basename(seg))
             os.replace(seg, dst)
             _fsync_dir(dst)
+            if meta is not None:
+                self._archived[dst] = meta[1]
         else:
             os.unlink(seg)
         _fsync_dir(seg)
+
+    def take_archived(self) -> Dict[str, int]:
+        """What this log knows of the segments it archived since the
+        last call: archived path -> the highest rv it wrote there.  The
+        archive's prune (``PitrArchive.prune(sealed=...)``) decides by it
+        without reading the segment back."""
+        out, self._archived = self._archived, {}
+        return out
 
     def reset(self) -> None:
         """Start a fresh empty log (the coverage was superseded

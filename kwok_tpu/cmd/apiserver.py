@@ -442,32 +442,61 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
 
     pitr = pitrs[0] if pitrs else None
 
-    def save_single() -> bool:
-        # online consistent cut: refs captured under one brief mutex
-        # hold (copy-on-write store), serialized outside the lock —
-        # live writers are never stalled for the disk write
-        from kwok_tpu.cluster.wal import write_state_file
+    from kwok_tpu.snapshot.child import save_in_child
+    from kwok_tpu.utils import telemetry
 
-        state = store.dump_state(copy=False)
+    # what a save costs: the whole of it (cut to prune, mostly the wait
+    # for the child that serializes), and the part of it that ran in this
+    # interpreter, which the request threads share
+    h_save = telemetry.histogram(
+        "kwok_apiserver_save_seconds",
+        help="one periodic save of the store (state file, PITR copy, WAL compaction)",
+        buckets=telemetry.DEFAULT_BUCKETS + (30.0, 60.0),
+    )
+    h_inprocess = telemetry.histogram(
+        "kwok_apiserver_save_inprocess_seconds",
+        help="one save less its wait for the snapshot child: cut, fork, renames, WAL compaction, prune",
+    )
+    waited = 0.0
+
+    def commit(lane, state, path, arch, log, which: str = "") -> bool:
+        """Every snapshot's way to the disk (kwok_tpu/snapshot/child.py):
+        a forked child serializes and fsyncs ``state``, this thread waits
+        without the GIL and renames; then ``lane``'s WAL is compacted up
+        to the snapshot's rv and the archive pruned."""
+        nonlocal waited
         try:
-            write_state_file(args.state_file, state)
-            if pitr is not None:
-                pitr.add_snapshot(state)
-            store.compact_wal(int(state["resourceVersion"]))
-            if pitr is not None:
-                pitr.prune(keep_snapshots=args.pitr_keep)
+            waited += save_in_child(
+                state,
+                path,
+                arch,
+                guard=log.guard_io if log is not None else None,
+            )
+            lane.compact_wal(int(state["resourceVersion"]))
+            if arch is not None:
+                arch.prune(
+                    keep_snapshots=args.pitr_keep,
+                    sealed=log.take_archived() if log is not None else None,
+                )
         except OSError as exc:
             # a full/failing disk cannot take a snapshot — skip this
             # tick instead of killing the daemon (the WAL keeps its
-            # coverage because compaction only retires what a durable
-            # snapshot covers)
-            print(f"snapshot save skipped: {exc}", flush=True)
+            # coverage because compaction only retires what a durable,
+            # renamed snapshot covers)
+            print(f"snapshot save skipped{which}: {exc}", flush=True)
             return False
         return True
 
+    def save_single() -> bool:
+        # online consistent cut: refs captured under one brief mutex
+        # hold (copy-on-write store), serialized outside the lock and
+        # outside this process — live writers are never stalled for it
+        return commit(
+            store, store.dump_state(copy=False), args.state_file, pitr, wal
+        )
+
     def save_shards() -> bool:
         from kwok_tpu.cluster.sharding.layout import shard_state_path
-        from kwok_tpu.cluster.wal import write_state_file
 
         workdir = os.path.dirname(os.path.abspath(args.state_file))
         # One captured horizon per shard stamps its snapshot: an rv a
@@ -479,7 +508,7 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
         # window) would archive future state under an rv-g label —
         # restore --to-rv g would then resurrect objects that did not
         # exist at g — so that shard skips this tick and retries at
-        # the next one, exactly like the full-disk skip below.
+        # the next one, exactly like the full-disk skip.
         # Records landing after a capture stay in their shard's WAL
         # (compaction stops at g).
         ok = True
@@ -496,22 +525,27 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
                 ok = False
                 continue
             state["resourceVersion"] = g
-            arch = pitrs[i] if i < len(pitrs) else None
-            try:
-                write_state_file(shard_state_path(workdir, i), state)
-                if arch is not None:
-                    arch.add_snapshot(state)
-                shard.compact_wal(g)
-                if arch is not None:
-                    arch.prune(keep_snapshots=args.pitr_keep)
-            except OSError as exc:
-                # one shard's full disk must not stop the healthy
-                # shards' snapshots — skip ITS tick only
-                print(f"snapshot save skipped [shard {i}]: {exc}", flush=True)
-                ok = False
+            # one shard's full disk must not stop the healthy shards'
+            # snapshots — commit skips ITS tick only
+            ok &= commit(
+                shard,
+                state,
+                shard_state_path(workdir, i),
+                pitrs[i] if i < len(pitrs) else None,
+                wals[i] if i < len(wals) else None,
+                which=f" [shard {i}]",
+            )
         return ok
 
-    save_once = save_shards if sharded else save_single
+    def save_once() -> bool:
+        nonlocal waited
+        waited = 0.0
+        t_save = time.perf_counter()
+        ok = save_shards() if sharded else save_single()
+        whole = time.perf_counter() - t_save
+        h_save.observe(whole)
+        h_inprocess.observe(whole - waited)
+        return ok
 
     def rearm_loop() -> None:
         # background re-arm probe: degraded mode also clears when NO
@@ -531,15 +565,6 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
     if args.wal_file:
         threading.Thread(target=rearm_loop, daemon=True).start()
 
-    from kwok_tpu.utils import telemetry
-
-    # the snapshot serializes the whole store under the request threads'
-    # GIL: how long each took, on this process's /metrics
-    h_save = telemetry.histogram(
-        "kwok_apiserver_save_seconds",
-        help="one periodic save of the store (state file, PITR copy, WAL compaction)",
-        buckets=telemetry.DEFAULT_BUCKETS + (30.0, 60.0),
-    )
     saved_rv = -1
     while not done.wait(args.save_interval):
         if args.state_file and store.resource_version != saved_rv:
@@ -547,10 +572,7 @@ def _serve(args, store, wal, wals, pitrs, sharded: bool) -> int:
             # snapshot serializes must re-trigger the next tick (and
             # the shutdown save), not be marked covered
             rv = store.resource_version
-            t_save = time.perf_counter()
-            ok = save_once()
-            h_save.observe(time.perf_counter() - t_save)
-            if ok:
+            if save_once():
                 saved_rv = rv
     if args.state_file and store.resource_version != saved_rv:
         save_once()

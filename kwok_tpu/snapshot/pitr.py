@@ -60,9 +60,11 @@ class PitrArchive:
 
     # ------------------------------------------------------------ contents
 
+    def snapshot_path(self, rv: int) -> str:
+        return os.path.join(self.root, f"{SNAP_PREFIX}{int(rv):012d}.json")
+
     def add_snapshot(self, state: Dict[str, Any]) -> str:
-        rv = int(state.get("resourceVersion", 0))
-        path = os.path.join(self.root, f"{SNAP_PREFIX}{rv:012d}.json")
+        path = self.snapshot_path(state.get("resourceVersion", 0))
         write_state_file(path, state)
         return path
 
@@ -297,11 +299,25 @@ class PitrArchive:
 
     # ------------------------------------------------------------- hygiene
 
-    def prune(self, keep_snapshots: int = 5) -> Dict[str, int]:
+    def prune(
+        self,
+        keep_snapshots: int = 5,
+        sealed: Optional[Dict[str, int]] = None,
+    ) -> Dict[str, int]:
         """Bound the archive: keep the newest ``keep_snapshots``
         snapshots, drop older ones plus any segment fully covered by
         the oldest kept snapshot (restores below it are given up —
-        deliberately, and only here)."""
+        deliberately, and only here).
+
+        ``sealed`` is ``WriteAheadLog.take_archived()`` of the log that
+        feeds this archive: the highest rv that log wrote into each
+        segment it moved here.  A segment so vouched for is not read
+        back (a scan decodes every record of it, seconds of the save
+        loop's process at a cluster's write rate); one found here with
+        no such word — after a restart, or put here by hand — is
+        scanned, and kept for ever if damaged."""
+        if sealed:
+            self._seg_max_rv.update(sealed)
         snaps = self.snapshots()
         dropped = {"snapshots": 0, "segments": 0}
         if len(snaps) > keep_snapshots:
