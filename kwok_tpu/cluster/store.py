@@ -2894,10 +2894,29 @@ class RecoveryReport:
         }
 
 
+#: one observation a request the recorder sends to the store, valued with
+#: the Events of that outcome in it: ``_sum`` counts Events, ``_count``
+#: requests.  ``kind`` is the involved object's; ``outcome`` is
+#: ``created``, ``aggregated`` (a repeat bumped ``count``) or ``dropped``
+#: (the store refused it, or the request failed)
+_H_EVENTS_RECORDED = _telemetry.histogram(
+    "kwok_events_recorded",
+    help="Events an EventRecorder sent to the store in one request",
+    buckets=(1, 4, 16, 64, 256, 1024, 4096, 16384),
+    labelnames=("kind", "outcome"),
+)
+
+
 class EventRecorder:
     """Aggregating k8s Event recorder (reference: controllers emit
     events via an EventBroadcaster, pod_controller.go:304-311; repeats
-    aggregate by bumping ``count``)."""
+    aggregate by bumping ``count``).
+
+    ``event`` records one Event now and hands back what the store made
+    of it; ``record`` sends many in one ``bulk`` and, as upstream's
+    buffered broadcaster does, drops and counts what the store refuses
+    instead of failing its caller.  ``_mut`` covers the correlation
+    cache alone, never a store round trip."""
 
     #: correlation-cache bound; oldest aggregation keys are evicted (k8s
     #: event correlators use an LRU the same way)
@@ -2918,7 +2937,8 @@ class EventRecorder:
         #: counter so Event names are seed-stable (kwok_tpu.dst)
         self._suffix = suffix or (lambda: f"{time.monotonic_ns():x}")
         self._mut = make_lock("cluster.store.EventRecorder._mut")
-        self._keys: "OrderedDict[Tuple, str]" = OrderedDict()
+        #: aggregation key -> [Event name, count as last sent]
+        self._keys: "OrderedDict[Tuple, List]" = OrderedDict()
         guarded(self, "_keys", "cluster.store.EventRecorder._mut")
 
     def _now_string(self) -> str:
@@ -2930,48 +2950,130 @@ class EventRecorder:
         )
         return t.isoformat(timespec="seconds").replace("+00:00", "Z")
 
+    def _new_event(
+        self, involved: dict, etype: str, reason: str, message: str, now: str
+    ) -> dict:
+        meta = involved.get("metadata") or {}
+        return {
+            "apiVersion": "v1",
+            "kind": "Event",
+            "metadata": {
+                "name": f"{meta.get('name', 'unknown')}.{self._suffix()}",
+                "namespace": meta.get("namespace") or "default",
+            },
+            "involvedObject": {
+                "apiVersion": involved.get("apiVersion"),
+                "kind": involved.get("kind"),
+                "name": meta.get("name"),
+                "namespace": meta.get("namespace"),
+                "uid": meta.get("uid"),
+            },
+            "reason": reason,
+            "message": message,
+            "type": etype,
+            "count": 1,
+            "firstTimestamp": now,
+            "lastTimestamp": now,
+            "source": {"component": self._source},
+        }
+
+    def _remember(self, key: Tuple, name: str, count: int) -> None:
+        with self._mut:
+            self._keys[key] = [name, count]
+            self._keys.move_to_end(key)
+            while len(self._keys) > self.MAX_KEYS:
+                self._keys.popitem(last=False)
+
+    def _forget(self, key: Tuple, name: str) -> None:
+        with self._mut:
+            if (self._keys.get(key) or [None])[0] == name:
+                del self._keys[key]
+
     def event(self, involved: dict, etype: str, reason: str, message: str) -> dict:
         meta = involved.get("metadata") or {}
         key = (meta.get("uid"), etype, reason, message)
         ns = meta.get("namespace") or "default"
+        kind = str(involved.get("kind") or "")
         now = self._now_string()
         with self._mut:
-            name = self._keys.get(key)
-            if name is not None:
-                try:
-                    cur = self._store.get("Event", name, namespace=ns)
+            name = (self._keys.get(key) or [None])[0]
+        if name is not None:
+            try:
+                cur = self._store.get("Event", name, namespace=ns)
+                count = int(cur.get("count") or 1) + 1
+                out = self._store.patch(
+                    "Event",
+                    name,
+                    {"count": count, "lastTimestamp": now},
+                    "merge",
+                    namespace=ns,
+                )
+                self._remember(key, name, count)
+                _H_EVENTS_RECORDED.observe(1, kind, "aggregated")
+                return out
+            except NotFound:
+                self._forget(key, name)
+        ev = self._new_event(involved, etype, reason, message, now)
+        created = self._store.create(ev)
+        self._remember(key, ev["metadata"]["name"], 1)
+        _H_EVENTS_RECORDED.observe(1, kind, "created")
+        return created
+
+    def record(self, items: List[Tuple[dict, str, str, str]]) -> int:
+        """Many Events ``(involved, type, reason, message)`` in one
+        ``store.bulk``: the aggregation key decides between a create and
+        a bump of ``count`` against the cache alone (no read of the
+        Event), the ops go in the order given, and an Event the store
+        refuses, or a request that fails, is dropped and counted, never
+        raised.  Returns how many were dropped."""
+        if not items:
+            return 0
+        now = self._now_string()
+        ops: List[dict] = []
+        #: per op: (key, Event name, involved kind, outcome if it lands)
+        sent: List[Tuple[Tuple, str, str, str]] = []
+        with self._mut:
+            for involved, etype, reason, message in items:
+                meta = involved.get("metadata") or {}
+                key = (meta.get("uid"), etype, reason, message)
+                kind = str(involved.get("kind") or "")
+                entry = self._keys.get(key)
+                if entry is not None:
+                    entry[1] += 1
                     self._keys.move_to_end(key)
-                    return self._store.patch(
-                        "Event",
-                        name,
-                        {"count": int(cur.get("count") or 1) + 1, "lastTimestamp": now},
-                        "merge",
-                        namespace=ns,
+                    ops.append(
+                        {
+                            "verb": "patch",
+                            "kind": "Event",
+                            "name": entry[0],
+                            "namespace": meta.get("namespace") or "default",
+                            "data": {"count": entry[1], "lastTimestamp": now},
+                            "patch_type": "merge",
+                        }
                     )
-                except NotFound:
-                    del self._keys[key]
-            name = f"{meta.get('name', 'unknown')}.{self._suffix()}"
-            ev = {
-                "apiVersion": "v1",
-                "kind": "Event",
-                "metadata": {"name": name, "namespace": ns},
-                "involvedObject": {
-                    "apiVersion": involved.get("apiVersion"),
-                    "kind": involved.get("kind"),
-                    "name": meta.get("name"),
-                    "namespace": meta.get("namespace"),
-                    "uid": meta.get("uid"),
-                },
-                "reason": reason,
-                "message": message,
-                "type": etype,
-                "count": 1,
-                "firstTimestamp": now,
-                "lastTimestamp": now,
-                "source": {"component": self._source},
-            }
-            created = self._store.create(ev)
-            self._keys[key] = name
+                    sent.append((key, entry[0], kind, "aggregated"))
+                else:
+                    ev = self._new_event(involved, etype, reason, message, now)
+                    self._keys[key] = [ev["metadata"]["name"], 1]
+                    ops.append({"verb": "create", "data": ev})
+                    sent.append((key, ev["metadata"]["name"], kind, "created"))
             while len(self._keys) > self.MAX_KEYS:
                 self._keys.popitem(last=False)
-            return created
+        try:
+            results = self._store.bulk(ops)
+        except Exception:  # noqa: BLE001 — an Event never fails its caller
+            results = []
+        tally: Dict[Tuple[str, str], int] = {}
+        dropped = 0
+        for i, (key, name, kind, outcome) in enumerate(sent):
+            res = results[i] if i < len(results) else None
+            if not isinstance(res, dict) or res.get("status") != "ok":
+                # whatever the cache says of it is no longer known to
+                # be stored: the next repeat makes a new Event
+                self._forget(key, name)
+                outcome = "dropped"
+                dropped += 1
+            tally[(kind, outcome)] = tally.get((kind, outcome), 0) + 1
+        for (kind, outcome), n in tally.items():
+            _H_EVENTS_RECORDED.observe(n, kind, outcome)
+        return dropped

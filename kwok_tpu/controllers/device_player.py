@@ -22,6 +22,7 @@ from __future__ import annotations
 import sys
 import threading
 import time
+from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -52,10 +53,11 @@ _LOG = get_logger("device-player")
 #: the profiler's clock and ``kwok_tick_stage_seconds{kind,stage}``.
 #: Outermost: ingest, device_tick, host_drain, post_tick, pace_wait;
 #: host_build and store_bulk (the status batch), delete_commit (the
-#: delete batch) and slow_build and slow_commit (``_drain_slow``: the
-#: per-row Python around its bulk, and the bulk) nest in host_drain,
-#: which reports self time; compile (engine/simulator.py) overlays the
-#: stage it stalls.
+#: delete batch), slow_build and slow_commit (``_drain_slow``: the
+#: per-row Python around its bulk, and the bulk) and event_post (the
+#: drain's Events handed to the recorder in one request, last) nest in
+#: host_drain, which reports self time; compile (engine/simulator.py)
+#: overlays the stage it stalls.
 _stage = _telemetry.stage
 
 #: rows in one ``store.apply_status_batch`` or ``apply_delete_batch``
@@ -67,6 +69,13 @@ _stage = _telemetry.stage
 #: the CPU cache across build, commit and confirm at this size
 _COMMIT_ROWS = ResourceStore.WATCH_HIGH_WATER // 4
 
+#: RenderPlans kept, by (stage, signature); a plan takes ~5 KB and ~0.5 ms
+#: to compile.  A node's name and a pod's labels are in the signature, so
+#: 1,000 nodes under ``pod-general`` + ``pod-chaos`` have some 13,000
+#: pairs in use, and a cache that holds fewer than are in use compiles
+#: plans again in every drain (PERF.md §6, PR 32)
+_PLAN_CACHE = 32768
+
 #: one observation a commit request, valued with the rows it committed:
 #: ``path`` is ``batch`` (``apply_status_batch``), ``delete``
 #: (``apply_delete_batch``) or ``slow`` (``_drain_slow``'s bulk).
@@ -77,6 +86,18 @@ _H_COMMIT_ROWS = _telemetry.histogram(
     help="rows committed by one status commit request of a device player",
     buckets=(1, 4, 16, 64, 256, 1024, 2048, 4096, 8192, 16384, 65536),
     labelnames=("kind", "path"),
+)
+
+#: one observation a drain (``_drain_stages``) and stage, valued with the
+#: rows of that stage the drain played, by the way they reached the
+#: store (``path`` as in ``kwok_status_commit_rows``; a row that had
+#: nothing to send counts where it was routed).  ``_sum`` adds up to
+#: ``kwok_stage_transitions_total``: the mix a window played
+_H_FIRED_ROWS = _telemetry.histogram(
+    "kwok_stage_fired_rows",
+    help="rows one drain of a device player played, by stage and commit path",
+    buckets=(1, 4, 16, 64, 256, 1024, 2048, 4096, 8192, 16384, 65536),
+    labelnames=("kind", "stage", "path"),
 )
 
 #: per object whose stage-driven delete the store acknowledged as gone:
@@ -161,6 +182,14 @@ class DeviceStagePlayer:
         #: suppression); grown alongside sim.capacity — at 1M rows an
         #: indexed load beats a big-dict probe on every hot path
         self._written_rv: List[Optional[str]] = [None] * capacity
+        #: device dispatches made so far, and, row-indexed, how many had
+        #: been made when the host last put an object's features into
+        #: the row (admitted it, or extracted them again because the
+        #: object was not what the device took it for): the output of
+        #: dispatch ``d`` says nothing of a row stamped ``d`` or later
+        #: (see _drain_stages_inner)
+        self._dispatches = 0
+        self._row_since = np.zeros(capacity, np.int64)
         self._mut = threading.Lock()
         self._paced = True
         self._done = threading.Event()
@@ -215,10 +244,21 @@ class DeviceStagePlayer:
         #: set's templates have no tracked read paths (identity reads
         #: are sentinel-substituted; spec/labels/annotations are part of
         #: the sig key).
-        self._plans: Dict[Tuple[int, int], Optional[RenderPlan]] = {}
+        self._plans: "OrderedDict[Tuple[int, int], Optional[RenderPlan]]" = (
+            OrderedDict()
+        )
         #: (stage_idx, finalizers, terminating) -> whether such a row of
         #: a deleting stage goes by the delete batch (_delete_is_one_event)
         self._delete_verdicts: Dict[tuple, bool] = {}
+        #: (stage_idx, path) -> rows played since the drain began
+        #: (tick thread only; observed and emptied by _drain_stages_inner)
+        self._fired: Dict[Tuple[int, str], int] = {}
+        #: the Events of the rows the drain has played so far, as
+        #: ``EventRecorder.record`` takes them (tick thread only)
+        self._pending_events: List[tuple] = []
+        #: the stage by row of the sub-tick being drained, for the
+        #: commits' accounting (set by _drain_tick)
+        self._drain_st: Optional[np.ndarray] = None
         self._fast_ok = not self.sim.cset._read_paths
         # in-process stores hand back stored instances from bulk
         # (immutable by contract): the slow-path drain adopts them into
@@ -327,6 +367,17 @@ class DeviceStagePlayer:
             self._written_rv.extend([None] * (cap - len(self._written_rv)))
         if len(self._vals_cache) < cap:
             self._vals_cache.extend([None] * (cap - len(self._vals_cache)))
+        if len(self._row_since) < cap:
+            self._row_since = np.concatenate(
+                [self._row_since, np.zeros(cap - len(self._row_since), np.int64)]
+            )
+
+    def _rematch_row(self, row: int) -> None:
+        """Extract the row's features from its mirror again and have
+        the device match it anew: what a dispatch already made fired
+        for the row, it fired for what the row held before."""
+        self.sim.refresh_row(row)
+        self._row_since[row] = self._dispatches
 
     # ------------------------------------------------------------ event ingest
 
@@ -381,11 +432,12 @@ class DeviceStagePlayer:
             row = self.sim.admit(obj)
             self._rows[key] = row
             self._grow_row_arrays()
+            self._row_since[row] = self._dispatches
             self._drop_render_cache(row)
         else:
             old = self.sim.objects[row]
             self.sim.objects[row] = obj
-            self.sim.refresh_row(row)
+            self._rematch_row(row)
             if not self._render_identity_same(old, obj):
                 self._drop_render_cache(row)
 
@@ -533,8 +585,9 @@ class DeviceStagePlayer:
         dt = dt_ms if dt_ms is not None else self.tick_ms
         with _stage(self.kind, "device_tick") as sp:
             stages_np, t0_ms = self.sim.tick_many(dt, n_ticks)
+        self._dispatches += 1
         self.t_device += sp.elapsed
-        fired_total = self._drain_stages(stages_np, t0_ms, dt)
+        fired_total = self._drain_stages(stages_np, t0_ms, dt, self._dispatches)
         self._run_post_tick()
         self._observe_tick(base, fired_total)
         return fired_total
@@ -580,15 +633,20 @@ class DeviceStagePlayer:
             # stall the stage loop
             self._swallow()
 
-    def _drain_stages(self, stages_np: np.ndarray, t0_ms: int, dt: int) -> int:
+    def _drain_stages(
+        self, stages_np: np.ndarray, t0_ms: int, dt: int, dispatch: int
+    ) -> int:
+        """Drain the output of the ``dispatch``-th device dispatch."""
         store_before = self.t_store
         with _stage(self.kind, "host_drain") as sp:
-            fired_total = self._drain_stages_inner(stages_np, t0_ms, dt)
+            fired_total = self._drain_stages_inner(stages_np, t0_ms, dt, dispatch)
         # t_host: the drain less its store round-trips (build included)
         self.t_host += sp.elapsed - (self.t_store - store_before)
         return fired_total
 
-    def _drain_stages_inner(self, stages_np: np.ndarray, t0_ms: int, dt: int) -> int:
+    def _drain_stages_inner(
+        self, stages_np: np.ndarray, t0_ms: int, dt: int, dispatch: int
+    ) -> int:
         fired_total = 0
         t_start = time.perf_counter()
         # shared grace anchor for the abort checks at every granularity
@@ -609,13 +667,45 @@ class DeviceStagePlayer:
             st = stages_np[k]
             rows = np.nonzero(st >= 0)[0]
             if rows.size:
+                # a row the host filled after this output was dispatched
+                # fired for what it held before: an overlapped macro-tick
+                # is drained one ingest late.  A pod-delete that fired
+                # for a pod by now gone must not take the new pod admitted
+                # into its row (which the delete batch, given the new
+                # pod's own name and resourceVersion, would do), and a
+                # stage that fired for an object somebody has changed
+                # since must not be played on the changed one: the
+                # device matches the row again from what it holds now
+                rows = rows[self._row_since[rows] < dispatch]
+            if rows.size:
                 fired_total += int(rows.size)
                 try:
                     self._drain_tick(rows, st, t0_ms + (k + 1) * dt)
                 except Exception:  # noqa: BLE001 — one bad sub-tick must
                     # not kill the loop for this kind
                     self._swallow()
+        if self._pending_events:
+            # the Events of every row the drain played, after the rows, in
+            # one request of their own: an Event the store refuses is the
+            # recorder's to drop and count
+            events, self._pending_events = self._pending_events, []
+            with _stage(self.kind, "event_post") as sp:
+                try:
+                    self.recorder.record(events)
+                except Exception:  # noqa: BLE001 — an Event never fails a row
+                    self._swallow()
+            self.t_store += sp.elapsed
+        if self._fired:
+            compiled = self.sim.cset.compiled
+            for (s_idx, path), n in self._fired.items():
+                _H_FIRED_ROWS.observe(n, self.kind, compiled[s_idx].name, path)
+            self._fired.clear()
         return fired_total
+
+    def _note_fired(self, s_idx: int, path: str, n: int = 1) -> None:
+        if n:
+            key = (s_idx, path)
+            self._fired[key] = self._fired.get(key, 0) + n
 
     def step_pipelined(self, dt_ms: Optional[int] = None, n_ticks: int = 1) -> int:
         """Overlapped macro-tick: dispatch the NEXT n_ticks on device,
@@ -627,7 +717,8 @@ class DeviceStagePlayer:
         reach the device one macro-tick late — the same eventual
         semantics the reference has between its informer and play
         workers.  Rows released mid-flight may fire once more; the
-        drain drops them (object already None).  Call
+        drain drops them (object already None, or another object
+        admitted into the row since the dispatch).  Call
         :meth:`flush_pipeline` to drain the final in-flight batch.
 
         Runs the post_tick hook (lease lanes) like step_batch does, so
@@ -643,18 +734,19 @@ class DeviceStagePlayer:
         prev = self._inflight
         with _stage(self.kind, "device_tick") as sp:
             stages_dev, t0_ms = self.sim.tick_many_async(dt, n_ticks)
-            self._inflight = (stages_dev, t0_ms, dt)
+            self._dispatches += 1
+            self._inflight = (stages_dev, t0_ms, dt, self._dispatches)
             # start the device->host copy NOW so it overlaps the drain
             # below: the next call's device_get finds the bytes on the host
             # instead of paying a blocking read
             stages_dev.copy_to_host_async()
             if prev is not None:
-                p_stages, p_t0, p_dt = prev
+                p_stages, p_t0, p_dt, p_dispatch = prev
                 stages_np = np.asarray(jax.device_get(p_stages))
         self.t_device += sp.elapsed
         fired = 0
         if prev is not None:
-            fired = self._drain_stages(stages_np, p_t0, p_dt)
+            fired = self._drain_stages(stages_np, p_t0, p_dt, p_dispatch)
         self._run_post_tick()
         self._observe_tick(base, fired)
         return fired
@@ -666,20 +758,22 @@ class DeviceStagePlayer:
             return 0
         import jax
 
-        stages_dev, t0_ms, dt = prev
+        stages_dev, t0_ms, dt, dispatch = prev
         with _stage(self.kind, "device_tick") as sp:
             stages_np = np.asarray(jax.device_get(stages_dev))
         self.t_device += sp.elapsed
-        return self._drain_stages(stages_np, t0_ms, dt)
+        return self._drain_stages(stages_np, t0_ms, dt, dispatch)
 
     _PLAN_MISS = object()
 
     def _plan_for(self, s_idx: int, sig: int, obj: dict) -> Optional[RenderPlan]:
         key = (s_idx, sig)
         plan = self._plans.get(key, self._PLAN_MISS)
-        if plan is self._PLAN_MISS:
-            if len(self._plans) >= 8192:
-                self._plans.clear()  # coarse bound (sig classes x stages)
+        if plan is not self._PLAN_MISS:
+            self._plans.move_to_end(key)
+        else:
+            if len(self._plans) >= _PLAN_CACHE:
+                self._plans.popitem(last=False)  # the one longest unused
             try:
                 plan = compile_plan(
                     self.sim.cset.lifecycle,
@@ -721,6 +815,7 @@ class DeviceStagePlayer:
         # builds the same items
         use_c = _FAST is not None
         self._grow_row_arrays()
+        self._drain_st = st
         srow = st[rows]
         sigrow = sigs[rows]
         order = np.lexsort((sigrow, srow))
@@ -835,6 +930,7 @@ class DeviceStagePlayer:
                             )
                         self.t_build += sp.elapsed
                         self.transitions += noops
+                        self._note_fired(s_idx, "batch", noops)
                         for row in slow_rows:
                             slow.append(self._make_transition(row, s_idx, t_ms))
                         if len(fast_items) >= chunk:
@@ -879,6 +975,7 @@ class DeviceStagePlayer:
                         )
                     )
                 self.transitions += transitions_local
+                self._note_fired(s_idx, "batch", transitions_local)
                 if len(fast_items) >= chunk:
                     _flush_locked()
             _flush_locked()
@@ -911,7 +1008,7 @@ class DeviceStagePlayer:
             objects = self.sim.objects
             for row in rows:
                 if objects[row] is not None:
-                    self.sim.refresh_row(row)
+                    self._rematch_row(row)
             return []
         if _FAST is not None:
             n_ok, refused = self._confirm_native_locked(
@@ -920,6 +1017,16 @@ class DeviceStagePlayer:
         else:
             n_ok, refused = self._confirm_python_locked(results, rows, items)
         _H_COMMIT_ROWS.observe(n_ok, self.kind, "batch")
+        st = self._drain_st
+        if n_ok == len(rows):
+            for s_idx, n in enumerate(np.bincount(st[rows]).tolist()):
+                self._note_fired(s_idx, "batch", n)
+        else:
+            # a refused row is played, and counted, by _drain_slow; one
+            # whose object is gone is released and counts nowhere
+            for row, res in zip(rows, results):
+                if res is not None and res is not False:
+                    self._note_fired(int(st[row]), "batch")
         return [rows[idx] for idx in refused]
 
     def _delete_is_one_event(self, s_idx: int, meta: dict) -> bool:
@@ -979,15 +1086,17 @@ class DeviceStagePlayer:
         self.t_store += sp.elapsed
         if results is None:
             for row in rows:
-                self.sim.refresh_row(row)
+                self._rematch_row(row)
             return []
         refused: List[int] = []
         now = self.clock.now()
+        st = self._drain_st
         for row, item, res in zip(rows, items, results):
             if res is False:
                 refused.append(row)
             else:
                 self._gone_locked((item[0] or "", item[1]), now)
+                self._note_fired(int(st[row]), "delete")
         n_ok = len(rows) - len(refused)
         self.transitions += n_ok
         _H_COMMIT_ROWS.observe(n_ok, self.kind, "delete")
@@ -1080,7 +1189,7 @@ class DeviceStagePlayer:
         status write: take it and extract the row's features again."""
         old = self.sim.objects[row]
         self.sim.objects[row] = new_obj
-        self.sim.refresh_row(row)
+        self._rematch_row(row)
         if not self._render_identity_same(old, new_obj):
             self._drop_render_cache(row)
 
@@ -1103,9 +1212,11 @@ class DeviceStagePlayer:
         """Legacy per-transition drain (deletes, finalizers, events,
         non-status patches): grouped ops through store.bulk with the
         sequential fallback.  ``slow_build`` is the Python a row on both
-        sides of the bulk, ``slow_commit`` the bulk."""
+        sides of the bulk, ``slow_commit`` the bulk; the rows' Events
+        wait for the end of the drain (``event_post``)."""
         can_bulk = hasattr(self.store, "bulk")
-        groups: List[Tuple[Tuple[str, str], List[dict]]] = []
+        #: (key, ops, stage_idx) a transition with something to send
+        groups: List[Tuple[Tuple[str, str], List[dict], int]] = []
         played = self.transitions
         with _stage(self.kind, "slow_build"):
             for j, tr in enumerate(transitions):
@@ -1115,19 +1226,21 @@ class DeviceStagePlayer:
                     and self._past_abort_grace()
                 ):
                     break  # shutdown: unplayed transitions re-fire on restart
+                before = self.transitions
                 try:
                     g = self._collect_ops(tr) if can_bulk else None
                     if g is not None:
                         key, ops = g
                         if ops:
-                            groups.append((key, ops))
+                            groups.append((key, ops, tr.stage_idx))
                     else:
                         self._play_transition(tr)
                 except Exception:  # noqa: BLE001 — one bad row must not stop the drain
                     self._swallow()
+                self._note_fired(tr.stage_idx, "slow", self.transitions - before)
             flat = [
                 {k: v for k, v in op.items() if k != "_fin"}
-                for _, ops in groups
+                for _, ops, _s in groups
                 for op in ops
             ]
         if groups:
@@ -1144,13 +1257,15 @@ class DeviceStagePlayer:
                 if results is None:
                     results = [self._op_sequential_result(op) for op in flat]
                 idx = 0
-                for key, ops in groups:
+                for key, ops, s_idx in groups:
                     rs = results[idx : idx + len(ops)]
                     idx += len(ops)
+                    before = self.transitions
                     try:
                         self._apply_group_results(key, ops, rs)
                     except Exception:  # noqa: BLE001 — per-group isolation
                         self._swallow()
+                    self._note_fired(s_idx, "slow", self.transitions - before)
         if groups or self.transitions != played:
             # whatever was played: through the bulk, by _play_transition,
             # or with nothing to send
@@ -1329,6 +1444,15 @@ class DeviceStagePlayer:
                 key, last_obj, simple=last_simple, own_finalizers=own_fin
             )
 
+    def _note_event(self, tr: Transition, obj: dict) -> None:
+        """The transition's Event, if its stage has one, joins those
+        the drain hands to the recorder in one request once its rows
+        are committed (``_drain_stages_inner``)."""
+        if tr.event is not None and self.recorder is not None:
+            self._pending_events.append(
+                (obj, tr.event.type or "Normal", tr.event.reason, tr.event.message)
+            )
+
     def _collect_ops(self, tr: Transition):
         """Lower a transition to an ORDERED op group for the bulk drain:
         returns (key, [op, ...]) — empty list means pure no-op (counted
@@ -1372,10 +1496,7 @@ class DeviceStagePlayer:
             )
 
         if effects.delete:
-            if tr.event is not None and self.recorder is not None:
-                self.recorder.event(
-                    obj, tr.event.type or "Normal", tr.event.reason, tr.event.message
-                )
+            self._note_event(tr, obj)
             ops.append(
                 {
                     "verb": "delete",
@@ -1401,10 +1522,7 @@ class DeviceStagePlayer:
             # _read_paths (the compiler excludes identity reads) — those
             # shapes keep the sequential base-chaining path.
             return None
-        if tr.event is not None and self.recorder is not None:
-            self.recorder.event(
-                obj, tr.event.type or "Normal", tr.event.reason, tr.event.message
-            )
+        self._note_event(tr, obj)
         if not patches and not ops:
             # nothing to send — the transition is complete here; ops
             # that DO ship count only once their patch lands (parity
@@ -1445,10 +1563,7 @@ class DeviceStagePlayer:
         if effects is None:
             return
 
-        if tr.event is not None and self.recorder is not None:
-            self.recorder.event(
-                obj, tr.event.type or "Normal", tr.event.reason, tr.event.message
-            )
+        self._note_event(tr, obj)
 
         result: Optional[dict] = None
         fin = effects.finalizers_patch(meta.get("finalizers") or [])
@@ -1528,7 +1643,7 @@ class DeviceStagePlayer:
                 return
             old = self.sim.objects[row]
             self.sim.objects[row] = obj
-            self.sim.refresh_row(row)
+            self._rematch_row(row)
             if not self._render_identity_same(old, obj):
                 self._drop_render_cache(row)
 

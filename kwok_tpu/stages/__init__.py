@@ -1,4 +1,6 @@
-"""Built-in stage sets (the simulator's "model zoo").
+"""Built-in stage sets: upstream's stage library as YAML files, each a
+list of Stage documents that a cluster plays by default or that a user
+selects with ``--config <file>``.
 
 Mirrors the reference's embedded default stages
 (reference: pkg/kwok/cmd/root.go:32-35,463-490 + kustomize/stage/*):

@@ -301,7 +301,7 @@ def test_player_counts_what_its_loop_swallows(capsys):
 
     player.post_tick = None
     player._drain_tick = bad_drain
-    player._drain_stages(np.zeros((2, 8), np.int8), 0, 100)
+    player._drain_stages(np.zeros((2, 8), np.int8), 0, 100, player._dispatches)
     assert player.swallowed_errors == 3  # one per sub-tick
 
     def bad_step(*a, **kw):

@@ -9,7 +9,7 @@ import time
 
 import pytest
 
-from kwok_tpu.cluster.store import ResourceStore
+from kwok_tpu.cluster.store import EventRecorder, ResourceStore
 from kwok_tpu.controllers.device_player import DeviceStagePlayer
 from kwok_tpu.engine import simulator
 from kwok_tpu.stages import load_builtin
@@ -19,10 +19,12 @@ from kwok_tpu.utils.clock import FakeClock
 NEW_STAGES = ("ingest", "compile", "post_tick", "pace_wait")
 
 
-def make_pod(name, finalizers=()):
+def make_pod(name, finalizers=(), annotations=None):
     meta = {"name": name, "namespace": "default", "uid": f"uid-{name}"}
     if finalizers:
         meta["finalizers"] = list(finalizers)
+    if annotations:
+        meta["annotations"] = dict(annotations)
     return {
         "apiVersion": "v1",
         "kind": "Pod",
@@ -32,13 +34,13 @@ def make_pod(name, finalizers=()):
     }
 
 
-def make_player(store, capacity, clock=None):
+def make_player(store, capacity, clock=None, stages=None, recorder=None):
     from kwok_tpu.controllers.pod_controller import PodEnv
 
     env = PodEnv()
     return DeviceStagePlayer(
-        store, "Pod", load_builtin("pod-fast"), capacity=capacity, tick_ms=20,
-        clock=clock, funcs_for=env.funcs, on_delete=env.release,
+        store, "Pod", stages or load_builtin("pod-fast"), capacity=capacity, tick_ms=20,
+        clock=clock, recorder=recorder, funcs_for=env.funcs, on_delete=env.release,
     )
 
 
@@ -71,19 +73,37 @@ def fresh():
     simulator.ShapeLog._seen.update(saved)
 
 
-@pytest.mark.parametrize("paced,deletes", [(True, False), (False, False), (False, True)],
-                         ids=["paced", "unpaced", "unpaced-deletes"])
-def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes):
+@pytest.mark.parametrize("paced,deletes,events",
+                         [(True, False, False), (False, False, False), (False, True, False),
+                          (False, True, True)],
+                         ids=["paced", "unpaced", "unpaced-deletes", "unpaced-events"])
+def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes, events):
     """The clock is injected: a paced loop waits for the test to
     advance it.  With ``deletes`` the pods carry a finalizer and are
     deleted once Running: ``pod-delete`` plays them through the delete
     batch, whose commit is one more stage of the sum (``delete_commit``;
-    ``_drain_slow``'s two are in the sum when a row goes that way)."""
+    ``_drain_slow``'s two are in the sum when a row goes that way).  With
+    ``events`` the stage set is ``pod-general`` and the player has a
+    recorder: ``pod-create`` and ``pod-remove-finalizer`` go through
+    ``_drain_slow`` and leave an Event each, which ``event_post`` hands
+    over a drain at a time."""
     clock = FakeClock(1000.0)
     store = ResourceStore(clock=clock)  # one clock: a deletionTimestamp is the player's time
-    player = make_player(store, capacity=16, clock=clock)
+    annotations = None
+    if events:
+        stages = load_builtin("pod-general")
+        annotations = {f"{s.name}.stage.kwok.x-k8s.io/{k}": v for s in stages
+                       for k, v in (("delay", "20ms"), ("jitter-delay", "40ms"))}
+        player = make_player(store, capacity=16, clock=clock, stages=stages,
+                             recorder=EventRecorder(store, "kwok", clock=clock))
+    else:
+        player = make_player(store, capacity=16, clock=clock)
     posts = []
     player.post_tick = posts.append
+    # pod-ready and pod-delete a pod; with events pod-create, pod-ready and
+    # pod-remove-finalizer (the store reaps the pod as its finalizer goes,
+    # and pod-delete, which fired for what the row held before, is dropped)
+    played = 120 if events else 80 if deletes else 40
     def waited():
         return stage_table().get("pace_wait", (0.0, 0))[1]
 
@@ -91,15 +111,17 @@ def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes):
     player.start(paced=paced)
     try:
         for i in range(40):
-            store.create(make_pod(f"pod-{i}", ("kwok.x-k8s.io/fake",) if deletes else ()))
+            store.create(make_pod(
+                f"pod-{i}", ("kwok.x-k8s.io/fake",) if deletes and not events else (),
+                annotations))
         # virtual time at a quarter of real time: once the first
         # programs have compiled, a paced loop is ahead of its schedule
         deadline = time.monotonic() + 30
         asked = False
         while time.monotonic() < deadline and (
-            player.transitions < (80 if deletes else 40) or (paced and waited() < 3)
+            player.transitions < played or (paced and waited() < 3)
         ):
-            if deletes and not asked and player.transitions >= 40:
+            if deletes and not asked and player.transitions >= (80 if events else 40):
                 asked = True
                 for i in range(40):
                     store.delete("Pod", f"pod-{i}", namespace="default")
@@ -110,14 +132,16 @@ def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes):
         clock.advance(0.02)  # wake a paced wait
         player.stop()
     wall = time.perf_counter() - t0
-    assert player.transitions >= (80 if deletes else 40) and posts
+    assert player.transitions >= played and posts
     table = stage_table()
     want = set(NEW_STAGES) | {"device_tick", "host_drain", "host_build", "store_bulk"}
     if not paced:
         want.discard("pace_wait")
     if deletes:
-        want |= {"delete_commit"}
+        want |= {"slow_build", "slow_commit", "event_post"} if events else {"delete_commit"}
         assert store.list("Pod")[0] == []
+    if events:
+        assert len(store.list("Event")[0]) == 80
     assert want <= {k for k, (_s, n) in table.items() if n > 0}, table
     # every stage reports self time but that compile overlays the stage
     # it stalls: the sum less the overlay is the thread's wall time
@@ -125,9 +149,11 @@ def test_the_stages_of_the_tick_thread_make_its_wall_time(paced, deletes):
     assert total == pytest.approx(wall, rel=0.05), (table, wall)
     # and the accumulators bench.py reads are fed from the same clocks
     assert player.t_device == pytest.approx(table["device_tick"][0])
-    slow_build, slow_commit, delete_commit = (
-        table.get(k, (0.0, 0))[0] for k in ("slow_build", "slow_commit", "delete_commit"))
-    assert player.t_store == pytest.approx(table["store_bulk"][0] + slow_commit + delete_commit)
+    slow_build, slow_commit, delete_commit, event_post = (
+        table.get(k, (0.0, 0))[0]
+        for k in ("slow_build", "slow_commit", "delete_commit", "event_post"))
+    assert player.t_store == pytest.approx(
+        table["store_bulk"][0] + slow_commit + delete_commit + event_post)
     assert player.t_build == pytest.approx(table["host_build"][0])
     assert player.t_host - player.t_build == pytest.approx(table["host_drain"][0] + slow_build)
 
