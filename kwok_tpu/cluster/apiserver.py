@@ -27,7 +27,14 @@ REST surface (kind-keyed rather than group/version-keyed; our
 - ``GET  /r/{plural}``                     list; query params
   ``namespace`` ``labelSelector`` ``fieldSelector``
 - ``GET  /r/{plural}?watch=1&resourceVersion=N``  newline-delimited
-  JSON watch stream (``{"type","object","rv"}``, BOOKMARK heartbeats)
+  JSON watch stream (``{"type","object","rv"}``, BOOKMARK heartbeats).
+  An event's line is shared and immutable, like the object it carries:
+  the store hands every watcher of a kind the same event instance, the
+  first stream that delivers it encodes the line and keeps it on the
+  event (``store.WatchEvent.line``), and every other stream (selected,
+  namespaced or resumed from the history ring alike) writes those
+  bytes.  Only with a tracer armed does each stream encode its own
+  (the envelope then carries the delivery's ``ctx``)
 - ``POST /r/{plural}``                     create
 - ``GET/PUT/PATCH/DELETE /r/{plural}/{name}``     single object; query
   params ``namespace`` ``subresource``; PATCH type from Content-Type
@@ -81,6 +88,30 @@ from kwok_tpu.cluster.store import (
 )
 
 __all__ = ["APIServer", "PATCH_CONTENT_TYPES"]
+
+#: how often the shared watch line engages, one observation a flushed
+#: burst of a stream: the lines that stream had to encode itself (0 when
+#: another stream of the kind got to every event first), the lines it
+#: wrote, and the CPU seconds of its thread the encoding took (thread
+#: time: what the encoding costs the one interpreter every request
+#: shares, not the turns it waited for)
+_H_LINES_ENCODED = _telemetry.histogram(
+    "kwok_watch_lines_encoded",
+    help="watch lines a stream encoded itself, per flushed burst",
+    buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512),
+    labelnames=("kind",),
+)
+_C_LINES = _telemetry.counter(
+    "kwok_watch_lines_total",
+    help="watch lines written to streams",
+    labelnames=("kind",),
+)
+_H_ENCODE = _telemetry.histogram(
+    "kwok_watch_encode_seconds",
+    help="thread CPU seconds a stream spent encoding a flushed burst",
+    buckets=(0.00001, 0.00005, 0.0001, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05),
+    labelnames=("kind",),
+)
 
 #: Paths owned by the Kubernetes wire-protocol facade (k8s_api.py);
 #: everything else stays on the legacy custom REST surface.
@@ -1029,6 +1060,9 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_header("Connection", "close")
         self.end_headers()
         self.close_connection = True
+        # the metrics' label: a registered type's kind (the watch above
+        # raised NotFound for anything else), so a bounded set
+        kind = self.store.resource_type(plural).kind
         shutdown = getattr(self.server, "shutting_down", None)
         inj = getattr(self.server, "fault_injector", None)
         cid = self.headers.get("X-Kwok-Client") or ""
@@ -1057,19 +1091,36 @@ class _Handler(BaseHTTPRequestHandler):
         )
 
         def _encode_burst(burst):
-            ctxs = (
-                ctx_many([e.rv for e in burst])
-                if ctx_many is not None
-                else {}
-            )
+            """The burst's lines, and how many this stream had to encode."""
+            if ctx_many is not None:
+                # traced: the envelope carries what THIS delivery
+                # resolved from the ring, so it is nobody else's line
+                ctxs = ctx_many([e.rv for e in burst])
+                out = []
+                for e in burst:
+                    payload = {"type": e.type, "object": e.object, "rv": e.rv}
+                    ctx = ctxs.get(e.rv)
+                    if ctx is not None:
+                        payload["ctx"] = list(ctx)
+                    out.append(self._encode_line(payload))
+                return out, len(out)
+            # a line is encoded by the first stream that delivers its
+            # event and kept on the event (store.WatchEvent.line): the
+            # store hands every watcher of a kind the same instances,
+            # so the other streams write these bytes.  Here, on the
+            # stream's own thread, never under the store mutex; two
+            # streams that race encode the same bytes twice
             out = []
+            fresh = 0
             for e in burst:
-                payload = {"type": e.type, "object": e.object, "rv": e.rv}
-                ctx = ctxs.get(e.rv)
-                if ctx is not None:
-                    payload["ctx"] = list(ctx)
-                out.append(self._encode_line(payload))
-            return out
+                line = e.line
+                if line is None:
+                    line = e.line = self._encode_line(
+                        {"type": e.type, "object": e.object, "rv": e.rv}
+                    )
+                    fresh += 1
+                out.append(line)
+            return out, fresh
 
         try:
             idle = 0.0
@@ -1120,8 +1171,14 @@ class _Handler(BaseHTTPRequestHandler):
                         break
                     burst.append(ev)
                 last_rv = burst[-1].rv
-                self.wfile.write(b"".join(_encode_burst(burst)))
+                t_enc = time.thread_time()
+                lines, fresh = _encode_burst(burst)
+                t_enc = time.thread_time() - t_enc
+                self.wfile.write(b"".join(lines))
                 self.wfile.flush()
+                _H_LINES_ENCODED.observe(fresh, kind)
+                _C_LINES.inc(len(lines), kind)
+                _H_ENCODE.observe(t_enc, kind)
                 # observed rv-commit -> delivery lag, one sample per
                 # flushed burst (shared with the k8s dialect)
                 observe_watch_delivery(self.store, last_rv)

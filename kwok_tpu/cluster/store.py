@@ -533,10 +533,19 @@ class WatchEvent:
     type: str  # ADDED | MODIFIED | DELETED
     object: dict
     rv: int = 0
+    #: the event's NDJSON watch line, kept by the first stream that
+    #: encodes it (cluster/apiserver.py) for every other stream that
+    #: carries this instance; immutable like ``object``, and no part
+    #: of what the event is
+    line: Optional[bytes] = field(default=None, compare=False, repr=False)
 
+
+#: the dataclass under a name of its own: what runs where the native
+#: unit is absent, and the twin the tests hold the C event to
+_PyWatchEvent = WatchEvent
 
 if _FAST is not None and hasattr(_FAST, "WatchEvent"):
-    # slot-backed C event: same (type, object, rv) surface, but
+    # slot-backed C event: same (type, object, rv, line) surface, but
     # status_commit can allocate it without a Python __init__ call per
     # row (every consumer is duck-typed on the three attributes)
     WatchEvent = _FAST.WatchEvent  # noqa: F811

@@ -20,6 +20,8 @@
  *   WatchEvent — slot-backed (type, object, rv) event; swapped in for
  *   the Python dataclass by cluster/store.py so status_commit can
  *   allocate events without a Python-level __init__ call per row.
+ *   A fourth slot, line, holds the event's NDJSON watch line once a
+ *   stream has encoded it (None until then; no part of equality).
  */
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
@@ -50,6 +52,7 @@ typedef struct {
     PyObject *type;
     PyObject *object;
     long long rv;
+    PyObject *line; /* bytes of the watch line, None until encoded */
 } FastEvent;
 
 static PyTypeObject FastEventType; /* fwd */
@@ -57,11 +60,11 @@ static PyTypeObject FastEventType; /* fwd */
 static PyObject *
 fastevent_new(PyTypeObject *tp, PyObject *args, PyObject *kwds)
 {
-    static char *kwlist[] = {"type", "object", "rv", NULL};
-    PyObject *type, *object;
+    static char *kwlist[] = {"type", "object", "rv", "line", NULL};
+    PyObject *type, *object, *line = Py_None;
     long long rv = 0;
-    if (!PyArg_ParseTupleAndKeywords(args, kwds, "OO|L", kwlist, &type,
-                                     &object, &rv))
+    if (!PyArg_ParseTupleAndKeywords(args, kwds, "OO|LO", kwlist, &type,
+                                     &object, &rv, &line))
         return NULL;
     FastEvent *ev = (FastEvent *)tp->tp_alloc(tp, 0);
     if (!ev)
@@ -71,6 +74,8 @@ fastevent_new(PyTypeObject *tp, PyObject *args, PyObject *kwds)
     Py_INCREF(object);
     ev->object = object;
     ev->rv = rv;
+    Py_INCREF(line);
+    ev->line = line;
     return (PyObject *)ev;
 }
 
@@ -79,6 +84,7 @@ fastevent_dealloc(FastEvent *ev)
 {
     Py_XDECREF(ev->type);
     Py_XDECREF(ev->object);
+    Py_XDECREF(ev->line);
     Py_TYPE(ev)->tp_free((PyObject *)ev);
 }
 
@@ -91,7 +97,7 @@ fastevent_richcompare(PyObject *a, PyObject *b, int op)
         !PyObject_TypeCheck(b, &FastEventType))
         Py_RETURN_NOTIMPLEMENTED;
     FastEvent *x = (FastEvent *)a, *y = (FastEvent *)b;
-    int eq = x->rv == y->rv;
+    int eq = x->rv == y->rv; /* line is a cache, not compared */
     if (eq) {
         eq = PyObject_RichCompareBool(x->type, y->type, Py_EQ);
         if (eq < 0)
@@ -113,6 +119,7 @@ static PyMemberDef fastevent_members[] = {
     {"type", Py_T_OBJECT_EX, offsetof(FastEvent, type), 0, NULL},
     {"object", Py_T_OBJECT_EX, offsetof(FastEvent, object), 0, NULL},
     {"rv", Py_T_LONGLONG, offsetof(FastEvent, rv), 0, NULL},
+    {"line", Py_T_OBJECT_EX, offsetof(FastEvent, line), 0, NULL},
     {NULL},
 };
 
@@ -350,6 +357,8 @@ py_status_commit(PyObject *self, PyObject *args)
                 Py_INCREF(newobj);
                 fe->object = newobj;
                 fe->rv = rv;
+                Py_INCREF(Py_None);
+                fe->line = Py_None;
                 ev = (PyObject *)fe;
             } else {
                 ev = PyObject_CallFunction(ev_cls, "OOL", s_MODIFIED,

@@ -381,7 +381,10 @@ def test_an_unwritable_directory_skips_the_save_and_keeps_the_log_whole(tmp_path
 
         os.rename(away, files)
         wait_for(lambda: d.saved_rv() >= 100, 30, "a save after the directory came back")
-        assert max(rv for rv, _p in PitrArchive(d.pitr).snapshots()) == d.saved_rv()
+        # the parent renames the state file, then the archive's copy: a
+        # reader between the two sees the first alone for a moment
+        wait_for(lambda: max(rv for rv, _p in PitrArchive(d.pitr).snapshots()) == d.saved_rv(),
+                 10, "the archive's snapshot of that save")
         assert temporaries(str(files), d.pitr) == []
         assert len(d.client.list("Pod")[0]) == 100
     finally:
