@@ -31,9 +31,10 @@ REST surface (kind-keyed rather than group/version-keyed; our
   An event's line is shared and immutable, like the object it carries:
   the store hands every watcher of a kind the same event instance, the
   first stream that delivers it encodes the line and keeps it on the
-  event (``store.WatchEvent.line``), and every other stream (selected,
-  namespaced or resumed from the history ring alike) writes those
-  bytes.  Only with a tracer armed does each stream encode its own
+  event (``store.watch_line``), and every other stream (selected,
+  namespaced or resumed from the history ring alike; a Kubernetes-wire
+  stream cuts its frame from them) writes those bytes.  Only with a
+  tracer armed does each stream encode its own
   (the envelope then carries the delivery's ``ctx``)
 - ``POST /r/{plural}``                     create
 - ``GET/PUT/PATCH/DELETE /r/{plural}/{name}``     single object; query
@@ -84,34 +85,12 @@ from kwok_tpu.cluster.k8s_api import (
 from kwok_tpu.cluster.store import (
     ResourceStore,
     ResourceType,
+    observe_watch_burst,
     observe_watch_delivery,
+    watch_line,
 )
 
 __all__ = ["APIServer", "PATCH_CONTENT_TYPES"]
-
-#: how often the shared watch line engages, one observation a flushed
-#: burst of a stream: the lines that stream had to encode itself (0 when
-#: another stream of the kind got to every event first), the lines it
-#: wrote, and the CPU seconds of its thread the encoding took (thread
-#: time: what the encoding costs the one interpreter every request
-#: shares, not the turns it waited for)
-_H_LINES_ENCODED = _telemetry.histogram(
-    "kwok_watch_lines_encoded",
-    help="watch lines a stream encoded itself, per flushed burst",
-    buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512),
-    labelnames=("kind",),
-)
-_C_LINES = _telemetry.counter(
-    "kwok_watch_lines_total",
-    help="watch lines written to streams",
-    labelnames=("kind",),
-)
-_H_ENCODE = _telemetry.histogram(
-    "kwok_watch_encode_seconds",
-    help="thread CPU seconds a stream spent encoding a flushed burst",
-    buckets=(0.00001, 0.00005, 0.0001, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05),
-    labelnames=("kind",),
-)
 
 #: Paths owned by the Kubernetes wire-protocol facade (k8s_api.py);
 #: everything else stays on the legacy custom REST surface.
@@ -845,6 +824,7 @@ class _Handler(BaseHTTPRequestHandler):
                         field_selector=q.get("fieldSelector"),
                         limit=int(q.get("limit") or 0),
                         continue_from=_decode_continue(q.get("continue")),
+                        copy=False,  # encoded at once, below
                     )
                     body = {"items": items, "resourceVersion": str(rv)}
                     if nxt is not None:
@@ -1105,20 +1085,15 @@ class _Handler(BaseHTTPRequestHandler):
                     out.append(self._encode_line(payload))
                 return out, len(out)
             # a line is encoded by the first stream that delivers its
-            # event and kept on the event (store.WatchEvent.line): the
+            # event and kept on the event (store.watch_line): the
             # store hands every watcher of a kind the same instances,
-            # so the other streams write these bytes.  Here, on the
-            # stream's own thread, never under the store mutex; two
-            # streams that race encode the same bytes twice
+            # so the other streams, of this dialect and of the
+            # Kubernetes wire, write these bytes
             out = []
             fresh = 0
             for e in burst:
-                line = e.line
-                if line is None:
-                    line = e.line = self._encode_line(
-                        {"type": e.type, "object": e.object, "rv": e.rv}
-                    )
-                    fresh += 1
+                line, encoded = watch_line(e)
+                fresh += encoded
                 out.append(line)
             return out, fresh
 
@@ -1176,9 +1151,7 @@ class _Handler(BaseHTTPRequestHandler):
                 t_enc = time.thread_time() - t_enc
                 self.wfile.write(b"".join(lines))
                 self.wfile.flush()
-                _H_LINES_ENCODED.observe(fresh, kind)
-                _C_LINES.inc(len(lines), kind)
-                _H_ENCODE.observe(t_enc, kind)
+                observe_watch_burst(kind, fresh, len(lines), t_enc)
                 # observed rv-commit -> delivery lag, one sample per
                 # flushed burst (shared with the k8s dialect)
                 observe_watch_delivery(self.store, last_rv)

@@ -668,11 +668,10 @@ class ClusterClient:
         label_selector: Selector = None,
         field_selector: Selector = None,
     ) -> Tuple[List[dict], int]:
-        """Single-request list: one consistent snapshot under the store
-        lock, which informers REQUIRE (the returned resourceVersion
-        must cover every item, or watch-from-rv misses events).  Use
-        :meth:`list_paged` for bulk exports where bounded response
-        sizes matter more than snapshot consistency."""
+        """Single-request list: one consistent snapshot whose
+        resourceVersion covers every item, so that a watch from it
+        misses nothing.  :meth:`list_paged` gives the same snapshot in
+        bounded responses."""
         plural = self.resource_type(kind).plural
         data = self._request(
             "GET",
@@ -693,9 +692,12 @@ class ClusterClient:
         field_selector: Selector = None,
         page_size: Optional[int] = None,
     ) -> Tuple[List[dict], int]:
-        """Paged list via limit/continue: bounds each response, but the
-        pages are independent reads — mutations between pages can skip
-        or duplicate items (see ResourceStore.list_page)."""
+        """Paged list via limit/continue: bounds each response, and the
+        pages are one snapshot (ResourceStore.list_page): every page
+        carries the first page's resourceVersion, each object that
+        existed then appears once, and a watch from it misses nothing.
+        A continue token whose snapshot the server no longer holds
+        raises :class:`Expired` (410); list again from the start."""
         plural = self.resource_type(kind).plural
         items: List[dict] = []
         rv = 0

@@ -177,6 +177,9 @@ class Informer:
             # in-process store: stored instances by reference (the
             # informer's consumers are read-only by contract)
             kw["copy"] = False
+        # one request: the cluster's own components list in process or
+        # over one local connection, where bounded pages buy nothing
+        # (list_paged serves the same one snapshot to who needs them)
         items, rv = self._store.list(
             self._kind,
             namespace=opt.namespace,

@@ -19,8 +19,14 @@ service discovery — can connect to a kwok-tpu cluster:
   (GET list/get, POST create, PUT update, PATCH with the three k8s
   patch content types, DELETE object + deletecollection),
   ``?watch=true`` chunk-streamed ``{"type","object"}`` frames with
-  optional BOOKMARK events, ``limit``/``continue`` paging, and
-  ``labelSelector``/``fieldSelector``/``resourceVersion`` params
+  optional BOOKMARK events (a frame is cut from the line the first
+  stream of either dialect encoded for the event, ``store.watch_line``:
+  one ``json.dumps`` an event whatever the number of streams; Table
+  and traced streams encode their own), ``limit``/``continue`` paging
+  over one snapshot a LIST (every page carries the first page's
+  resourceVersion and serves from it; a token whose snapshot is gone
+  answers 410 ``Expired``, as a real apiserver's after compaction),
+  and ``labelSelector``/``fieldSelector``/``resourceVersion`` params
 - ``POST .../pods/{name}/binding``         scheduler binding subresource
 - ``GET/PUT/PATCH .../deployments/{name}/scale`` (and replicasets) —
   the autoscaling/v1 Scale subresource kubectl scale drives; writes
@@ -50,8 +56,11 @@ from kwok_tpu.cluster.store import (
     ResourceStore,
     ResourceType,
     StorageDegraded,
+    k8s_frame,
+    observe_watch_burst,
     observe_watch_delivery,
     selector_to_string,
+    watch_line,
 )
 from kwok_tpu.cluster.tables import to_table, wants_table
 
@@ -99,16 +108,16 @@ def scale_of(obj: dict) -> dict:
 
 
 def encode_continue(token) -> str:
-    """Opaque continue token: base64(json([ns, name])) — object names
-    may contain any character, so no separator scheme is safe."""
+    """Opaque continue token: base64(json(token)) of what
+    ``ResourceStore.list_page`` hands out (a LIST snapshot's id and a
+    position in it)."""
     return base64.urlsafe_b64encode(json.dumps(list(token)).encode()).decode()
 
 
 def decode_continue(raw):
     if not raw:
         return None
-    ns, name = json.loads(base64.urlsafe_b64decode(raw.encode()))
-    return (ns, name)
+    return tuple(json.loads(base64.urlsafe_b64decode(raw.encode())))
 
 
 def group_version(rtype: ResourceType) -> Tuple[str, str]:
@@ -602,8 +611,9 @@ class K8sFacade:
                 return True
             if r.subresource == "log":
                 return self._proxy_log(handler, r, q)
-            obj = self.store.get(r.rtype.kind, r.name, namespace=ns)
-            self._stamp(r.rtype, obj)
+            obj = self._stamp(
+                r.rtype, self.store.get(r.rtype.kind, r.name, namespace=ns)
+            )
             if self._maybe_send_table(handler, r, [obj], q):
                 return True
             self._send(handler, 200, obj)
@@ -797,6 +807,7 @@ class K8sFacade:
                 field_selector=q.get("fieldSelector"),
                 limit=limit,
                 continue_from=decode_continue(q.get("continue")),
+                copy=False,  # _stamp copies what it has to change
             )
             body["metadata"] = {"resourceVersion": str(rv)}
             if nxt is not None:
@@ -1014,24 +1025,27 @@ class K8sFacade:
                         break
                     burst.append(ev)
                 last_rv = burst[-1].rv
-                ctxs = (
-                    ctx_many([e.rv for e in burst])
-                    if ctx_many is not None
-                    else {}
-                )
-                handler.wfile.write(
-                    b"".join(
+                t_enc = time.thread_time()
+                if as_table or ctx_many is not None:
+                    # Table-typed or traced: nobody else's bytes
+                    ctxs = (
+                        ctx_many([e.rv for e in burst])
+                        if ctx_many is not None
+                        else {}
+                    )
+                    frames = [
                         self._encode_event(
-                            r.rtype,
-                            e,
-                            as_table,
-                            include_object,
-                            ctx=ctxs.get(e.rv),
+                            r.rtype, e, as_table, include_object, ctx=ctxs.get(e.rv)
                         )
                         for e in burst
-                    )
-                )
+                    ]
+                    fresh = len(frames)
+                else:
+                    frames, fresh = self._shared_frames(r.rtype, burst)
+                t_enc = time.thread_time() - t_enc
+                handler.wfile.write(b"".join(frames))
                 handler.wfile.flush()
+                observe_watch_burst(r.rtype.kind, fresh, len(frames), t_enc)
                 # observed rv-commit -> delivery lag, one sample per
                 # flushed burst (shared with the legacy dialect)
                 observe_watch_delivery(self.store, last_rv)
@@ -1039,6 +1053,28 @@ class K8sFacade:
             pass
         finally:
             w.stop()
+
+    def _shared_frames(self, rtype, burst) -> Tuple[List[bytes], int]:
+        """The burst's frames, and how many this stream had to encode:
+        a frame is cut from the line that the first stream of either
+        dialect encoded for the event and left on it
+        (``store.watch_line``), so N streams of a kind write the same
+        bytes for one ``json.dumps``.  An object stored without its kind
+        or apiVersion (none is, by ``create``) gets a frame of its own."""
+        frames = []
+        fresh = 0
+        for e in burst:
+            obj = e.object
+            if "kind" in obj and "apiVersion" in obj:
+                line = e.line  # most often there: one attribute read a frame
+                if line is None:
+                    line = watch_line(e)[0]
+                    fresh += 1
+                frames.append(k8s_frame(line))
+            else:
+                frames.append(self._encode_event(rtype, e))
+                fresh += 1
+        return frames, fresh
 
     def _encode_event(
         self,
@@ -1049,13 +1085,8 @@ class K8sFacade:
         ctx=None,
     ) -> bytes:
         # watch events share the stored instance (store._emit contract):
-        # never _stamp it in place — graft missing kind/apiVersion onto
-        # a shallow copy instead
-        obj = ev.object
-        if "kind" not in obj or "apiVersion" not in obj:
-            obj = dict(obj)
-            obj.setdefault("kind", rtype.kind)
-            obj.setdefault("apiVersion", rtype.api_version)
+        # _stamp grafts a missing kind/apiVersion onto a shallow copy
+        obj = self._stamp(rtype, ev.object)
         if as_table:
             obj = to_table(rtype.kind, [obj], include_object=include_object)
         payload = {"type": ev.type, "object": obj}
@@ -1064,8 +1095,8 @@ class K8sFacade:
         # carries the committing span context as an EXTRA top-level key
         # (object payload untouched; client-go/kubectl ignore unknown
         # watch-event fields, and Table streams stay pristine — kubectl
-        # is the only Table consumer).  Tracing off ⇒ byte-identical
-        # frames to the pre-existing dialect.
+        # is the only Table consumer).  Tracing off ⇒ the frame every
+        # untraced stream of the kind writes (_shared_frames).
         if ctx is not None and not as_table:
             payload["ctx"] = list(ctx)
         return json.dumps(payload).encode() + b"\n"
@@ -1404,7 +1435,14 @@ class K8sFacade:
 
     # ------------------------------------------------------------- plumbing
 
-    def _stamp(self, rtype: ResourceType, obj: dict) -> dict:
+    @staticmethod
+    def _stamp(rtype: ResourceType, obj: dict) -> dict:
+        """``obj`` with its kind and apiVersion; grafted onto a shallow
+        copy where one is missing, since ``obj`` may be the stored
+        instance (a paged LIST's, a watch event's)."""
+        if "kind" in obj and "apiVersion" in obj:
+            return obj
+        obj = dict(obj)
         obj.setdefault("kind", rtype.kind)
         obj.setdefault("apiVersion", rtype.api_version)
         return obj

@@ -40,16 +40,19 @@ state.
 from __future__ import annotations
 
 import contextlib
+import operator
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
 
 from kwok_tpu.cluster.store import (
     CrossShardTransaction,
+    ListSnapshots,
     NotFound,
     ResourceStore,
     ResourceType,
     Selector,
     Watcher,
+    list_page_from,
 )
 from kwok_tpu.cluster.sharding.fanin import MergedWatcher
 from kwok_tpu.utils.locks import make_lock
@@ -241,6 +244,8 @@ class ShardedStore:
             raise ValueError("a sharded store needs at least one shard")
         self._shards = list(shards)
         self._source = source
+        #: what the continue tokens of a LIST across every shard name
+        self._snapshots = ListSnapshots()
         #: test-only injected regression (`--dst-bug cross-shard-txn`):
         #: stripes txn ops across shards per-OP (a load-balancing
         #: "optimization" instead of the per-namespace placement) —
@@ -540,8 +545,9 @@ class ShardedStore:
         label_selector: Selector = None,
         field_selector: Selector = None,
         limit: int = 0,
-        continue_from: Optional[Tuple[str, str]] = None,
-    ) -> Tuple[List[dict], int, Optional[Tuple[str, str]]]:
+        continue_from: Optional[Tuple[int, int]] = None,
+        copy: bool = True,
+    ) -> Tuple[List[dict], int, Optional[Tuple[int, int]]]:
         if not self._fanout(kind, namespace):
             return self._route(kind, namespace).list_page(
                 kind,
@@ -550,52 +556,37 @@ class ShardedStore:
                 field_selector=field_selector,
                 limit=limit,
                 continue_from=continue_from,
+                copy=copy,
             )
-        # shards are walked in index order; the continue token stays
-        # the single-store (ns, name) shape — the namespace names the
-        # shard the cursor is in (placement is pure), so no token
-        # format change leaks to clients
+        # a kind read across every shard pages over ONE snapshot of its
+        # own, as a single store's LIST does (store.ListSnapshots): the
+        # first page cuts every shard, the later ones serve from it
+        return list_page_from(
+            self._snapshots,
+            self._rtype(kind),
+            lambda: self._cut_pairs(kind),
+            namespace,
+            label_selector,
+            field_selector,
+            limit,
+            continue_from,
+            copy,
+        )
+
+    def _cut_pairs(self, kind: str) -> Tuple[list, int]:
+        """Every shard's pairs in key order, at the resume point a
+        merged read reports: read-time rvs, like ``list()`` — a write
+        that lands on a shard already cut must not push the resume
+        point past itself, or a list-then-watch would skip it."""
         g0 = self._source.current()
-        n = len(self._shards)
-        start = 0
-        if continue_from is not None:
-            ns = tuple(continue_from)[0]
-            start = shard_of(True, kind, ns or None, n)
-        items: List[dict] = []
-        last_key: Optional[Tuple[str, str]] = None
-        # read-time rvs, like list(): re-reading the shards' CURRENT
-        # rvs at return time would let a write that landed on an
-        # already-paged shard mid-walk push the resume point past
-        # itself — a list-then-watch would skip that object.  A walk
-        # that did not visit every shard (mid-pagination return, or a
-        # continue token that skipped ahead) pins at g0 for the same
-        # reason: the unvisited shards' events are unaccounted.
+        pairs: list = []
         rvs: List[int] = []
-        for i in range(start, n):
-            tok = continue_from if i == start else None
-            remaining = (limit - len(items)) if limit else 0
-            its, rv_i, nxt = self._shards[i].list_page(
-                kind,
-                namespace=namespace,
-                label_selector=label_selector,
-                field_selector=field_selector,
-                limit=remaining,
-                continue_from=tok,
-            )
-            rvs.append(rv_i)
-            items.extend(its)
-            if its:
-                m = its[-1].get("metadata") or {}
-                last_key = (m.get("namespace") or "", m.get("name") or "")
-            if nxt is not None:
-                return items, g0, nxt
-            if limit and len(items) >= limit and i + 1 < n:
-                # page full exactly at a shard boundary: resume from
-                # the last returned key — its namespace re-addresses
-                # shard i, whose exhausted cursor advances to i+1
-                return items, g0, last_key
-        full_walk = start == 0
-        return items, (self._merged_rv(rvs, g0) if full_walk else g0), None
+        for s in self._shards:
+            cut, rv = s._cut_pairs(kind)
+            pairs.extend(cut)
+            rvs.append(rv)
+        pairs.sort(key=operator.itemgetter(0))
+        return pairs, self._merged_rv(rvs, g0)
 
     def count(self, kind: str) -> int:
         if not self._rtype(kind).namespaced:

@@ -27,7 +27,11 @@ controllers use this object directly (the Go↔device bridge boundary).
 
 from __future__ import annotations
 
+import contextlib
 import datetime
+import functools
+import json
+import operator
 import threading
 import time
 from collections import OrderedDict, deque
@@ -59,6 +63,71 @@ _H_WATCH_DELIVERY = _telemetry.histogram(
     help="lag from rv commit to watch-stream delivery",
     labelnames=("shard",),
 )
+
+
+#: how often the shared watch line engages, one observation a flushed
+#: burst of a stream of either dialect: the lines that stream had to
+#: encode itself (0 when another stream of the kind got to every event
+#: first), the lines it wrote, and the CPU seconds of its thread the
+#: encoding took (thread time: what the encoding costs the one
+#: interpreter every request shares, not the turns it waited for)
+_H_LINES_ENCODED = _telemetry.histogram(
+    "kwok_watch_lines_encoded",
+    help="watch lines a stream encoded itself, per flushed burst",
+    buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512),
+    labelnames=("kind",),
+)
+_C_LINES = _telemetry.counter(
+    "kwok_watch_lines_total",
+    help="watch lines written to streams",
+    labelnames=("kind",),
+)
+_H_ENCODE = _telemetry.histogram(
+    "kwok_watch_encode_seconds",
+    help="thread CPU seconds a stream spent encoding a flushed burst",
+    buckets=(0.00001, 0.00005, 0.0001, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05),
+    labelnames=("kind",),
+)
+
+#: what the watchers of a kind cost a writer: seconds a commit (or a
+#: batch, or a bulk's commits together) spent under the store mutex
+#: deciding which watchers get its events and handing them over
+_H_WATCH_FILTER = _telemetry.histogram(
+    "kwok_watch_filter_seconds",
+    help="seconds a commit spent handing its events to the kind's watchers",
+    buckets=(0.000005, 0.00002, 0.0001, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05),
+    labelnames=("kind",),
+)
+
+#: the paged LIST: seconds and objects a page (``list_page``: the cut of
+#: a first page's snapshot, the slice, the selectors; the answer's JSON
+#: is the route's), and what became of the snapshots: ``opened`` (a
+#: first page left more to serve), ``served`` (the last page went out),
+#: ``expired`` (a continue token named one that was gone: 410)
+_H_LIST_PAGE = _telemetry.histogram(
+    "kwok_list_page_seconds",
+    help="seconds a page of a paged LIST took inside the store",
+    buckets=(0.0001, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25),
+    labelnames=("kind",),
+)
+_H_LIST_OBJECTS = _telemetry.histogram(
+    "kwok_list_page_objects",
+    help="objects a page of a paged LIST returned",
+    buckets=(0, 1, 10, 100, 500, 1000, 5000, 10000),
+    labelnames=("kind",),
+)
+_C_LIST_SNAPSHOTS = _telemetry.counter(
+    "kwok_list_snapshots",
+    help="LIST snapshots by what became of them",
+    labelnames=("outcome",),
+)
+
+
+def observe_watch_burst(kind: str, encoded: int, written: int, seconds: float) -> None:
+    """One flushed burst of a watch stream, either dialect."""
+    _H_LINES_ENCODED.observe(encoded, kind)
+    _C_LINES.inc(written, kind)
+    _H_ENCODE.observe(seconds, kind)
 
 
 def observe_watch_delivery(store, rv: int) -> None:
@@ -272,26 +341,33 @@ def _split_requirements(sel: str) -> List[str]:
     return parts
 
 
-def _parse_selector(sel: Selector) -> List[Tuple[str, str, str]]:
+def _parse_selector(sel: Selector) -> Tuple[Tuple[str, str, Any], ...]:
     """Parse the full k8s selector grammar — 'k=v', 'k!=v', 'k', '!k',
-    'k in (a,b)', 'k notin (a,b)' — into (key, op, value) requirements
-    (set values stay as the raw '(a,b)' text; match splits them)."""
+    'k in (a,b)', 'k notin (a,b)' — into (key, op, value) requirements;
+    the value of a set-based one is the frozenset of its members.  A
+    selector string is parsed once and remembered (bounded): a watcher's
+    or a LIST's filter runs once an object, under the store mutex."""
     if sel is None:
-        return []
+        return ()
     if isinstance(sel, dict):
-        return [(k, "=", v) for k, v in sel.items()]
-    reqs: List[Tuple[str, str, str]] = []
-    for part in _split_requirements(str(sel)):
+        return tuple((k, "=", v) for k, v in sel.items())
+    return _parse_selector_string(str(sel))
+
+
+@functools.lru_cache(maxsize=1024)
+def _parse_selector_string(sel: str) -> Tuple[Tuple[str, str, Any], ...]:
+    reqs: List[Tuple[str, str, Any]] = []
+    for part in _split_requirements(sel):
         part = part.strip()
         if not part:
             continue
         low = f" {part} "
         if " notin " in low:
             k, v = low.split(" notin ", 1)
-            reqs.append((k.strip(), "notin", v.strip()))
+            reqs.append((k.strip(), "notin", _set_values(v)))
         elif " in " in low:
             k, v = low.split(" in ", 1)
-            reqs.append((k.strip(), "in", v.strip()))
+            reqs.append((k.strip(), "in", _set_values(v)))
         elif "!=" in part:
             k, v = part.split("!=", 1)
             reqs.append((k.strip(), "!=", v.strip()))
@@ -302,29 +378,42 @@ def _parse_selector(sel: Selector) -> List[Tuple[str, str, str]]:
             reqs.append((part[1:].strip(), "notexists", ""))
         else:
             reqs.append((part, "exists", ""))
-    return reqs
+    return tuple(reqs)
 
 
-def _set_values(raw: str) -> List[str]:
-    return [v.strip() for v in raw.strip().strip("()").split(",") if v.strip()]
+def _set_values(raw: str) -> frozenset:
+    return frozenset(
+        v.strip() for v in raw.strip().strip("()").split(",") if v.strip()
+    )
+
+
+def _labels_match(labels: dict, reqs) -> bool:
+    for k, op, v in reqs:
+        if op == "=":
+            if labels.get(k) != v:
+                return False
+        elif op == "!=":
+            if labels.get(k) == v:
+                return False
+        elif op == "exists":
+            if k not in labels:
+                return False
+        elif op == "notexists":
+            if k in labels:
+                return False
+        elif op == "in":
+            if labels.get(k) not in v:
+                return False
+        elif op == "notin" and labels.get(k) in v:
+            return False
+    return True
 
 
 def match_label_selector(obj: dict, sel: Selector) -> bool:
-    labels = (obj.get("metadata") or {}).get("labels") or {}
-    for k, op, v in _parse_selector(sel):
-        if op == "=" and labels.get(k) != v:
-            return False
-        if op == "!=" and labels.get(k) == v:
-            return False
-        if op == "exists" and k not in labels:
-            return False
-        if op == "notexists" and k in labels:
-            return False
-        if op == "in" and (k not in labels or labels[k] not in _set_values(v)):
-            return False
-        if op == "notin" and labels.get(k) in _set_values(v):
-            return False
-    return True
+    reqs = _parse_selector(sel)
+    if not reqs:
+        return True
+    return _labels_match((obj.get("metadata") or {}).get("labels") or {}, reqs)
 
 
 def selector_to_string(selector: Optional[dict]) -> Optional[str]:
@@ -415,16 +504,21 @@ class Watcher:
     def __init__(
         self,
         store: "ResourceStore",
-        filt: Callable[[dict], bool],
-        trivial: bool = False,
+        filt: Optional[Callable[[dict], bool]] = None,
+        route: Tuple = ("every",),
         status_interest: bool = True,
         high_water: int = 0,
     ):
         self._store = store
+        #: what the watcher selects, compiled once at ``watch()``; None
+        #: selects every object of the kind.  The store's fan-out
+        #: (``_WatchRoutes``) asks it only of objects the watcher's
+        #: ``route`` does not already decide, and ``watch()`` of the
+        #: history it replays on a resume
         self._filter = filt
-        #: a trivial filter (no namespace/selectors) lets batch pushes
-        #: skip the per-event filter call on the store thread
-        self._trivial = trivial
+        #: where the fan-out finds this watcher: ``("every",)``,
+        #: ``("namespace", ns)``, ``("label", key, value)`` or ``("scan",)``
+        self._route = route
         #: False: this consumer declares it does not need status-only
         #: batch events (the GC controller's posture — it reads
         #: ownerReferences/deletionTimestamp, which status writes never
@@ -449,31 +543,36 @@ class Watcher:
         self.stop()
 
     def _push(self, ev: "WatchEvent") -> None:
+        """One event the fan-out selected for this watcher."""
         if self._stopped.is_set():
-            return
-        if not self._filter(ev.object):
             return
         self._events.append(ev)
         if self.high_water and len(self._events) > self.high_water:
             self._evict()
             return
-        self._signal.set()
+        self._wake()
 
     def _push_batch(self, evs: List["WatchEvent"]) -> None:
-        """Deliver many events with one signal (the status-batch drain
-        emits thousands per tick; per-event Event.set wakeups and filter
-        calls were measurable at that rate)."""
+        """Deliver many selected events with one signal (the status-batch
+        drain emits thousands per tick; per-event Event.set wakeups
+        were measurable at that rate)."""
         if self._stopped.is_set() or not evs:
             return
-        if self._trivial:
-            self._events.extend(evs)
-        else:
-            f = self._filter
-            self._events.extend(ev for ev in evs if f(ev.object))
+        self._events.extend(evs)
         if self.high_water and len(self._events) > self.high_water:
             self._evict()
             return
-        self._signal.set()
+        self._wake()
+
+    def _wake(self) -> None:
+        """Tell the consumer there is something to take, unless it has
+        been told and has not looked yet: ``Event.set`` takes a lock and
+        wakes a thread, under the writer's mutex and once a watcher an
+        event, where the flag's state is one read.  (The event is queued
+        before the flag is read, and ``next`` clears the flag before it
+        looks at the queue again, so no wake-up is lost.)"""
+        if not self._signal.is_set():
+            self._signal.set()
 
     def _seed(self, evs: List["WatchEvent"]) -> None:
         """Preload resume-replay events with no high-water check: the
@@ -534,9 +633,9 @@ class WatchEvent:
     object: dict
     rv: int = 0
     #: the event's NDJSON watch line, kept by the first stream that
-    #: encodes it (cluster/apiserver.py) for every other stream that
-    #: carries this instance; immutable like ``object``, and no part
-    #: of what the event is
+    #: encodes it (``watch_line`` below) for every other stream of
+    #: either dialect that carries this instance; immutable like
+    #: ``object``, and no part of what the event is
     line: Optional[bytes] = field(default=None, compare=False, repr=False)
 
 
@@ -551,18 +650,203 @@ if _FAST is not None and hasattr(_FAST, "WatchEvent"):
     WatchEvent = _FAST.WatchEvent  # noqa: F811
 
 
+def watch_line(ev) -> Tuple[bytes, int]:
+    """The event's NDJSON watch line, ``{"type", "object", "rv"}`` as
+    ``json.dumps`` gives it, and 1 where this call had to encode it: the
+    first stream that delivers an event encodes the line and keeps it on
+    the event, every other stream of the kind, of either dialect, gets
+    those bytes.  On the stream's own thread, never under the store
+    mutex; two streams that race encode the same bytes twice."""
+    line = ev.line
+    if line is not None:
+        return line, 0
+    line = ev.line = (
+        json.dumps({"type": ev.type, "object": ev.object, "rv": ev.rv}).encode()
+        + b"\n"
+    )
+    return line, 1
+
+
+def k8s_frame(line: bytes) -> bytes:
+    """The Kubernetes-wire frame ``{"type", "object"}`` of the event
+    whose ``watch_line`` is ``line``: the same bytes without the
+    envelope's last member.  (The cut is at the LAST ``, "rv": ``: what
+    follows the envelope's is digits and the closing brace, so no text
+    inside the object can stand in for it.)"""
+    return line[: line.rindex(b', "rv": ')] + b"}\n"
+
+
+class _WatchRoutes:
+    """The watchers of one kind, by what the store can look up about an
+    event's object, so that a commit's locked pass does not grow with
+    the watchers that do not select it.  ``homes`` maps a watcher's
+    route to the watchers on it: ``("every",)`` take each event of the
+    kind; ``("namespace", ns)`` each event of that namespace (they have
+    no other requirement); ``("label", key, value)`` are the watchers
+    whose selector has the equality ``key=value``, asked their whole
+    filter of an object that carries it; ``("scan",)`` is whoever is
+    left (set-based or inequality selectors alone, field selectors
+    alone) and is asked its filter of every object.  What a watcher is
+    delivered is what its filter selects, event for event, whichever
+    route holds it."""
+
+    def __init__(self):
+        self.homes: Dict[Tuple, List[Watcher]] = {}
+        #: the label keys that some ("label", key, value) route names
+        self.label_keys: Tuple[str, ...] = ()
+
+    def add(self, w: Watcher) -> None:
+        self.homes.setdefault(w._route, []).append(w)
+        self._rekey()
+
+    def remove(self, w: Watcher) -> None:
+        home = self.homes.get(w._route)
+        if home is not None and w in home:
+            home.remove(w)
+            if not home:
+                del self.homes[w._route]
+                self._rekey()
+
+    def _rekey(self) -> None:
+        self.label_keys = tuple({r[1] for r in self.homes if r[0] == "label"})
+
+
 @dataclass
 class _TypeState:
     rtype: ResourceType
     history: deque
     objects: Dict[Tuple[str, str], dict] = field(default_factory=dict)
     watchers: List[Watcher] = field(default_factory=list)
+    #: the same watchers, by what they select (``_fan_out`` reads it)
+    routes: _WatchRoutes = field(default_factory=_WatchRoutes)
     #: field-path -> value -> keys (the informer-cache index analog:
     #: client-go indexes pods by spec.nodeName the same way)
     indexes: Dict[str, Dict[str, set]] = field(default_factory=dict)
-    #: lazily maintained sorted key list; invalidated on add/remove so
-    #: paged walks don't re-sort the keyspace per page
-    sorted_keys: Optional[List[Tuple[str, str]]] = None
+
+
+def list_page_from(
+    snapshots: "ListSnapshots",
+    rtype: ResourceType,
+    cut: Callable[[], Tuple[list, int]],
+    namespace: Optional[str],
+    label_selector: Selector,
+    field_selector: Selector,
+    limit: int,
+    continue_from,
+    copy: bool,
+) -> Tuple[List[dict], int, Optional[Tuple[int, int]]]:
+    """One page of a paged LIST out of ``snapshots`` (a single store's or
+    a sharded router's): ``(items, resourceVersion, next token)``.  The
+    page is ``limit`` keys of the snapshot, filtered after it is cut;
+    one observation of ``kwok_list_page_seconds`` and
+    ``kwok_list_page_objects``, and a span where a tracer is armed."""
+    t0 = time.perf_counter()
+    with _trace_span("kwok_list_page_seconds"):
+        pairs, rv, next_token = snapshots.page(rtype.kind, continue_from, limit, cut)
+        if not rtype.namespaced:
+            namespace = None
+        labels = _parse_selector(label_selector)
+        items = []
+        for (ns, _name), obj in pairs:
+            if namespace is not None and ns != namespace:
+                continue
+            if labels and not _labels_match(
+                (obj.get("metadata") or {}).get("labels") or {}, labels
+            ):
+                continue
+            if field_selector and not match_field_selector(obj, field_selector):
+                continue
+            items.append(copy_json(obj) if copy else obj)
+    _H_LIST_PAGE.observe(time.perf_counter() - t0, rtype.kind)
+    _H_LIST_OBJECTS.observe(len(items), rtype.kind)
+    return items, rv, next_token
+
+
+def _trace_span(name: str):
+    """A span of ``name`` where a tracer is armed, else nothing."""
+    tr = _trace.peek_global()
+    if tr is not None and tr.enabled:
+        return tr.span(name)
+    return contextlib.nullcontext()
+
+
+class ListSnapshots:
+    """The snapshots that the continue tokens of paged LISTs name.
+
+    A first page cuts a snapshot: the kind's ``(key, object)`` pairs in
+    key order and the resourceVersion they were read at.  Stored objects
+    are copy-on-write, so the pairs are references and cost no copy; an
+    object replaced since stays alive for as long as a snapshot holds
+    it, which is what is bounded here, by count and by age: at most
+    ``MAX`` snapshots are kept (opening one more drops the oldest) and
+    none for longer than ``TTL_S`` seconds after it was cut (Kubernetes
+    expires a continue token with etcd's compaction, minutes after the
+    first page).  A LIST that fits one page pins nothing, and a
+    snapshot goes as its last page is served.  A token is ``(snapshot
+    id, position)``; one whose snapshot is gone, or that no first page
+    ever gave out, raises :class:`Expired` (410 on both wires), never a
+    fresh read."""
+
+    MAX = 64
+    TTL_S = 300.0
+
+    def __init__(self):
+        self._mut = make_lock("cluster.store.ListSnapshots._mut")
+        #: id -> (kind, pairs, resourceVersion, instant cut), oldest first
+        self._snaps: "OrderedDict[int, tuple]" = OrderedDict()
+        self._next = 0
+        for outcome in ("opened", "served", "expired"):
+            _C_LIST_SNAPSHOTS.inc(0, outcome)
+
+    def page(self, kind: str, token, limit: int, cut: Callable[[], Tuple[list, int]]):
+        """(pairs of this page, resourceVersion, next token or None).
+        ``token`` None is a first page: ``cut()`` gives ``kind``'s sorted
+        pairs and their resourceVersion."""
+        now = time.monotonic()
+        if token is None:
+            sid, pos = None, 0
+            pairs, rv = cut()
+        else:
+            try:
+                sid, pos = token
+                pos = int(pos)
+                with self._mut:
+                    of_kind, pairs, rv, t_cut = self._snaps[sid]
+                if (
+                    of_kind != kind
+                    or now - t_cut > self.TTL_S
+                    or not 0 < pos < len(pairs)
+                ):
+                    raise KeyError(sid)
+            except (KeyError, TypeError, ValueError):
+                _C_LIST_SNAPSHOTS.inc(1, "expired")
+                raise Expired(
+                    "the continue token's LIST snapshot is gone (or never "
+                    "was); list again from the start"
+                ) from None
+        end = pos + limit if limit else len(pairs)
+        if end < len(pairs):
+            if sid is None:
+                sid = self._open(kind, pairs, rv, now)
+            return pairs[pos:end], rv, (sid, end)
+        if sid is not None:
+            with self._mut:
+                self._snaps.pop(sid, None)
+            _C_LIST_SNAPSHOTS.inc(1, "served")
+        return pairs[pos:end], rv, None
+
+    def _open(self, kind: str, pairs: list, rv: int, now: float) -> int:
+        with self._mut:
+            self._next += 1
+            sid = self._next
+            self._snaps[sid] = (kind, pairs, rv, now)
+            while self._snaps and (
+                len(self._snaps) > self.MAX
+                or now - next(iter(self._snaps.values()))[3] > self.TTL_S
+            ):
+                self._snaps.popitem(last=False)
+        _C_LIST_SNAPSHOTS.inc(1, "opened")
+        return sid
 
 
 class ResourceStore:
@@ -636,6 +920,8 @@ class ResourceStore:
         #: gets Expired and re-lists instead of silently missing events
         self._history_floor = 0
         self._types: Dict[str, _TypeState] = {}
+        #: what the continue tokens of this store's paged LISTs name
+        self._snapshots = ListSnapshots()
         #: (verb, key, as_user); bounded — at device-drain rates an
         #: unbounded list is a slow memory leak.  Overflow is counted
         #: (audit_overflow), not silent: trace-replaying invariant
@@ -854,8 +1140,6 @@ class ResourceStore:
 
     @staticmethod
     def _index_update(st: _TypeState, key: Tuple[str, str], old: Optional[dict], new: Optional[dict]) -> None:
-        if old is None or new is None:  # key added or removed
-            st.sorted_keys = None
         for path, idx in st.indexes.items():
             ov = _index_value(_dotted_get(old, path) if old is not None else None)
             nv = _index_value(_dotted_get(new, path) if new is not None else None)
@@ -1025,14 +1309,74 @@ class ResourceStore:
                 tl.batch_rv = rv
             else:
                 self._note_commit(rv, st=st, etype=etype, obj=obj)
-        for w in list(st.watchers):
-            w._push(ev)
+        if st.watchers:
+            self._fan_out(st, (ev,))
+
+    def _fan_out(
+        self,
+        st: _TypeState,
+        evs,
+        exclude: Optional[Watcher] = None,
+        status: bool = False,
+    ) -> None:
+        """Hand a commit's events (one, or a batch's list) to the
+        watchers of the kind that select them; caller holds the mutex.
+        ``status``: a status batch, which watchers without
+        ``status_interest`` are not handed.  The seconds this takes are
+        what the watchers cost every writer: one observation of
+        ``kwok_watch_filter_seconds{kind}`` a commit or batch (a bulk's
+        commits add up to one)."""
+        t0 = time.perf_counter()
+        routes = st.routes
+
+        def hand(w: Watcher, mine) -> None:
+            if w is exclude or (status and not w.status_interest):
+                return
+            if len(mine) == 1:
+                w._push(mine[0])
+            else:
+                w._push_batch(mine)
+
+        homes = routes.homes
+        for w in list(homes.get(("every",), ())):
+            hand(w, evs)
+        if len(homes) > (("every",) in homes):
+            label_keys = routes.label_keys
+            scan = homes.get(("scan",), ())
+            by_namespace: Dict[Optional[str], list] = {}
+            took: Dict[Watcher, list] = {}
+            for ev in evs:
+                obj = ev.object
+                meta = obj.get("metadata") or {}
+                by_namespace.setdefault(meta.get("namespace"), []).append(ev)
+                labels = meta.get("labels")
+                if labels:
+                    for key in label_keys:
+                        for w in homes.get(("label", key, labels.get(key)), ()):
+                            if w._filter(obj):
+                                took.setdefault(w, []).append(ev)
+                for w in scan:
+                    if w._filter(obj):
+                        took.setdefault(w, []).append(ev)
+            for ns, mine in by_namespace.items():
+                for w in list(homes.get(("namespace", ns), ())):
+                    hand(w, mine)
+            for w, mine in took.items():
+                hand(w, mine)
+        dt = time.perf_counter() - t0
+        tl = self._tel_local
+        if getattr(tl, "in_batch", False):
+            kind = st.rtype.kind
+            tl.filter_s[kind] = tl.filter_s.get(kind, 0.0) + dt
+        else:
+            _H_WATCH_FILTER.observe(dt, st.rtype.kind)
 
     def _drop_watcher(self, watcher: Watcher) -> None:
         with self._mut:
             for st in self._types.values():
                 if watcher in st.watchers:
                     st.watchers.remove(watcher)
+                    st.routes.remove(watcher)
 
     def _note_eviction(self, watcher: Watcher) -> None:
         # pushes happen under the mutex, but the re-entrant hold is
@@ -1207,14 +1551,25 @@ class ResourceStore:
         page_size: Optional[int] = None,
     ) -> Tuple[List[dict], int]:
         """Duck-type twin of ClusterClient.list_paged.  In-process there
-        is no response-size concern, so one consistent snapshot read is
-        strictly better — delegate to :meth:`list`."""
+        is no response-size concern and both are one snapshot, so
+        delegate to :meth:`list`."""
         return self.list(
             kind,
             namespace=namespace,
             label_selector=label_selector,
             field_selector=field_selector,
         )
+
+    def _cut_pairs(self, kind: str) -> Tuple[list, int]:
+        """A paged LIST's snapshot of ``kind``: ``(key, object)`` pairs
+        in key order and the resourceVersion they were read at.  Under
+        the mutex only the references are taken; the sort is the
+        caller's thread's own."""
+        with self._mut:
+            pairs = list(self._state(kind).objects.items())
+            rv = self._rv
+        pairs.sort(key=operator.itemgetter(0))
+        return pairs, rv
 
     def list_page(
         self,
@@ -1223,53 +1578,38 @@ class ResourceStore:
         label_selector: Selector = None,
         field_selector: Selector = None,
         limit: int = 0,
-        continue_from: Optional[Tuple[str, str]] = None,
-    ) -> Tuple[List[dict], int, Optional[Tuple[str, str]]]:
+        continue_from: Optional[Tuple[int, int]] = None,
+        copy: bool = True,
+    ) -> Tuple[List[dict], int, Optional[Tuple[int, int]]]:
         """Paged list (the apiserver's limit/continue semantics; the
         reference's snapshot pager consumes the same, snapshot/save.go).
-        Returns (items, rv, next_token): next_token is the last key of
-        a full page, None when exhausted.  Filtering applies after
-        pagination-by-key like k8s (a page can be shorter than limit
-        even when more items remain).
+        Returns (items, rv, next_token): next_token is None when
+        exhausted, else what ``continue_from`` of the next call takes
+        (opaque: a snapshot's id and a position in it).  Filtering
+        applies after pagination-by-key like k8s (a page can be shorter
+        than limit even when more items remain).
 
-        Consistency caveat: pages are independent reads, not one
-        snapshot — mutations between pages can skip or duplicate
-        objects (k8s pins continue tokens to an etcd snapshot; this
-        store does not).  Informers therefore use the single-request
-        :meth:`list`; paging is for bulk export paths."""
-        import bisect
-
-        with self._mut:
-            st = self._state(kind)
-            items: List[dict] = []
-            next_token: Optional[Tuple[str, str]] = None
-            scanned = 0
-            if st.sorted_keys is None:
-                st.sorted_keys = sorted(st.objects)
-            keys = st.sorted_keys
-            start = (
-                bisect.bisect_right(keys, continue_from)
-                if continue_from is not None
-                else 0
-            )
-            for i in range(start, len(keys)):  # no tail copy per page
-                key = keys[i]
-                if limit and scanned >= limit:
-                    break
-                scanned += 1
-                next_token = key
-                ns, _ = key
-                obj = st.objects[key]
-                if st.rtype.namespaced and namespace is not None and ns != namespace:
-                    continue
-                if not match_label_selector(obj, label_selector):
-                    continue
-                if not match_field_selector(obj, field_selector):
-                    continue
-                items.append(copy_json(obj))
-            if not limit or scanned < limit:
-                next_token = None
-            return items, self._rv, next_token
+        The pages of one LIST are one snapshot (:class:`ListSnapshots`):
+        the first page fixes it, every page carries its resourceVersion
+        and serves from it, so each object that existed at that
+        resourceVersion appears exactly once and none created later
+        appears, whatever is written between the pages; a WATCH from
+        that resourceVersion then misses nothing.  A token whose
+        snapshot is gone raises :class:`Expired`.  No page sorts or
+        copies under the store mutex: ``copy=False`` hands out the
+        stored instances themselves (the read-only contract of
+        :meth:`list`), which the HTTP routes encode at once."""
+        return list_page_from(
+            self._snapshots,
+            self._state(kind).rtype,
+            lambda: self._cut_pairs(kind),
+            namespace,
+            label_selector,
+            field_selector,
+            limit,
+            continue_from,
+            copy,
+        )
 
     def update(
         self,
@@ -1640,22 +1980,32 @@ class ResourceStore:
         with self._mut:
             st = self._state(kind)
 
-            def filt(obj: dict, _ns=namespace, _st=st) -> bool:
-                if _st.rtype.namespaced and _ns is not None:
-                    if (obj.get("metadata") or {}).get("namespace") != _ns:
-                        return False
-                return match_label_selector(obj, label_selector) and match_field_selector(
-                    obj, field_selector
-                )
+            ns = namespace if st.rtype.namespaced else None
+            labels = _parse_selector(label_selector)
+            fields = _parse_selector(field_selector)
+            filt: Optional[Callable[[dict], bool]] = None
+            route: Tuple = ("every",)
+            if ns is not None or labels or fields:
 
+                def filt(obj: dict) -> bool:
+                    meta = obj.get("metadata") or {}
+                    if ns is not None and meta.get("namespace") != ns:
+                        return False
+                    if labels and not _labels_match(meta.get("labels") or {}, labels):
+                        return False
+                    return not fields or match_field_selector(obj, field_selector)
+
+                equal = next((r for r in labels if r[1] == "="), None)
+                if equal is not None:
+                    route = ("label", equal[0], equal[2])
+                elif labels or fields:
+                    route = ("scan",)
+                else:
+                    route = ("namespace", ns)
             w = Watcher(
                 self,
                 filt,
-                trivial=(
-                    (namespace is None or not st.rtype.namespaced)
-                    and label_selector is None
-                    and field_selector is None
-                ),
+                route,
                 status_interest=status_interest,
                 high_water=self.watch_high_water,
             )
@@ -1693,9 +2043,14 @@ class ResourceStore:
                 # the backlog is ring-bounded and predates the
                 # consumer's first read — only LIVE lag evicts
                 w._seed(
-                    [ev for ev in hist if ev.rv > since_rv and filt(ev.object)]
+                    [
+                        ev
+                        for ev in hist
+                        if ev.rv > since_rv and (filt is None or filt(ev.object))
+                    ]
                 )
             st.watchers.append(w)
+            st.routes.add(w)
             return w
 
     # --------------------------------------------------------------------- bulk
@@ -1768,9 +2123,7 @@ class ResourceStore:
                         # a tick commits thousands) — delivery lag is
                         # then measured against the batch's last rv
                         self._note_commit(evs[-1].rv)
-                    for w in list(st.watchers):
-                        if w is not exclude and w.status_interest:
-                            w._push_batch(evs)
+                    self._fan_out(st, evs, exclude, status=True)
                 return out
             out: list = []
             evs: List[WatchEvent] = []
@@ -1820,9 +2173,7 @@ class ResourceStore:
                 ):
                     # same per-batch commit note as the fast lane above
                     self._note_commit(evs[-1].rv)
-                for w in list(st.watchers):
-                    if w is not exclude and w.status_interest:
-                        w._push_batch(evs)
+                self._fan_out(st, evs, exclude, status=True)
             return out
 
     def _wal_status_batch(self, kind: str, items, out) -> None:
@@ -1924,8 +2275,7 @@ class ResourceStore:
                 if watchers and _telemetry.enabled():
                     # one commit-time note a batch, as the status batch's
                     self._note_commit(evs[-1].rv)
-                for w in watchers:
-                    w._push_batch(evs)
+                self._fan_out(st, evs, exclude)
             return out
 
     @staticmethod
@@ -2013,10 +2363,13 @@ class ResourceStore:
         tl = self._tel_local
         tl.in_batch = True
         tl.batch_rv = None
+        tl.filter_s = {}
         try:
             self._bulk_ops(ops, results, copy_results)
         finally:
             tl.in_batch = False
+            for kind, seconds in tl.filter_s.items():
+                _H_WATCH_FILTER.observe(seconds, kind)
             if tl.batch_rv is not None:
                 # one delivery-lag commit note per batch (the status-
                 # batch cadence): the last rv stands in for the burst

@@ -309,10 +309,12 @@ class TenantStore:
         field_selector=None,
         limit: int = 0,
         continue_from=None,
+        copy: bool = True,
     ):
-        # continue tokens stay store-global; pages filter to the tenant
-        # afterwards (a page may come back short — the token still
-        # advances, so pagination terminates correctly)
+        # continue tokens stay store-global (the store's LIST snapshot
+        # and a position in it); pages filter to the tenant afterwards
+        # (a page may come back short — the token still advances, so
+        # pagination terminates correctly)
         ns = (
             _map_ns(self.tenant, namespace)
             if namespace is not None and self._namespaced(kind)
@@ -326,6 +328,7 @@ class TenantStore:
             field_selector=field_selector,
             limit=limit,
             continue_from=continue_from,
+            copy=copy,
         )
         if self._is_ns_kind(kind):
             items = [

@@ -335,22 +335,22 @@ def test_list_page_resume_rv_not_pushed_past_mid_walk_write():
     s.create(pod("a0", ns0))
     s.create(pod("b0", ns1))
     shard1 = s._shards[1]
-    real = shard1.list_page
+    real = shard1._cut_pairs
     injected = {}
 
-    def racing(kind, **kw):
+    def racing(kind):
         if not injected:
-            # shard 0 was already paged; shard 1's own write drags the
+            # shard 0 was already cut; shard 1's own write drags the
             # at-return rvs past the shard-0 straggler
             injected["mid"] = s.create(pod("mid", ns0))
             s.create(pod("late", ns1))
-        return real(kind, **kw)
+        return real(kind)
 
-    shard1.list_page = racing
+    shard1._cut_pairs = racing
     try:
         items, rv, nxt = s.list_page("Pod")
     finally:
-        shard1.list_page = real
+        shard1._cut_pairs = real
     mid_rv = int(injected["mid"]["metadata"]["resourceVersion"])
     assert nxt is None
     assert rv < mid_rv

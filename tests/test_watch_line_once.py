@@ -140,12 +140,12 @@ class Counts:
 
     @staticmethod
     def _now():
-        enc = api_mod._H_LINES_ENCODED.snapshot().get(("Pod",), {"sum": 0.0, "count": 0})
-        sec = api_mod._H_ENCODE.snapshot().get(("Pod",), {"sum": 0.0, "count": 0})
+        enc = store_mod._H_LINES_ENCODED.snapshot().get(("Pod",), {"sum": 0.0, "count": 0})
+        sec = store_mod._H_ENCODE.snapshot().get(("Pod",), {"sum": 0.0, "count": 0})
         return {
             "encoded": enc["sum"],
             "bursts": enc["count"],
-            "written": api_mod._C_LINES.snapshot().get(("Pod",), 0),
+            "written": store_mod._C_LINES.snapshot().get(("Pod",), 0),
             "timed": sec["count"],
         }
 
