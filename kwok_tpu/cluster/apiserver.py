@@ -29,19 +29,24 @@ REST surface (kind-keyed rather than group/version-keyed; our
 - ``GET  /r/{plural}?watch=1&resourceVersion=N``  newline-delimited
   JSON watch stream (``{"type","object","rv"}``, BOOKMARK heartbeats).
   An event's line is shared and immutable, like the object it carries:
-  the store hands every watcher of a kind the same event instance, the
-  first stream that delivers it encodes the line and keeps it on the
-  event (``store.watch_line``), and every other stream (selected,
-  namespaced or resumed from the history ring alike; a Kubernetes-wire
-  stream cuts its frame from them) writes those bytes.  Only with a
-  tracer armed does each stream encode its own
+  the store hands every watcher of a kind the same event instance; the
+  line is an envelope round the object's JSON, which the committing
+  thread encoded for the WAL's record and left on the event (without a
+  WAL, and for a status or delete batch's events, the first stream
+  that delivers it encodes, ``store.watch_line``), and every stream
+  (selected, namespaced or resumed from the history ring alike; a
+  Kubernetes-wire stream cuts its frame from them) writes those
+  bytes.  Only with a tracer armed does each stream encode its own
   (the envelope then carries the delivery's ``ctx``)
 - ``POST /r/{plural}``                     create
 - ``GET/PUT/PATCH/DELETE /r/{plural}/{name}``     single object; query
   params ``namespace`` ``subresource``; PATCH type from Content-Type
   (application/{merge-patch,json-patch,strategic-merge-patch}+json)
 - ``POST /bulk``, ``POST /txn``            many mutations in one
-  round trip (``ResourceStore.bulk``; all-or-nothing ``transact``)
+  round trip (``ResourceStore.bulk``; all-or-nothing ``transact``);
+  the answer's entries are envelopes round the same bytes as the WAL
+  record and the watch line (``_send_results``): an object is turned
+  into JSON once a resourceVersion
 - ``POST /status-batch``                   ``{kind, items}``, items
   ``[namespace, name, status(, resourceVersion)]``: the columnar status
   commit (``ResourceStore.apply_status_batch``) a device player drains
@@ -64,6 +69,7 @@ each with a JSON body ``{"error", "reason"}``.
 
 from __future__ import annotations
 
+import functools
 import json
 import socket
 import threading
@@ -87,6 +93,7 @@ from kwok_tpu.cluster.store import (
     ResourceType,
     observe_watch_burst,
     observe_watch_delivery,
+    results_body,
     watch_line,
 )
 
@@ -130,6 +137,20 @@ _H_REQ = _telemetry.histogram(
     # the legitimate label product (verbs x registered kinds x levels
     # x shards) is wide; the cap stays a leak backstop, not a quota
     max_children=512,
+)
+
+#: what a ``POST /bulk`` costs the one interpreter every request and
+#: stream shares: CPU seconds of the request's thread (thread time, so
+#: without the turns it waited for) round the store call and the
+#: building of the answer, one observation a request, and its ops
+_H_BULK_CPU = _telemetry.histogram(
+    "kwok_bulk_cpu_seconds",
+    help="thread CPU seconds of a /bulk's store call and answer",
+    buckets=(0.0001, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5),
+)
+_C_BULK_OPS = _telemetry.counter(
+    "kwok_bulk_ops_total",
+    help="ops of the /bulk requests observed in kwok_bulk_cpu_seconds",
 )
 
 #: non-resource route heads that may appear as a ``kind`` label; any
@@ -280,7 +301,9 @@ class _Handler(BaseHTTPRequestHandler):
     # ------------------------------------------------------------- plumbing
 
     def _send_json(self, code: int, payload, retry_after=None) -> None:
-        body = json.dumps(payload).encode()
+        self._send_body(code, json.dumps(payload).encode(), retry_after)
+
+    def _send_body(self, code: int, body: bytes, retry_after=None) -> None:
         self.send_response(code)
         self.send_header("Content-Type", "application/json")
         if retry_after is not None:
@@ -288,6 +311,26 @@ class _Handler(BaseHTTPRequestHandler):
         self.send_header("Content-Length", str(len(body)))
         self.end_headers()
         self.wfile.write(body)
+
+    def _send_results(self, fn, body, cpu: bool = False) -> None:
+        """Answer ``/bulk``, ``/txn`` and their per-shard lanes from
+        what ``fn`` (the store's ``bulk`` or ``transact``) gives for the
+        body's ops.  The store's entries are JSON already, envelopes
+        round the bytes each commit left on its event, so nothing is
+        copied or encoded for an answer that is written and dropped.  A
+        tenant's proxy maps namespaces, so its answer is not the host's
+        bytes: it is encoded here, from the stored instances."""
+        ops = (body or {}).get("ops") or []
+        t0 = time.thread_time()
+        if self._tenant is None:
+            answer = results_body(fn(ops, as_user=self._user(), encoded=True))
+        else:
+            results = fn(ops, as_user=self._user(), copy_results=False)
+            answer = json.dumps({"results": results}).encode()
+        if cpu:
+            _H_BULK_CPU.observe(time.thread_time() - t0)
+            _C_BULK_OPS.inc(len(ops))
+        self._send_body(200, answer)
 
     def _send_error(self, exc: Exception) -> None:
         # same exception→code mapping as the k8s Status path, rendered
@@ -870,18 +913,12 @@ class _Handler(BaseHTTPRequestHandler):
                 )
                 self._send_json(201, {"status": "registered"})
             elif head == "bulk":
-                results = self.store.bulk(
-                    (body or {}).get("ops") or [], as_user=self._user()
-                )
-                self._send_json(200, {"results": results})
+                self._send_results(self.store.bulk, body, cpu=True)
             elif head == "txn":
                 # all-or-nothing sibling of /bulk (gang scheduling's
                 # commit lane); TransactionAborted → 409 via the shared
                 # error mapping, with the failing op index in the body
-                results = self.store.transact(
-                    (body or {}).get("ops") or [], as_user=self._user()
-                )
-                self._send_json(200, {"results": results})
+                self._send_results(self.store.transact, body)
             elif head == "status-batch" and self._tenant is None:
                 # the columnar sibling of a /bulk of status patches
                 # (module docstring); inside _dispatch like /bulk.  A
@@ -928,12 +965,11 @@ class _Handler(BaseHTTPRequestHandler):
                         404, {"error": "store is not sharded", "reason": "NotFound"}
                     )
                 else:
-                    results = fn(
-                        int(rest[0]),
-                        (body or {}).get("ops") or [],
-                        as_user=self._user(),
+                    self._send_results(
+                        functools.partial(fn, int(rest[0])),
+                        body,
+                        cpu=rest[1] == "bulk",
                     )
-                    self._send_json(200, {"results": results})
             elif head == "r" and len(rest) == 1:
                 out = self.store.create(
                     body, namespace=self._ns(q), as_user=self._user()
@@ -1084,11 +1120,11 @@ class _Handler(BaseHTTPRequestHandler):
                         payload["ctx"] = list(ctx)
                     out.append(self._encode_line(payload))
                 return out, len(out)
-            # a line is encoded by the first stream that delivers its
-            # event and kept on the event (store.watch_line): the
-            # store hands every watcher of a kind the same instances,
-            # so the other streams, of this dialect and of the
-            # Kubernetes wire, write these bytes
+            # a line is on the event since its commit, or encoded by
+            # the first stream that delivers it and kept there
+            # (store.watch_line): the store hands every watcher of a
+            # kind the same instances, so the other streams, of this
+            # dialect and of the Kubernetes wire, write these bytes
             out = []
             fresh = 0
             for e in burst:

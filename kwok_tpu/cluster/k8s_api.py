@@ -19,9 +19,10 @@ service discovery — can connect to a kwok-tpu cluster:
   (GET list/get, POST create, PUT update, PATCH with the three k8s
   patch content types, DELETE object + deletecollection),
   ``?watch=true`` chunk-streamed ``{"type","object"}`` frames with
-  optional BOOKMARK events (a frame is cut from the line the first
-  stream of either dialect encoded for the event, ``store.watch_line``:
-  one ``json.dumps`` an event whatever the number of streams; Table
+  optional BOOKMARK events (a frame is cut from the event's line,
+  ``store.watch_line``, whose object the commit encoded for the WAL
+  or else the first stream of either dialect: one ``json.dumps`` an
+  object and resourceVersion whatever the number of streams; Table
   and traced streams encode their own), ``limit``/``continue`` paging
   over one snapshot a LIST (every page carries the first page's
   resourceVersion and serves from it; a token whose snapshot is gone
@@ -1056,8 +1057,8 @@ class K8sFacade:
 
     def _shared_frames(self, rtype, burst) -> Tuple[List[bytes], int]:
         """The burst's frames, and how many this stream had to encode:
-        a frame is cut from the line that the first stream of either
-        dialect encoded for the event and left on it
+        a frame is cut from the line that the commit, or else the
+        first stream of either dialect, left on the event
         (``store.watch_line``), so N streams of a kind write the same
         bytes for one ``json.dumps``.  An object stored without its kind
         or apiVersion (none is, by ``create``) gets a frame of its own."""

@@ -40,6 +40,7 @@ state.
 from __future__ import annotations
 
 import contextlib
+import json
 import operator
 import zlib
 from typing import Any, Dict, List, Optional, Tuple
@@ -666,7 +667,10 @@ class ShardedStore:
         ops: List[dict],
         copy_results: bool = True,
         as_user: Optional[str] = None,
+        encoded: bool = False,
     ) -> List[dict]:
+        """``ResourceStore.bulk`` over the shards that own the ops;
+        ``encoded`` entries (JSON bytes) are merged like any others."""
         groups = self._group_ops(ops)
         if not groups:
             return self._shards[0].bulk(
@@ -680,6 +684,7 @@ class ShardedStore:
                 [op for _, op in pairs],
                 copy_results=copy_results,
                 as_user=as_user,
+                encoded=encoded,
             )
         results: List[Optional[dict]] = [None] * len(ops)
         for shard in sorted(groups):
@@ -688,6 +693,7 @@ class ShardedStore:
                 [op for _, op in pairs],
                 copy_results=copy_results,
                 as_user=as_user,
+                encoded=encoded,
             )
             for (i, _op), res in zip(pairs, out):
                 results[i] = res
@@ -698,6 +704,7 @@ class ShardedStore:
         ops: List[dict],
         as_user: Optional[str] = None,
         copy_results: bool = True,
+        encoded: bool = False,
     ) -> List[Optional[dict]]:
         ops = list(ops)
         if self.unsafe_split_cross_shard_txns:
@@ -718,6 +725,7 @@ class ShardedStore:
                     [op for _, op in pairs],
                     as_user=as_user,
                     copy_results=copy_results,
+                    encoded=encoded,
                 )
                 for (i, _op), res in zip(pairs, out):
                     results[i] = res
@@ -749,7 +757,10 @@ class ShardedStore:
             )
         (shard, pairs), = groups.items()
         return self._shards[shard].transact(
-            [op for _, op in pairs], as_user=as_user, copy_results=copy_results
+            [op for _, op in pairs],
+            as_user=as_user,
+            copy_results=copy_results,
+            encoded=encoded,
         )
 
     def shard_bulk(
@@ -758,6 +769,7 @@ class ShardedStore:
         ops: List[dict],
         copy_results: bool = True,
         as_user: Optional[str] = None,
+        encoded: bool = False,
     ) -> List[dict]:
         """The per-shard HTTP dispatch lane (``POST /shards/{i}/bulk``):
         the caller routed with its own copy of the route table, the
@@ -773,7 +785,7 @@ class ShardedStore:
             except NotFound:
                 owner = index
             if owner != index:
-                results[i] = {
+                entry = {
                     "status": "error",
                     "reason": "Misrouted",
                     "error": (
@@ -781,6 +793,7 @@ class ShardedStore:
                         "(stale route table?)"
                     ),
                 }
+                results[i] = json.dumps(entry).encode() if encoded else entry
             else:
                 checked.append((i, op))
         if checked:
@@ -788,6 +801,7 @@ class ShardedStore:
                 [op for _, op in checked],
                 copy_results=copy_results,
                 as_user=as_user,
+                encoded=encoded,
             )
             for (i, _op), res in zip(checked, out):
                 results[i] = res
@@ -799,6 +813,7 @@ class ShardedStore:
         ops: List[dict],
         as_user: Optional[str] = None,
         copy_results: bool = True,
+        encoded: bool = False,
     ) -> List[Optional[dict]]:
         """``POST /shards/{i}/txn``: ownership re-validated for every
         op (atomicity would silently narrow to "the subset that landed
@@ -816,7 +831,7 @@ class ShardedStore:
                     f"shard lane {index}",
                 )
         return self._shards[index].transact(
-            ops, as_user=as_user, copy_results=copy_results
+            ops, as_user=as_user, copy_results=copy_results, encoded=encoded
         )
 
     # ---------------------------------------------------------- batch verbs

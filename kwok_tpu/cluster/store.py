@@ -38,7 +38,14 @@ from collections import OrderedDict, deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Union
 
-from kwok_tpu.cluster.wal import BATCH_RECORDS, StorageDegraded, WalExhausted
+from kwok_tpu.cluster.wal import (
+    BATCH_RECORDS,
+    COMPACT,
+    StorageDegraded,
+    WalExhausted,
+    ev_record,
+    txn_record,
+)
 from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.utils import trace as _trace
 from kwok_tpu.utils.clock import Clock, RealClock
@@ -122,12 +129,45 @@ _C_LIST_SNAPSHOTS = _telemetry.counter(
     labelnames=("outcome",),
 )
 
+#: an object is turned into JSON once a resourceVersion: the times one
+#: of its three writers inside the apiserver (the WAL's ``ev`` record,
+#: the event's watch line, the object's entry in a ``/bulk`` or ``/txn``
+#: answer) had to ``json.dumps`` it (``encoded``) or wrote bytes another
+#: of them had left on the event (``reused``), one increment a use.  The
+#: histogram holds the ``reused`` uses again, one observation a commit,
+#: bulk or burst: ``_sum`` is what a reader that divides by a counter
+#: takes its numerator from
+_C_OBJECT_JSON = _telemetry.counter(
+    "kwok_object_json_total",
+    help="uses of a committed object's JSON, by whether the user had to encode it",
+    labelnames=("kind", "source"),
+)
+_H_OBJECT_JSON_REUSED = _telemetry.histogram(
+    "kwok_object_json_reused",
+    help="uses of a committed object's JSON that wrote kept bytes, per commit, bulk or burst",
+    buckets=(0, 1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048),
+    labelnames=("kind",),
+)
+
+
+def count_object_json(kind: str, encoded: int, reused: int) -> None:
+    """``encoded`` and ``reused`` more uses of the JSON of ``kind``'s
+    committed objects."""
+    if encoded:
+        _C_OBJECT_JSON.inc(encoded, kind, "encoded")
+    if reused:
+        _C_OBJECT_JSON.inc(reused, kind, "reused")
+    _H_OBJECT_JSON_REUSED.observe(reused, kind)
+
 
 def observe_watch_burst(kind: str, encoded: int, written: int, seconds: float) -> None:
-    """One flushed burst of a watch stream, either dialect."""
+    """One flushed burst of a watch stream, either dialect.  A line the
+    stream encoded is one more ``json.dumps`` of its object."""
     _H_LINES_ENCODED.observe(encoded, kind)
     _C_LINES.inc(written, kind)
     _H_ENCODE.observe(seconds, kind)
+    if encoded:
+        count_object_json(kind, encoded, 0)
 
 
 def observe_watch_delivery(store, rv: int) -> None:
@@ -632,10 +672,11 @@ class WatchEvent:
     type: str  # ADDED | MODIFIED | DELETED
     object: dict
     rv: int = 0
-    #: the event's NDJSON watch line, kept by the first stream that
-    #: encodes it (``watch_line`` below) for every other stream of
-    #: either dialect that carries this instance; immutable like
-    #: ``object``, and no part of what the event is
+    #: the event's NDJSON watch line, left by the commit where the
+    #: store has a WAL (``ResourceStore._emit``) and else by the first
+    #: reader that needs it (``watch_line`` below), for every stream of
+    #: either dialect that carries this instance and for the ``/bulk``
+    #: answer; immutable like ``object``, and no part of what the event is
     line: Optional[bytes] = field(default=None, compare=False, repr=False)
 
 
@@ -650,30 +691,60 @@ if _FAST is not None and hasattr(_FAST, "WatchEvent"):
     WatchEvent = _FAST.WatchEvent  # noqa: F811
 
 
+def _line_round(etype: str, obj_json: str, rv: int) -> bytes:
+    """The NDJSON watch line ``{"type", "object", "rv"}`` round an
+    object's compact JSON."""
+    return f'{{"type": "{etype}", "object": {obj_json}, "rv": {rv}}}\n'.encode()
+
+
 def watch_line(ev) -> Tuple[bytes, int]:
-    """The event's NDJSON watch line, ``{"type", "object", "rv"}`` as
-    ``json.dumps`` gives it, and 1 where this call had to encode it: the
-    first stream that delivers an event encodes the line and keeps it on
-    the event, every other stream of the kind, of either dialect, gets
-    those bytes.  On the stream's own thread, never under the store
-    mutex; two streams that race encode the same bytes twice."""
+    """The event's NDJSON watch line, ``{"type", "object", "rv"}`` round
+    the object's compact JSON, and 1 where this call had to encode it.
+    Where the store has a WAL the committing thread encoded the object
+    for its ``ev`` record and left the line on the event
+    (``ResourceStore._emit``), so the call is an attribute read; else
+    (no WAL, or an event of a status or delete batch, whose records
+    hold no object) the first reader that needs the bytes encodes them
+    here and keeps them on the event for every other stream of the
+    kind, of either dialect, and for the ``/bulk`` answer.  On the
+    reader's own thread, never under the store mutex; two readers that
+    race encode the same bytes twice."""
     line = ev.line
     if line is not None:
         return line, 0
-    line = ev.line = (
-        json.dumps({"type": ev.type, "object": ev.object, "rv": ev.rv}).encode()
-        + b"\n"
+    line = ev.line = _line_round(
+        ev.type, json.dumps(ev.object, separators=COMPACT), ev.rv
     )
     return line, 1
+
+
+#: what stands in a watch line before and after the event's type
+_LINE_HEAD = len(b'{"type": "') + len(b'", "object": ')
+_RV_MEMBER = b', "rv": '
+
+
+def object_json(etype: str, line: bytes) -> bytes:
+    """The object's JSON cut out of its event's ``watch_line``: what
+    stands between the envelope's head and its last ``, "rv": ``."""
+    return line[_LINE_HEAD + len(etype) : line.rindex(_RV_MEMBER)]
 
 
 def k8s_frame(line: bytes) -> bytes:
     """The Kubernetes-wire frame ``{"type", "object"}`` of the event
     whose ``watch_line`` is ``line``: the same bytes without the
-    envelope's last member.  (The cut is at the LAST ``, "rv": ``: what
-    follows the envelope's is digits and the closing brace, so no text
-    inside the object can stand in for it.)"""
-    return line[: line.rindex(b', "rv": ')] + b"}\n"
+    envelope's last member.  The cut is at the LAST ``, "rv": ``: what
+    follows the envelope's is digits and the closing brace, and the
+    object before it is compact JSON, in which a comma outside a string
+    is never followed by a space and a quote inside one is escaped, so
+    no text inside the object can stand in for it (nor could it: the
+    envelope's comes last)."""
+    return line[: line.rindex(_RV_MEMBER)] + b"}\n"
+
+
+def results_body(entries: List[bytes]) -> bytes:
+    """The answer ``{"results": [...]}`` of ``/bulk`` and ``/txn`` from
+    entries that are JSON already (``bulk(encoded=True)``)."""
+    return b'{"results": [' + b", ".join(entries) + b"]}"
 
 
 class _WatchRoutes:
@@ -1015,12 +1086,15 @@ class ResourceStore:
         else:
             self._wal.append(rec)
 
-    def _wal_event(self, etype: str, obj: dict, rv: int) -> None:
+    def _wal_event(self, etype: str, obj: dict, rv: int) -> str:
         """Append one committed mutation; caller holds the mutex and
-        has already checked ``self._wal is not None``."""
-        self._wal_put(
-            {"t": "ev", "rv": rv, "u": self._uid, "e": etype, "o": obj}
-        )
+        has already checked ``self._wal is not None``.  Returns the
+        object's compact JSON as the record holds it: the one encode of
+        the object at this resourceVersion, which :meth:`_emit` puts
+        into the event's watch line."""
+        obj_json = json.dumps(obj, separators=COMPACT)
+        self._wal_put(ev_record(rv, self._uid, etype, obj_json))
+        return obj_json
 
     def _check_writable(
         self, kind: str = "", namespace: Optional[str] = None
@@ -1058,8 +1132,9 @@ class ResourceStore:
 
     def _wal_event_or_rollback(
         self, etype: str, obj: dict, rv: int, undo: Callable[[], None]
-    ) -> None:
-        """Append the commit's WAL record; if the log cannot make it
+    ) -> str:
+        """Append the commit's WAL record (and return the object's JSON
+        in it, as :meth:`_wal_event` does); if the log cannot make it
         durable even through the emergency reserve, run ``undo`` (the
         in-memory commit has not been observed yet — no event was
         emitted, the ack was not sent) and surface StorageDegraded.
@@ -1067,7 +1142,7 @@ class ResourceStore:
         existed: the fsyncgate failure class, closed at the commit
         boundary."""
         try:
-            self._wal_event(etype, obj, rv)
+            return self._wal_event(etype, obj, rv)
         except WalExhausted as exc:
             undo()
             self._unbump(rv)
@@ -1293,14 +1368,30 @@ class ResourceStore:
         with self._mut:
             return self._commit_meta.get(rv)
 
-    def _emit(self, st: _TypeState, etype: str, obj: dict, rv: int) -> None:
+    def _emit(
+        self,
+        st: _TypeState,
+        etype: str,
+        obj: dict,
+        rv: int,
+        obj_json: Optional[str] = None,
+    ) -> None:
         # the event shares the stored instance — the same
         # handed-out-by-reference contract apply_status_batch pins:
         # every store mutation path is copy-on-write, so the instance
         # is immutable from here on; watchers/caches must not mutate
         # it.  (The former per-event deep copy was half the slow-path
         # drain cost at 1M objects.)
-        ev = WatchEvent(type=etype, object=obj, rv=rv)
+        line = None
+        if obj_json is not None:
+            # the WAL record's encode of the object is the event's too;
+            # without a WAL the first reader that needs the bytes encodes
+            # (watch_line), and a store nobody reads encodes nothing
+            line = _line_round(etype, obj_json, rv)
+            self._count_json(st.rtype.kind, 1, 1)
+        ev = WatchEvent(type=etype, object=obj, rv=rv, line=line)
+        # what bulk(encoded=True) and transact answer their op from
+        self._tel_local.emitted = (ev, st.rtype.kind)
         st.history.append(ev)
         if st.watchers and _telemetry.enabled():
             tl = self._tel_local
@@ -1462,15 +1553,16 @@ class ResourceStore:
             rv = self._bump(obj)
             st.objects[key] = obj
             self._index_update(st, key, None, obj)
+            obj_json = None
             if self._wal is not None:
 
                 def undo(st=st, key=key, obj=obj):
                     del st.objects[key]
                     self._index_update(st, key, obj, None)
 
-                self._wal_event_or_rollback(ADDED, obj, rv, undo)
+                obj_json = self._wal_event_or_rollback(ADDED, obj, rv, undo)
             self._commit_point("after-commit")
-            self._emit(st, ADDED, obj, rv)
+            self._emit(st, ADDED, obj, rv, obj_json)
             return obj if not copy_result else copy_json(obj)
 
     def get(self, kind: str, name: str, namespace: Optional[str] = None) -> dict:
@@ -1880,6 +1972,7 @@ class ResourceStore:
             elif old_gen is not None:
                 meta["generation"] = old_gen
         self._commit_point("before-commit")
+        obj_json = None
         if meta.get("deletionTimestamp") is not None and not meta.get("finalizers"):
             rv = self._bump(new)
             del st.objects[key]
@@ -1890,9 +1983,9 @@ class ResourceStore:
                     st.objects[key] = old
                     self._index_update(st, key, None, old)
 
-                self._wal_event_or_rollback(DELETED, new, rv, undo_reap)
+                obj_json = self._wal_event_or_rollback(DELETED, new, rv, undo_reap)
             self._commit_point("after-commit")
-            self._emit(st, DELETED, new, rv)
+            self._emit(st, DELETED, new, rv, obj_json)
             return new if not copy_result else copy_json(new)
         rv = self._bump(new)
         st.objects[key] = new
@@ -1907,9 +2000,9 @@ class ResourceStore:
                     st.objects[key] = old
                     self._index_update(st, key, new, old)
 
-            self._wal_event_or_rollback(MODIFIED, new, rv, undo_mod)
+            obj_json = self._wal_event_or_rollback(MODIFIED, new, rv, undo_mod)
         self._commit_point("after-commit")
-        self._emit(st, MODIFIED, new, rv)
+        self._emit(st, MODIFIED, new, rv, obj_json)
         return new if not copy_result else copy_json(new)
 
     def delete(
@@ -1937,6 +2030,7 @@ class ResourceStore:
             cur = dict(orig)
             meta = cur["metadata"] = dict(cur.get("metadata") or {})
             self._commit_point("before-commit")
+            obj_json = None
 
             def undo(st=st, key=key, orig=orig, cur=cur):
                 st.objects[key] = orig
@@ -1948,9 +2042,11 @@ class ResourceStore:
                     rv = self._bump(cur)
                     st.objects[key] = cur
                     if self._wal is not None:
-                        self._wal_event_or_rollback(MODIFIED, cur, rv, undo)
+                        obj_json = self._wal_event_or_rollback(
+                            MODIFIED, cur, rv, undo
+                        )
                     self._commit_point("after-commit")
-                    self._emit(st, MODIFIED, cur, rv)
+                    self._emit(st, MODIFIED, cur, rv, obj_json)
                 return cur if not copy_result else copy_json(cur)
             rv = self._bump(cur)
             del st.objects[key]
@@ -1961,9 +2057,9 @@ class ResourceStore:
                     st.objects[key] = orig
                     self._index_update(st, key, None, orig)
 
-                self._wal_event_or_rollback(DELETED, cur, rv, undo_del)
+                obj_json = self._wal_event_or_rollback(DELETED, cur, rv, undo_del)
             self._commit_point("after-commit")
-            self._emit(st, DELETED, cur, rv)
+            self._emit(st, DELETED, cur, rv, obj_json)
             return None
 
     # -------------------------------------------------------------------- watch
@@ -2290,11 +2386,39 @@ class ResourceStore:
             del meta["finalizers"]
         return gone
 
+    def _count_json(self, kind: str, encoded: int, reused: int) -> None:
+        """``count_object_json``, once a :meth:`bulk` for what its ops
+        add up to, like its ``kwok_watch_filter_seconds``."""
+        tl = self._tel_local
+        if getattr(tl, "in_batch", False):
+            uses = tl.json_uses.setdefault(kind, [0, 0])
+            uses[0] += encoded
+            uses[1] += reused
+        else:
+            count_object_json(kind, encoded, reused)
+
+    def _answer_json(self, out: Optional[dict], emitted) -> bytes:
+        """The JSON of what an op answered (``out``), from the bytes its
+        commit left on the event (``emitted``: ``_emit``'s word of the
+        op's commit, or None).  Not under the mutex: without a WAL the
+        object is encoded here, and kept for the streams."""
+        if out is None:
+            return b"null"  # a completed delete
+        if emitted is not None and emitted[0].object is out:
+            ev, kind = emitted
+            line, fresh = watch_line(ev)
+            self._count_json(kind, fresh, 1 - fresh)
+            return object_json(ev.type, line)
+        # no commit of its own: a delete that found the object terminating
+        self._count_json(str(out.get("kind") or ""), 1, 0)
+        return json.dumps(out, separators=COMPACT).encode()
+
     def bulk(
         self,
         ops: List[dict],
         copy_results: bool = True,
         as_user: Optional[str] = None,
+        encoded: bool = False,
     ) -> List[dict]:
         """Apply many mutations in one call — the device backend's
         dirty-row drain (SURVEY §2.9: only dirty rows cross the
@@ -2313,8 +2437,15 @@ class ResourceStore:
         ``copy_results=False`` hands back stored instances (immutable
         by contract) — the in-process drain adopts them into its row
         mirrors, and deep-copying a 1M-row create wave was most of its
-        cost.  The HTTP facade keeps the default (it serializes results
-        outside the store lock).
+        cost.  The default copies, for in-process callers that keep or
+        change what they get.
+
+        ``encoded=True`` is the HTTP routes': every entry comes back as
+        the JSON bytes of what is described above, an ``ok`` entry as
+        an envelope round the bytes its commit left on the event (with
+        a WAL the object was encoded once, for its record; the watch
+        line holds the same bytes), so nothing is copied and nothing is
+        encoded twice; :func:`results_body` joins them.
 
         Besides the per-op entries, one ``("bulk", "<kinds>:<n>",
         as_user)`` summary lands in the audit log per call — the
@@ -2364,12 +2495,15 @@ class ResourceStore:
         tl.in_batch = True
         tl.batch_rv = None
         tl.filter_s = {}
+        tl.json_uses = {}
         try:
-            self._bulk_ops(ops, results, copy_results)
+            self._bulk_ops(ops, results, copy_results, encoded)
         finally:
             tl.in_batch = False
             for kind, seconds in tl.filter_s.items():
                 _H_WATCH_FILTER.observe(seconds, kind)
+            for kind, (fresh, reused) in tl.json_uses.items():
+                count_object_json(kind, fresh, reused)
             if tl.batch_rv is not None:
                 # one delivery-lag commit note per batch (the status-
                 # batch cadence): the last rv stands in for the burst
@@ -2398,8 +2532,16 @@ class ResourceStore:
                             ) from exc
         return results
 
-    def _bulk_ops(self, ops, results, copy_results) -> None:
+    def _bulk_ops(self, ops, results, copy_results, encoded) -> None:
+        copy = copy_results and not encoded
+        tl = self._tel_local
+
+        def failed(reason: str, exc: Exception) -> None:
+            entry = {"status": "error", "reason": reason, "error": str(exc)}
+            results.append(json.dumps(entry).encode() if encoded else entry)
+
         for op in ops:
+            tl.emitted = None
             try:
                 verb = op.get("verb")
                 if verb == "patch":
@@ -2412,7 +2554,7 @@ class ResourceStore:
                         subresource=op.get("subresource", ""),
                         as_user=op.get("as_user"),
                         expect=op.get("expect"),
-                        copy_result=copy_results,
+                        copy_result=copy,
                     )
                 elif verb == "delete":
                     out = self.delete(
@@ -2420,41 +2562,36 @@ class ResourceStore:
                         op["name"],
                         namespace=op.get("namespace"),
                         as_user=op.get("as_user"),
-                        copy_result=copy_results,
+                        copy_result=copy,
                     )
                 elif verb == "create":
                     out = self.create(
                         op["data"],
                         namespace=op.get("namespace"),
                         as_user=op.get("as_user"),
-                        copy_result=copy_results,
+                        copy_result=copy,
                     )
                 else:
                     raise ValueError(f"unknown bulk verb {verb!r}")
-                results.append({"status": "ok", "object": out})
+                if encoded:
+                    results.append(
+                        b'{"status": "ok", "object": '
+                        + self._answer_json(out, tl.emitted)
+                        + b"}"
+                    )
+                else:
+                    results.append({"status": "ok", "object": out})
             except NotFound as exc:
-                results.append(
-                    {"status": "error", "reason": "NotFound", "error": str(exc)}
-                )
+                failed("NotFound", exc)
             except Conflict as exc:
-                results.append(
-                    {"status": "error", "reason": "Conflict", "error": str(exc)}
-                )
+                failed("Conflict", exc)
             except StorageDegraded as exc:
                 # a pressure window opened mid-batch: the remaining ops
                 # get the same machine-readable rejection a fresh
                 # request would
-                results.append(
-                    {
-                        "status": "error",
-                        "reason": "StorageDegraded",
-                        "error": str(exc),
-                    }
-                )
+                failed("StorageDegraded", exc)
             except Exception as exc:  # noqa: BLE001 — per-op isolation
-                results.append(
-                    {"status": "error", "reason": "Invalid", "error": str(exc)}
-                )
+                failed("Invalid", exc)
 
     # --------------------------------------------------------------- transact
 
@@ -2468,6 +2605,7 @@ class ResourceStore:
         ops: List[dict],
         as_user: Optional[str] = None,
         copy_results: bool = True,
+        encoded: bool = False,
     ) -> List[Optional[dict]]:
         """All-or-nothing sibling of :meth:`bulk` — the gang-scheduling
         commit lane (``kwok_tpu/sched/engine.py`` binds a whole
@@ -2491,8 +2629,13 @@ class ResourceStore:
         of validation.  ``create`` ops must carry a concrete name
         (``generateName`` alone would make validation a guess).
         Returns one result per op: the committed object, or None for a
-        completed delete.
+        completed delete; with ``encoded=True`` (``POST /txn``) its JSON
+        bytes, from what the commit left on the event, as
+        :meth:`bulk`'s.
         """
+        copy = copy_results and not encoded
+        emitted: List[Any] = []
+        tl = self._tel_local
         with self._mut:
             self._check_writable()
             # ---------------- phase 1: validate (mutates nothing) ----
@@ -2688,6 +2831,7 @@ class ResourceStore:
             results: List[Optional[dict]] = []
             try:
                 for op in ops:
+                    tl.emitted = None
                     verb = op["verb"]
                     user = op.get("as_user") or as_user
                     if verb == "create":
@@ -2695,7 +2839,7 @@ class ResourceStore:
                             op["data"],
                             namespace=op.get("namespace"),
                             as_user=user,
-                            copy_result=copy_results,
+                            copy_result=copy,
                         )
                     elif verb == "patch":
                         out = self.patch(
@@ -2707,7 +2851,7 @@ class ResourceStore:
                             subresource=op.get("subresource", ""),
                             as_user=user,
                             expect=op.get("expect"),
-                            copy_result=copy_results,
+                            copy_result=copy,
                         )
                     else:
                         out = self.delete(
@@ -2715,9 +2859,10 @@ class ResourceStore:
                             op["name"],
                             namespace=op.get("namespace"),
                             as_user=user,
-                            copy_result=copy_results,
+                            copy_result=copy,
                         )
                     results.append(out)
+                    emitted.append(tl.emitted)
             except BaseException:
                 # validation guarantees this is unreachable for
                 # precondition failures; what remains is a crash hook
@@ -2732,21 +2877,18 @@ class ResourceStore:
                 buf = self._wal_local.buf
                 self._wal_local.buf = prev_buf
                 if buf:
-                    txn = {
-                        "t": "txn",
-                        "rv": max(int(r.get("rv", 0) or 0) for r in buf),
-                        "recs": buf,
-                    }
                     try:
                         # _wal_put: lands directly, or joins an outer
                         # bulk deferral as one (still atomic) record
-                        self._wal_put(txn)
+                        self._wal_put(txn_record(buf))
                     except WalExhausted as exc:
                         # committed in memory but not durable: refuse
                         # the ack; a crash before space returns rolls
                         # the whole txn back together (see bulk())
                         raise StorageDegraded(exc.reason, str(exc)) from exc
-            return results
+        if encoded:
+            return [self._answer_json(out, ev) for out, ev in zip(results, emitted)]
+        return results
 
     # -------------------------------------------------------------- persistence
 

@@ -208,9 +208,52 @@ def _frame(seq: int, payload: str) -> str:
     return f"{seq} {crc:08x} {payload}\n"
 
 
-def encode_record(seq: int, record: Dict[str, Any]) -> str:
-    """One framed line for ``record`` (compact JSON, seq + CRC32)."""
-    return _frame(seq, json.dumps(record, separators=(",", ":")))
+#: how every record, and the object inside an ``ev`` record, is written
+COMPACT = (",", ":")
+
+
+class EncodedRecord:
+    """A record whose payload the committing thread already wrote
+    (:func:`ev_record`, :func:`txn_record`): the store encodes a
+    committed object once, for its ``ev`` record, and the event's watch
+    line and the ``/bulk`` answer are envelopes round the same bytes.
+    ``lo`` and ``hi`` are the resourceVersions it spans, which is all
+    ``WriteAheadLog._note_rv`` reads of a record."""
+
+    __slots__ = ("payload", "lo", "hi")
+
+    def __init__(self, payload: str, lo: int, hi: int):
+        self.payload = payload
+        self.lo = lo
+        self.hi = hi
+
+
+def ev_record(rv: int, uid: int, etype: str, obj_json: str) -> EncodedRecord:
+    """``{"t": "ev", "rv", "u", "e", "o"}`` round an object's compact
+    JSON: byte for byte what ``json.dumps`` of that record gives,
+    without a second walk over the object."""
+    return EncodedRecord(
+        f'{{"t":"ev","rv":{rv},"u":{uid},"e":"{etype}","o":{obj_json}}}', rv, rv
+    )
+
+
+def txn_record(recs: List[EncodedRecord]) -> EncodedRecord:
+    """``transact``'s one ``txn`` record over its ops' ``ev`` records,
+    framed whole so that it replays all or nothing."""
+    lo = min(r.lo for r in recs)
+    hi = max(r.hi for r in recs)
+    body = ",".join(r.payload for r in recs)
+    return EncodedRecord(f'{{"t":"txn","rv":{hi},"recs":[{body}]}}', lo, hi)
+
+
+def encode_record(seq: int, record) -> str:
+    """One framed line for ``record`` (compact JSON, seq + CRC32).  A
+    plain dict is encoded here; an :class:`EncodedRecord` brings its
+    payload, whose object the store encoded at the commit and shares
+    with the event's watch line and the answer (``store._wal_event``)."""
+    if isinstance(record, EncodedRecord):
+        return _frame(seq, record.payload)
+    return _frame(seq, json.dumps(record, separators=COMPACT))
 
 
 def _parse_frame(line: str) -> Tuple[Optional[int], Dict[str, Any], bool]:
@@ -774,29 +817,32 @@ class WriteAheadLog:
 
     # ------------------------------------------------------------ writing
 
-    def _note_rv(self, record: Dict[str, Any]) -> None:
-        rvs = []
-        if record.get("t") == "txn":
-            # a txn frame spans its inner events' whole rv range — the
-            # segment floor must reflect the smallest, or compaction
-            # bookkeeping would overstate what this file retains
-            for sub in record.get("recs") or []:
-                try:
-                    rvs.append(int(sub.get("rv", 0)))
-                except (TypeError, ValueError):
-                    pass
-        try:
-            rvs.append(int(record.get("rv", 0)))
-        except (TypeError, ValueError):
-            rvs.append(0)
-        lo, hi = min(rvs), max(rvs)
+    def _note_rv(self, record) -> None:
+        # a txn frame spans its inner events' whole rv range — the
+        # segment floor must reflect the smallest, or compaction
+        # bookkeeping would overstate what this file retains
+        if isinstance(record, EncodedRecord):
+            lo, hi = record.lo, record.hi
+        else:
+            rvs = []
+            if record.get("t") == "txn":
+                for sub in record.get("recs") or []:
+                    try:
+                        rvs.append(int(sub.get("rv", 0)))
+                    except (TypeError, ValueError):
+                        pass
+            try:
+                rvs.append(int(record.get("rv", 0)))
+            except (TypeError, ValueError):
+                rvs.append(0)
+            lo, hi = min(rvs), max(rvs)
         if self._active_min_rv is None or lo < self._active_min_rv:
             self._active_min_rv = lo
         if self._active_max_rv is None or hi > self._active_max_rv:
             self._active_max_rv = hi
         self._active_records += 1
 
-    def append(self, record: Dict[str, Any]) -> None:
+    def append(self, record) -> None:
         self.append_many([record])
 
     def append_many(self, records) -> None:

@@ -415,9 +415,14 @@ def test_n_kubernetes_wire_streams_write_the_same_bytes_for_one_encode(one_turn_
                 s.close()
     history = list(store._state("Pod").history)
     assert len(history) == events
-    # the frame is what json.dumps of the Kubernetes envelope gives, as before
-    assert got[0] == [json.dumps({"type": e.type, "object": e.object}).encode() + b"\n"
-                      for e in history]
+    # the frame is the Kubernetes envelope round the object's compact JSON
+    # (the WAL's style since PR 37), and parses to what it did before
+    assert got[0] == [
+        b'{"type": "%s", "object": %s}\n'
+        % (e.type.encode(), json.dumps(e.object, separators=(",", ":")).encode())
+        for e in history]
+    assert [json.loads(f) for f in got[0]] == [
+        {"type": e.type, "object": e.object} for e in history]
     assert all(lines == got[0] for lines in got[1:])
     # a scoped stream writes its share of the same bytes, the other dialect
     # the line they were cut from

@@ -203,12 +203,16 @@ def test_every_stream_writes_the_bytes_the_first_one_encoded(one_turn_a_burst):
             for s in streams:
                 s.close()
     assert all(lines == got[0] for lines in got[1:])
-    # the bytes are what json.dumps of the envelope gives, as before
+    # the bytes are the envelope round the object's compact JSON (the
+    # WAL's style since PR 37), and parse to what they did before
     history = list(store._state("Pod").history)
     assert len(history) == events
     assert got[0] == [
-        json.dumps({"type": e.type, "object": e.object, "rv": e.rv}).encode() + b"\n"
+        b'{"type": "%s", "object": %s, "rv": %d}\n'
+        % (e.type.encode(), json.dumps(e.object, separators=(",", ":")).encode(), e.rv)
         for e in history]
+    assert [json.loads(ln) for ln in got[0]] == [
+        {"type": e.type, "object": e.object, "rv": e.rv} for e in history]
     assert [json.loads(ln)["type"] for ln in got[0]] == (
         ["ADDED"] * 12 + ["MODIFIED"] * 24 + ["DELETED"] * 12)
     # each event in order, once; and the line stays on the event
