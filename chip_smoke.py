@@ -404,8 +404,9 @@ def phase_deployed(a) -> dict:
             "compile_seconds": _metric(ms, "kwok_jit_compile_seconds_total"),
             "cache_hits": _metric(ms, "kwok_jit_compile_cache_hits_total"),
             "cache_misses": _metric(ms, "kwok_jit_compile_cache_misses_total"),
-            "tick_lag_p99_s": _metric(ms, "kwok_tick_lag_seconds",
-                                      kind="Pod", quantile="0.99"),
+            "tick_lag_mean_s": round(
+                _metric(ms, "kwok_tick_lag_seconds_sum", kind="Pod")
+                / max(_metric(ms, "kwok_tick_lag_seconds_count", kind="Pod"), 1), 6),
         }
         m["native_drain_apiserver"] = (client.stats().get("native") or {}).get("fastdrain")
         res["kwok_metrics"] = m

@@ -205,12 +205,14 @@ def start_config_watcher(client, srv, done: threading.Event, base_configs=None) 
 
 
 def _controller_self_metrics(get_ctr, elector=None, device=None):
-    """Self-metrics updater: stage transitions/patches per kind (host
-    and device paths), device tick-lag quantiles (the p99
-    heartbeat-lag signal, SURVEY §7 step 5), and this replica's
-    leader-election state.  ``get_ctr`` indirects through the election
-    holder — a standby replica has no Controller yet (None), but its
-    election gauges still publish.  ``device`` is what the device
+    """Self-metrics updater: the process's start-up milestones, stage
+    transitions/patches per kind (host and device paths), the Node
+    player's Ready wave, lease heartbeat health (SURVEY §7 step 5), and
+    this replica's leader-election state.  (The tick loop's lag is a
+    histogram of ``utils/telemetry``'s registry, observed where it
+    happens: controllers/device_player.py.)  ``get_ctr`` indirects
+    through the election holder — a standby replica has no Controller
+    yet (None), but its election gauges still publish.  ``device`` is what the device
     backend runs on (utils/accel.device_info), None on the host
     backend: with it the scrape says which platform ticks, what the
     jit compiles cost, and whether the native units loaded — nothing
@@ -253,14 +255,20 @@ def _controller_self_metrics(get_ctr, elector=None, device=None):
                 elector.stepdowns,
                 lease=elector.lease_name,
             )
-            age = elector.last_renew_age()
-            if age is not None:
-                gauge(
-                    "kwok_leader_election_last_renew_age_seconds",
-                    "Seconds since the last successful lease renew.",
-                    round(age, 3),
-                    lease=elector.lease_name,
-                )
+
+        from kwok_tpu.utils import telemetry
+
+        marks = telemetry.milestones()
+        for milestone, labels, seconds in marks.snapshot():
+            gauge(
+                "kwok_process_milestone_seconds",
+                f"Seconds from {marks.origin} to a milestone of this daemon, "
+                "each set once: main (arguments parsed), device_ready, "
+                "apiserver_ready, leading, reconciling, first_tick by kind.",
+                round(seconds, 3),
+                milestone=milestone,
+                **labels,
+            )
 
         from kwok_tpu.native import status as native_status
 
@@ -347,31 +355,13 @@ def _controller_self_metrics(get_ctr, elector=None, device=None):
                 kind=kind,
                 backend=backend,
             )
-            raw = getattr(p, "tick_lags", None)
-            lags = []
-            if raw:
-                # the tick thread appends concurrently; a mid-copy
-                # mutation raises RuntimeError — retry once, else skip
-                for _ in range(2):
-                    try:
-                        lags = sorted(raw)
-                        break
-                    except RuntimeError:
-                        continue
-            if lags:
-                for q in (0.5, 0.99):
-                    gauge(
-                        "kwok_tick_lag_seconds",
-                        "Device tick-loop lag behind schedule.",
-                        lags[min(len(lags) - 1, int(q * len(lags)))],
-                        kind=kind,
-                        quantile=str(q),
-                    )
+            if getattr(p, "wave_wall_s", None) is not None:
                 gauge(
-                    "kwok_tick_lag_seconds_max",
-                    "Max recent device tick-loop lag.",
-                    lags[-1],
-                    kind=kind,
+                    "kwok_node_wave_wall_seconds",
+                    "Seconds from the Node player's first admission to the "
+                    "newest acknowledged status commit that held a node row "
+                    "never committed before: the Ready wave's wall.",
+                    round(p.wave_wall_s, 3),
                 )
 
         # lease heartbeat health (SURVEY §7 step 5): renewals + p99 lag,
@@ -417,6 +407,10 @@ def _controller_self_metrics(get_ctr, elector=None, device=None):
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    from kwok_tpu.utils.telemetry import milestones
+
+    mark = milestones().mark
+    mark("main")
     # install the process tracer at boot (KWOK_TRACE_ENDPOINT /
     # KWOK_TRACE_SERVICE from the runtime): watch streams opened
     # before the first traced request must already see it to
@@ -461,6 +455,7 @@ def main(argv=None) -> int:
         # to initialize backend" under JAX_PLATFORMS=tpu
         print(f"error: --backend device: {exc}", file=sys.stderr)
         return 1
+    mark("device_ready")
 
     client = ClusterClient(
         args.server,
@@ -471,6 +466,7 @@ def main(argv=None) -> int:
     if not client.wait_ready(timeout=args.wait_timeout):
         print(f"apiserver {args.server} not ready", file=sys.stderr)
         return 1
+    mark("apiserver_ready")
 
     # the Controller lives behind the leader election: built and
     # started on acquisition, stopped (node leases released) on
@@ -479,12 +475,14 @@ def main(argv=None) -> int:
     ctr_mut = threading.Lock()
 
     def start_controllers(active=None) -> None:
+        mark("leading")
         with ctr_mut:
             if holder["ctr"] is not None:
                 return
             c = Controller(client, conf, local_stages=stages, seed=args.seed)
             c.start()
             holder["ctr"] = c
+        mark("reconciling")
         print("kwok controller reconciling", flush=True)
 
     def stop_controllers() -> None:

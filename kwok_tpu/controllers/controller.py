@@ -29,6 +29,7 @@ from kwok_tpu.controllers.node_lease_controller import NodeLeaseController
 from kwok_tpu.controllers.pod_controller import PodController
 from kwok_tpu.controllers.stage_controller import StageController
 from kwok_tpu.controllers.stages_manager import StagesManager
+from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.utils.clock import Clock, RealClock
 from kwok_tpu.utils.log import get_logger
 from kwok_tpu.utils.queue import Queue
@@ -200,17 +201,20 @@ class Controller:
         """Lease acquired (or leases disabled): simulate the node and
         re-feed its pods (reference controller.go:276-279). Device
         players get the same catch-up — events dropped while read-only
-        are replayed."""
-        if self.nodes is not None:
-            self.nodes.manage_node(node_name)
-        if self.pods is not None:
-            self.pods.sync_node(node_name)
-        # snapshot: lease workers land here while _start_device_controller
-        # inserts the next kind's player under _mut
-        with self._mut:
-            players = list(self.device_players.values())
-        for dp in players:
-            dp.sync_node(node_name)
+        are replayed.  One ``NodeBringup/node_sync`` stage a node, on the
+        lease worker's thread that took it."""
+        with _telemetry.stage("NodeBringup", "node_sync"):
+            if self.nodes is not None:
+                self.nodes.manage_node(node_name)
+            if self.pods is not None:
+                self.pods.sync_node(node_name)
+            # snapshot: lease workers land here while
+            # _start_device_controller inserts the next kind's player
+            # under _mut
+            with self._mut:
+                players = list(self.device_players.values())
+            for dp in players:
+                dp.sync_node(node_name)
 
     def _on_node_unmanaged(self, node_name: str) -> None:
         if self.node_leases is not None:

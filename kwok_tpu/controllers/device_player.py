@@ -112,6 +112,24 @@ _H_DELETE_TO_GONE = _telemetry.histogram(
     labelnames=("kind",),
 )
 
+#: how far past its schedule an iteration of the paced tick loop began
+#: (0 in saturation mode): one observation an iteration, so a window
+#: reads its own mean, where a ring of the last samples read the daemon's
+_H_TICK_LAG = _telemetry.histogram(
+    "kwok_tick_lag_seconds",
+    help="device tick loop: seconds an iteration began past its schedule",
+    buckets=(0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0),
+    labelnames=("kind",),
+)
+
+#: virtual seconds the dispatches covered (sub-ticks times ``tick_ms``):
+#: over a stretch of wall time, 1.0 a second while the loop keeps its pace
+_VIRTUAL_PLAYED = _telemetry.counter(
+    "kwok_virtual_seconds_played_total",
+    help="virtual seconds covered by the device dispatches of a tick loop",
+    labelnames=("kind",),
+)
+
 #: live players for the interpreter-exit safety net: a daemon tick
 #: thread killed mid-XLA-dispatch at teardown aborts the whole process
 #: ("terminate called ... FATAL: exception not rethrown", rc=134), so
@@ -218,12 +236,17 @@ class DeviceStagePlayer:
         #: (native fast_group) — reported separately by the bench so
         #: the breakdown names the real bottleneck
         self.t_build = 0.0
-        #: recent tick-lag samples in seconds (how far the real-time
-        #: loop fell behind its schedule) — the p99 heartbeat-lag
-        #: signal from SURVEY §7 step 5
-        from collections import deque
-
-        self.tick_lags = deque(maxlen=1024)
+        #: the Ready wave of a Node player (None on every other kind):
+        #: row-indexed, whether a status commit the store acknowledged
+        #: has held the row since it was admitted (cleared at release);
+        #: the instant of the first admission; seconds from it to the
+        #: newest commit that held a row never committed before
+        #: (``kwok_node_wave_wall_seconds``; see _note_committed)
+        self._committed: Optional[np.ndarray] = (
+            np.zeros(capacity, np.bool_) if kind == "Node" else None
+        )
+        self._wave_t0: Optional[float] = None
+        self.wave_wall_s: Optional[float] = 0.0 if kind == "Node" else None
         # which object state the stage templates read: gates whether a
         # multi-op transition may render every patch from one base (see
         # _collect_ops)
@@ -371,6 +394,10 @@ class DeviceStagePlayer:
             self._row_since = np.concatenate(
                 [self._row_since, np.zeros(cap - len(self._row_since), np.int64)]
             )
+        if self._committed is not None and len(self._committed) < cap:
+            self._committed = np.concatenate(
+                [self._committed, np.zeros(cap - len(self._committed), np.bool_)]
+            )
 
     def _rematch_row(self, row: int) -> None:
         """Extract the row's features from its mirror again and have
@@ -400,6 +427,8 @@ class DeviceStagePlayer:
                 evs = _FAST.filter_stale(evs, self._rows, self._written_rv)
             for ev in evs:
                 self._apply_event_locked(ev)
+            if self._wave_t0 is None and self._committed is not None and self._rows:
+                self._wave_t0 = time.perf_counter()
 
     def _apply_event_locked(self, ev: InformerEvent) -> None:
         obj = ev.object
@@ -407,12 +436,7 @@ class DeviceStagePlayer:
         key = (meta.get("namespace") or "", meta.get("name") or "")
         row = self._rows.get(key)
         if ev.type == DELETED:
-            if row is not None:
-                self.sim.release(row)
-                del self._rows[key]
-                if row < len(self._written_rv):
-                    self._written_rv[row] = None
-                self._drop_render_cache(row)
+            self._release_locked(key)
             if self.on_delete is not None:
                 self.on_delete(obj)
             return
@@ -474,12 +498,12 @@ class DeviceStagePlayer:
                     # back — device computes batch N+1 while the host
                     # drains batch N
                     self.step_pipelined(self.tick_ms, self.macro_ticks)
-                    self.tick_lags.append(0.0)
+                    _H_TICK_LAG.observe(0.0, self.kind)
                     continue
                 behind = self.clock.now() - next_tick
                 # one lag sample per paced iteration: how far this
                 # tick started past its schedule
-                self.tick_lags.append(max(behind, 0.0))
+                _H_TICK_LAG.observe(behind, self.kind)
                 if behind > dt_s:
                     # behind cadence: cover the missed ticks with ONE
                     # overlapped macro-tick instead of spiraling (the
@@ -586,11 +610,20 @@ class DeviceStagePlayer:
         with _stage(self.kind, "device_tick") as sp:
             stages_np, t0_ms = self.sim.tick_many(dt, n_ticks)
         self._dispatches += 1
+        self._note_dispatch(dt, n_ticks)
         self.t_device += sp.elapsed
         fired_total = self._drain_stages(stages_np, t0_ms, dt, self._dispatches)
         self._run_post_tick()
         self._observe_tick(base, fired_total)
         return fired_total
+
+    def _note_dispatch(self, dt_ms: int, n_ticks: int) -> None:
+        """A ``device_tick`` stage that dispatched ``n_ticks`` sub-ticks
+        has closed: the virtual time it covers and, the first time in
+        the process, the ``first_tick`` milestone of this kind (its
+        compile is inside the stage)."""
+        _VIRTUAL_PLAYED.inc(n_ticks * dt_ms / 1000.0, self.kind)
+        _telemetry.milestones().mark("first_tick", kind=self.kind)
 
     def _observe_tick(
         self, base: Tuple[float, float, float, float], fired: int
@@ -743,6 +776,7 @@ class DeviceStagePlayer:
             if prev is not None:
                 p_stages, p_t0, p_dt, p_dispatch = prev
                 stages_np = np.asarray(jax.device_get(p_stages))
+        self._note_dispatch(dt, n_ticks)
         self.t_device += sp.elapsed
         fired = 0
         if prev is not None:
@@ -1018,16 +1052,34 @@ class DeviceStagePlayer:
             n_ok, refused = self._confirm_python_locked(results, rows, items)
         _H_COMMIT_ROWS.observe(n_ok, self.kind, "batch")
         st = self._drain_st
+        committed = rows
         if n_ok == len(rows):
             for s_idx, n in enumerate(np.bincount(st[rows]).tolist()):
                 self._note_fired(s_idx, "batch", n)
         else:
             # a refused row is played, and counted, by _drain_slow; one
             # whose object is gone is released and counts nowhere
-            for row, res in zip(rows, results):
-                if res is not None and res is not False:
-                    self._note_fired(int(st[row]), "batch")
+            committed = [
+                row for row, res in zip(rows, results)
+                if res is not None and res is not False
+            ]
+            for row in committed:
+                self._note_fired(int(st[row]), "batch")
+        if self._committed is not None:
+            self._note_committed(committed)
         return [rows[idx] for idx in refused]
+
+    def _note_committed(self, rows: List[int]) -> None:
+        """The store acknowledged a status commit of these rows of the
+        Node player: if any had not been in one since its admission, the
+        Ready wave reaches to now.  One test a request; a heartbeat's
+        commit of rows long Ready finds none and moves nothing."""
+        if not rows:
+            return
+        idx = np.asarray(rows)
+        if not self._committed[idx].all():
+            self._committed[idx] = True
+            self.wave_wall_s = time.perf_counter() - self._wave_t0
 
     def _delete_is_one_event(self, s_idx: int, meta: dict) -> bool:
         """Whether all that the per-row path would make of this fired
@@ -1270,6 +1322,13 @@ class DeviceStagePlayer:
             # whatever was played: through the bulk, by _play_transition,
             # or with nothing to send
             _H_COMMIT_ROWS.observe(self.transitions - played, self.kind, "slow")
+            if self._committed is not None and self.transitions != played:
+                with self._mut:
+                    objects = self.sim.objects
+                    # a row whose object is gone was released, not committed
+                    self._note_committed(
+                        [tr.row for tr in transitions if objects[tr.row] is not None]
+                    )
 
     def _finish_delete(self, key: Tuple[str, str], out: Optional[dict]) -> None:
         """Complete a stage-driven delete: fully gone → release the
@@ -1618,6 +1677,8 @@ class DeviceStagePlayer:
             if row < len(self._written_rv):
                 self._written_rv[row] = None
             self._drop_render_cache(row)
+            if self._committed is not None:
+                self._committed[row] = False
 
     def _refresh(
         self,

@@ -17,9 +17,10 @@ from __future__ import annotations
 import datetime
 import random
 import threading
-from typing import Callable, Dict, List, Optional, Set
+from typing import Callable, Dict, List, Optional, Set, Tuple
 
 from kwok_tpu.cluster.store import Conflict, NotFound, ResourceStore
+from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.utils.clock import Clock, RealClock
 from kwok_tpu.utils.queue import DelayingQueue
 
@@ -253,7 +254,28 @@ class NodeLeaseController:
     def _sync(self, name: str) -> float:
         """Renew or acquire; returns seconds until next try
         (node_lease_controller.go:174-214 sync + :322-338
-        nextTryDuration)."""
+        nextTryDuration).  A call for a node this controller does not
+        hold is a ``NodeBringup/lease_acquire`` stage, observed if it
+        ends holding the node: the stage's count is nodes acquired, and a
+        renewal, a Conflict's re-read or a wait for another holder's
+        lease to expire is none."""
+        with self._mut:
+            holding = name in self._holding
+        if holding:
+            next_try, first = self._renew_or_acquire(name)
+        else:
+            sp = _telemetry.stage("NodeBringup", "lease_acquire")
+            sp.counted = False  # until it has taken the node
+            with sp:
+                next_try, sp.counted = self._renew_or_acquire(name)
+            first = sp.counted
+        if first and self._on_node_managed is not None:
+            self._on_node_managed(name)
+        return next_try
+
+    def _renew_or_acquire(self, name: str) -> Tuple[float, bool]:
+        """One read and write of the node's Lease: seconds until the next
+        try, and whether this write took a node not held before."""
         now = self._now()
         try:
             lease = self.store.get("Lease", name, namespace=NAMESPACE_NODE_LEASE)
@@ -274,7 +296,7 @@ class NodeLeaseController:
                     with self._mut:
                         self._holding.discard(name)
                     expire = renew + datetime.timedelta(seconds=dur)
-                    return max((expire - now).total_seconds(), 0.1)
+                    return max((expire - now).total_seconds(), 0.1), False
             else:
                 renew = _parse_micro(spec.get("renewTime") or "")
                 if renew is not None:
@@ -291,7 +313,7 @@ class NodeLeaseController:
             try:
                 self.store.update(lease)
             except (Conflict, NotFound):
-                return 0.1  # re-read immediately
+                return 0.1, False  # re-read immediately
         else:
             lease = {
                 "apiVersion": "coordination.k8s.io/v1",
@@ -309,7 +331,7 @@ class NodeLeaseController:
             try:
                 self.store.create(lease)
             except Conflict:
-                return 0.1
+                return 0.1, False
 
         first = False
         with self._mut:
@@ -317,11 +339,8 @@ class NodeLeaseController:
                 self._holding.add(name)
                 first = True
         self.renew_count += 1
-        if first and self._on_node_managed is not None:
-            self._on_node_managed(name)
-
         # renewInterval + one-sided jitter in [iv, iv*(1+0.04)]
-        return self.renew_interval * (1.0 + self.renew_jitter * self.rng.random())
+        return self.renew_interval * (1.0 + self.renew_jitter * self.rng.random()), first
 
     # ------------------------------------------------------------ lane renewals
 

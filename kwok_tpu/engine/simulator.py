@@ -43,6 +43,7 @@ from kwok_tpu.ops.tick import (
     scatter_rows,
     tick,
 )
+from kwok_tpu.utils import accel as _accel
 from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.utils.patch import apply_patch
 
@@ -71,6 +72,45 @@ _TICKS = _telemetry.counter(
     help="device sub-ticks dispatched",
     labelnames=("kind",),
 )
+#: the seconds of those first uses, one observation each: what the
+#: ``compile`` stage times, under the program and the cause it has and
+#: what the backend did for it
+_COMPILE_STALL = _telemetry.histogram(
+    "kwok_compile_stall_seconds",
+    help="seconds a tick thread waited in the first use of a device program "
+    "shape (program, cause: as kwok_device_new_shapes_total; outcome: cold = "
+    "the persistent cache lacked a program compiled under it, fetched = it "
+    "had every one, none = no program was asked of the backend)",
+    buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0),
+    labelnames=("kind", "program", "cause", "outcome"),
+    max_children=256,
+)
+
+
+class _Compile(_telemetry.stage):
+    """The ``compile`` stage of one first use: as it ends, its ``elapsed``
+    is also observed under what the first use was and what the backend
+    did for it on this thread (``utils/accel.thread_compiles``: the Node
+    and the Pod player compile at once during set-up)."""
+
+    __slots__ = ("program", "cause", "_before")
+
+    def __init__(self, kind: str, program: str, cause: str):
+        super().__init__(kind, "compile", overlay=True)
+        self.program = program
+        self.cause = cause
+
+    def __enter__(self) -> "_Compile":
+        self._before = _accel.thread_compiles()
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        programs, hits = _accel.thread_compiles()
+        programs -= self._before[0]
+        hits -= self._before[1]
+        outcome = "cold" if programs > hits else "fetched" if programs else "none"
+        _COMPILE_STALL.observe(self.elapsed, self.kind, self.program, self.cause, outcome)
 
 
 class ShapeLog:
@@ -86,10 +126,15 @@ class ShapeLog:
     def __init__(self, kind: str):
         self.kind = kind
         self._last: Dict[str, tuple] = {}
+        # the sums a set-up metric reads stand at 0 from the start: a
+        # process that never compiled for such a cause has a 0, not nothing
+        for outcome in ("cold", "fetched"):
+            _COMPILE_STALL.add_running(0.0, kind, "run_ticks_collect", "signatures", outcome)
 
     def first_use(self, program: str, key: tuple, parts: Tuple[str, ...]):
         """The context to call ``program`` in: nothing for a key used
-        before, else a ``compile`` stage, counted under the cause found."""
+        before, else a ``compile`` stage, counted and timed under the
+        cause found."""
         last, self._last[program] = self._last.get(program), key
         seen = self._seen.setdefault(program, set())
         if key in seen:
@@ -102,7 +147,7 @@ class ShapeLog:
                     cause = part
                     break
         _NEW_SHAPES.inc(1, self.kind, program, cause)
-        return _telemetry.stage(self.kind, "compile", overlay=True)
+        return _Compile(self.kind, program, cause)
 
 
 _USED_BEFORE = contextlib.nullcontext()

@@ -28,7 +28,7 @@ from __future__ import annotations
 
 import os
 import threading
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 __all__ = [
     "DEFAULT_CACHE_DIR",
@@ -38,6 +38,7 @@ __all__ = [
     "enable_compile_cache",
     "pinned_platforms",
     "require_accelerator",
+    "thread_compiles",
 ]
 
 #: the in-checkout compile cache used when JAX_COMPILATION_CACHE_DIR is
@@ -57,6 +58,9 @@ _CACHE_MISS = "/jax/compilation_cache/cache_misses"
 
 _mut = threading.Lock()
 _listening = False
+#: the calling thread's own programs asked of the backend and, of them,
+#: persistent-cache hits: JAX calls a listener on the thread that compiles
+_mine = threading.local()
 _stats = {
     "compilations": 0,
     "compile_seconds": 0.0,
@@ -72,6 +76,7 @@ class NoAccelerator(RuntimeError):
 
 def _on_event(event: str, **_kw) -> None:
     if event == _CACHE_HIT:
+        _mine.hits = getattr(_mine, "hits", 0) + 1
         with _mut:
             _stats["cache_hits"] += 1
     elif event == _CACHE_MISS:
@@ -83,6 +88,7 @@ def _on_duration(event: str, duration_secs: float, **_kw) -> None:
     if event == _BACKEND_COMPILE:
         # one per program JAX asks the backend for: a cold compile or a
         # persistent-cache retrieval (the duration covers either)
+        _mine.programs = getattr(_mine, "programs", 0) + 1
         with _mut:
             _stats["compilations"] += 1
             _stats["compile_seconds"] += duration_secs
@@ -123,6 +129,15 @@ def compile_stats() -> Dict[str, float]:
     out["compile_seconds"] = round(out["compile_seconds"], 3)
     out["trace_seconds"] = round(out["trace_seconds"], 3)
     return out
+
+
+def thread_compiles() -> Tuple[int, int]:
+    """Programs the calling thread has asked of the backend since
+    :func:`enable_compile_cache`, and how many of them the persistent
+    cache had.  The difference of two readings round a call tells a cold
+    compile (programs grew by more than hits) from a fetch, whatever
+    another thread compiled meanwhile."""
+    return getattr(_mine, "programs", 0), getattr(_mine, "hits", 0)
 
 
 def device_info() -> Dict[str, object]:

@@ -22,6 +22,9 @@ in-tree substrate every control-plane hot path observes into:
 - :func:`stage` — the one span helper of the device tick threads: a
   ``jax.profiler.TraceAnnotation`` on the profiler's clock plus an
   observation into ``kwok_tick_stage_seconds``;
+- :class:`Milestones` — seconds from the process's start to what
+  happens once in it (device taken, apiserver ready, leading,
+  reconciling, a kind's first tick): what set-up is made of;
 - :class:`FlightRecorder` — a bounded in-memory ring of recent
   per-tick stage breakdowns and slow-request samples (each carrying
   its trace id as an exemplar), served at ``/debug/flightrecorder`` so
@@ -67,12 +70,14 @@ __all__ = [
     "FlightRecorder",
     "HistogramFamily",
     "JourneyRecorder",
+    "Milestones",
     "Telemetry",
     "enabled",
     "flight_recorder",
     "histogram",
     "journey",
     "counter",
+    "milestones",
     "registry",
     "set_enabled",
     "stage",
@@ -634,6 +639,65 @@ class JourneyRecorder:
             self.dropped_hops = 0
 
 
+# --------------------------------------------------------------- milestones
+
+
+def _process_age() -> Optional[float]:
+    """Seconds since the kernel started this process (``starttime`` of
+    ``/proc/self/stat`` against the boot clock), or None where that
+    cannot be read."""
+    try:
+        with open("/proc/self/stat", encoding="ascii") as f:
+            stat = f.read()
+        # the fields after "(comm)": starttime is the 22nd of the line
+        ticks = int(stat[stat.rindex(")") + 2:].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return age if age >= 0.0 else None
+
+
+class Milestones:
+    """Seconds from the process's start to things that happen once.
+
+    The origin is the process's start as the kernel has it, so the
+    interpreter's start and the imports are inside the first milestone;
+    where ``/proc/self/stat`` cannot be read it is the first
+    :meth:`mark` (``origin`` says which).  A milestone is set by the
+    first ``mark`` of its name and labels and never moves: a controller
+    that loses the election and leads again keeps its first readings."""
+
+    def __init__(self):
+        self._mut = make_lock("utils.telemetry.Milestones._mut")
+        self._t0: Optional[float] = None
+        self.origin = ""
+        #: (name, ((label, value), ...)) -> seconds, in the order set
+        self._at: "OrderedDict[Tuple[str, tuple], float]" = OrderedDict()
+
+    def mark(self, name: str, **labels: str) -> None:
+        key = (name, tuple(sorted(labels.items())))
+        now = time.monotonic()
+        with self._mut:
+            if key in self._at:
+                return
+            if self._t0 is None:
+                age = _process_age()
+                self.origin = "the kernel's start of the process" if age is not None \
+                    else "the first milestone"
+                self._t0 = now - (age or 0.0)
+            self._at[key] = now - self._t0
+
+    def snapshot(self) -> List[Tuple[str, Dict[str, str], float]]:
+        """(name, labels, seconds) in the order the milestones were set."""
+        with self._mut:
+            return [(n, dict(ls), v) for (n, ls), v in self._at.items()]
+
+    def reset(self) -> None:
+        with self._mut:
+            self._t0 = None
+            self._at.clear()
+
+
 # ------------------------------------------------------------------ registry
 
 
@@ -646,6 +710,7 @@ class Telemetry:
         self._families: Dict[str, HistogramFamily] = {}
         self.recorder = FlightRecorder()
         self.journey = JourneyRecorder()
+        self.milestones = Milestones()
 
     def histogram(
         self,
@@ -728,6 +793,7 @@ class Telemetry:
             fam.clear()
         self.recorder.reset()
         self.journey.reset()
+        self.milestones.reset()
 
 
 class _State:
@@ -784,6 +850,10 @@ def flight_recorder() -> FlightRecorder:
 
 def journey() -> JourneyRecorder:
     return _REGISTRY.journey
+
+
+def milestones() -> Milestones:
+    return _REGISTRY.milestones
 
 
 def set_enabled(on: bool) -> bool:
@@ -859,11 +929,16 @@ class stage:
     :class:`_SessionKeeper` keeps it in the trace of a session that it
     outlasts, :func:`account_open_stages` in the sums a scrape reads.
 
+    A block that turns out not to have been the thing the stage names
+    (a lease ``_sync`` that did not take the node) sets ``sp.counted =
+    False`` before it ends: the span stays in the trace, the family
+    observes nothing.
+
     Wrap batched operations only, never a row; ``kind`` and ``name``
     come from bounded sets (a resource kind, a literal)."""
 
     __slots__ = (
-        "kind", "name", "overlay", "elapsed", "nested",
+        "kind", "name", "overlay", "elapsed", "nested", "counted",
         "_t0", "_ann", "_parent", "_slice", "_in_sum",
     )
 
@@ -874,6 +949,8 @@ class stage:
         self.elapsed = 0.0
         #: seconds of the stages nested directly in this one, overlays apart
         self.nested = 0.0
+        #: whether the family observes this stage as it ends
+        self.counted = True
         self._ann = None
         #: the keeper's running slice of this stage, in a session
         self._slice = None
@@ -921,10 +998,15 @@ class stage:
                 parent.nested += self.elapsed
         if running is not None:
             running.__exit__(None, None, None)
-        if _STATE.enabled:
+        if not _STATE.enabled:
+            return
+        if self.counted:
             tick_stage_family().observe(
                 self.elapsed - self.nested, self.kind, self.name, in_sum=self._in_sum
             )
+        elif self._in_sum:
+            # a scrape put its time so far into the sum: take it back
+            tick_stage_family().add_running(-self._in_sum, self.kind, self.name)
 
 
 def _open_stages():
