@@ -150,6 +150,22 @@ _H_OBJECT_JSON_REUSED = _telemetry.histogram(
 )
 
 
+#: the scheduler's work as the store sees it: commits that set
+#: ``spec.nodeName`` on a pod that was created without one (on either
+#: wire, by ``patch``, ``update``, ``bulk``, ``transact`` or the
+#: ``binding`` subresource), and seconds from that pod's create commit
+#: to its bind commit on the monotonic clock
+_C_POD_BINDS = _telemetry.counter(
+    "kwok_pod_binds_total",
+    help="commits that bound a pod created without spec.nodeName",
+)
+_H_CREATE_TO_BIND = _telemetry.histogram(
+    "kwok_pod_create_to_bind_seconds",
+    help="seconds from a pod's create commit to the commit that bound it",
+    buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 60.0),
+)
+
+
 def count_object_json(kind: str, encoded: int, reused: int) -> None:
     """``encoded`` and ``reused`` more uses of the JSON of ``kind``'s
     committed objects."""
@@ -793,6 +809,10 @@ class _TypeState:
     #: field-path -> value -> keys (the informer-cache index analog:
     #: client-go indexes pods by spec.nodeName the same way)
     indexes: Dict[str, Dict[str, set]] = field(default_factory=dict)
+    #: key -> monotonic instant of the create commit of an object that
+    #: was committed without ``spec.nodeName`` and is not yet bound or
+    #: deleted; None for a kind whose binds are not timed (Pod's is)
+    unbound_since: Optional[Dict[Tuple[str, str], float]] = None
 
 
 def list_page_from(
@@ -1045,6 +1065,9 @@ class ResourceStore:
         # the hottest field-selector in the system: the kubelet server
         # and pod controller list pods by node on every scrape/sync
         self.register_index("Pod", "spec.nodeName")
+        self._state("Pod").unbound_since = {}
+        _C_POD_BINDS.inc(0)
+        _H_CREATE_TO_BIND.add_running(0.0)
 
     # -------------------------------------------------------------- durability
 
@@ -1389,6 +1412,8 @@ class ResourceStore:
             # (watch_line), and a store nobody reads encodes nothing
             line = _line_round(etype, obj_json, rv)
             self._count_json(st.rtype.kind, 1, 1)
+        if st.unbound_since is not None:
+            self._note_bind(st, etype, obj)
         ev = WatchEvent(type=etype, object=obj, rv=rv, line=line)
         # what bulk(encoded=True) and transact answer their op from
         self._tel_local.emitted = (ev, st.rtype.kind)
@@ -1402,6 +1427,22 @@ class ResourceStore:
                 self._note_commit(rv, st=st, etype=etype, obj=obj)
         if st.watchers:
             self._fan_out(st, (ev,))
+
+    def _note_bind(self, st: _TypeState, etype: str, obj: dict) -> None:
+        """A committed event of a kind whose binds are timed: a create
+        without ``spec.nodeName`` keeps its instant, the first commit
+        that sets it (a bind, by any verb) counts and observes the time
+        since, a delete forgets it."""
+        key = self._key(st, obj)
+        if etype == DELETED:
+            st.unbound_since.pop(key, None)
+        elif (obj.get("spec") or {}).get("nodeName"):
+            t = st.unbound_since.pop(key, None)
+            if t is not None:
+                _C_POD_BINDS.inc(1)
+                _H_CREATE_TO_BIND.observe(time.monotonic() - t)
+        elif etype == ADDED:
+            st.unbound_since[key] = time.monotonic()
 
     def _fan_out(
         self,
@@ -1575,10 +1616,14 @@ class ResourceStore:
             return copy_json(obj)
 
     @staticmethod
-    def _index_candidates(st: _TypeState, field_selector: Selector):
-        """Sorted key subset from an index when the field selector is a
-        single equality on an indexed path; None → full scan."""
-        if not st.indexes or field_selector is None:
+    def _index_candidates(
+        st: _TypeState, field_selector: Selector, namespace: Optional[str]
+    ):
+        """Sorted key subset when the field selector is a single
+        equality on an indexed path, or on ``metadata.name`` where that
+        names one key (the kind is cluster-scoped, or one namespace is
+        asked: a node's own re-feed); None → full scan."""
+        if field_selector is None:
             return None
         reqs = _parse_selector(field_selector)
         if len(reqs) != 1 or reqs[0][1] != "=":
@@ -1588,6 +1633,10 @@ class ResourceStore:
             # match_field_selector treats missing fields as "" — unset
             # values are not indexed, so serve that query by full scan
             return None
+        if path == "metadata.name":
+            if not st.rtype.namespaced:
+                return [("", value)]
+            return None if namespace is None else [(namespace, value)]
         idx = st.indexes.get(path)
         if idx is None:
             return None
@@ -1609,7 +1658,7 @@ class ResourceStore:
         out = copy_json if copy else (lambda o: o)
         with self._mut:
             st = self._state(kind)
-            cand = self._index_candidates(st, field_selector)
+            cand = self._index_candidates(st, field_selector, namespace)
             if cand is not None:
                 items = []
                 for key in cand:
@@ -2359,6 +2408,8 @@ class ResourceStore:
                 meta["resourceVersion"] = str(rv)
                 del objects[key]
                 self._index_update(st, key, cur, None)
+                if st.unbound_since:
+                    st.unbound_since.pop(key, None)
                 evs.append(WatchEvent(type=DELETED, object=gone, rv=rv))
                 committed.append([ns, name, rv])
                 out.append(rv)

@@ -262,6 +262,13 @@ class Scheduler:
     def _bind_inner(self, pod: dict, span) -> None:
         meta = pod.get("metadata") or {}
         name, ns = meta.get("name") or "", meta.get("namespace") or "default"
+        uid = meta.get("uid")
+        with self._mut:
+            if uid and uid in self._pod_usage:
+                # bound since this event was queued (by the retry pass,
+                # or an earlier event of the same pod): a bound pod is
+                # never bound again, as kube's binding answers Conflict
+                return
         target = self._pick_node(pod)
         if span is not None:
             span.set("node", target or "")
