@@ -164,6 +164,14 @@ _H_CREATE_TO_BIND = _telemetry.histogram(
     help="seconds from a pod's create commit to the commit that bound it",
     buckets=(0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 60.0),
 )
+#: pods one request bound, one observation a request that bound any: a
+#: ``bulk``'s binds or a ``transact``'s, 1 for a ``patch``, ``update`` or
+#: ``binding``; ``_sum`` is ``kwok_pod_binds_total``
+_H_BINDS_PER_REQUEST = _telemetry.histogram(
+    "kwok_pod_binds_per_request",
+    help="pods bound by one request that bound any",
+    buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024),
+)
 
 
 def count_object_json(kind: str, encoded: int, reused: int) -> None:
@@ -1441,6 +1449,15 @@ class ResourceStore:
             if t is not None:
                 _C_POD_BINDS.inc(1)
                 _H_CREATE_TO_BIND.observe(time.monotonic() - t)
+                # a bulk or a transaction observes its binds once, as it
+                # ends; any other commit is a request of its own
+                tl = self._tel_local
+                if getattr(tl, "in_batch", False):
+                    tl.batch_binds += 1
+                elif getattr(tl, "txn_binds", None) is not None:
+                    tl.txn_binds += 1
+                else:
+                    _H_BINDS_PER_REQUEST.observe(1)
         elif etype == ADDED:
             st.unbound_since[key] = time.monotonic()
 
@@ -2547,10 +2564,13 @@ class ResourceStore:
         tl.batch_rv = None
         tl.filter_s = {}
         tl.json_uses = {}
+        tl.batch_binds = 0
         try:
             self._bulk_ops(ops, results, copy_results, encoded)
         finally:
             tl.in_batch = False
+            if tl.batch_binds:
+                _H_BINDS_PER_REQUEST.observe(tl.batch_binds)
             for kind, seconds in tl.filter_s.items():
                 _H_WATCH_FILTER.observe(seconds, kind)
             for kind, (fresh, reused) in tl.json_uses.items():
@@ -2684,7 +2704,18 @@ class ResourceStore:
         bytes, from what the commit left on the event, as
         :meth:`bulk`'s.
         """
-        copy = copy_results and not encoded
+        # its binds are one request's: counted apart from ``in_batch``,
+        # which would defer a delivery-lag note nothing here flushes
+        tl = self._tel_local
+        tl.txn_binds = 0
+        try:
+            return self._transact(ops, as_user, copy_results and not encoded, encoded)
+        finally:
+            binds, tl.txn_binds = tl.txn_binds, None
+            if binds:
+                _H_BINDS_PER_REQUEST.observe(binds)
+
+    def _transact(self, ops, as_user, copy, encoded) -> List[Optional[dict]]:
         emitted: List[Any] = []
         tl = self._tel_local
         with self._mut:
