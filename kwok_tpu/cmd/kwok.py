@@ -204,9 +204,17 @@ def start_config_watcher(client, srv, done: threading.Event, base_configs=None) 
     threading.Thread(target=loop, daemon=True).start()
 
 
+_DEVICE_ROWS_HELP = (
+    "Rows of a device player's SoA by kind (Pod: deviceCapacity; Node: "
+    "at most 4096 to start; doubled as rows join past them), and under "
+    "kind=Lease the slots of the device lease lane."
+)
+
+
 def _controller_self_metrics(get_ctr, elector=None, device=None):
     """Self-metrics updater: the process's start-up milestones, stage
-    transitions/patches per kind (host and device paths), the Node
+    transitions/patches per kind (host and device paths), the rows each
+    device player and the lease lane hold, the Node
     player's Ready wave, lease heartbeat health (SURVEY §7 step 5), and
     this replica's leader-election state.  (The tick loop's lag is a
     histogram of ``utils/telemetry``'s registry, observed where it
@@ -348,6 +356,12 @@ def _controller_self_metrics(get_ctr, elector=None, device=None):
                     p.swallowed_errors,
                     kind=kind,
                 )
+                gauge(
+                    "kwok_device_rows",
+                    _DEVICE_ROWS_HELP,
+                    p.sim.capacity,
+                    kind=kind,
+                )
             counter(
                 "kwok_stage_transitions_total",
                 "Stage transitions played.",
@@ -369,6 +383,8 @@ def _controller_self_metrics(get_ctr, elector=None, device=None):
         nl = ctr.node_leases
         if nl is not None:
             lane = getattr(nl, "_lane", None)
+            if lane is not None:
+                gauge("kwok_device_rows", _DEVICE_ROWS_HELP, lane.capacity, kind="Lease")
             counter(
                 "kwok_lease_renewals_total",
                 "Node lease renewals written.",

@@ -36,6 +36,12 @@ from kwok_tpu.utils.queue import Queue
 
 _LOG = get_logger("controller")
 
+#: rows the Node player's SoA and the lease lane's slots start at, when
+#: ``deviceCapacity`` is larger: both double to fit the nodes as they
+#: join, in set-up, so a cluster of 1M pod rows ticks as many Node rows
+#: and scans as many lease slots as it has nodes, to the next doubling
+NODE_ROWS = 4096
+
 
 def _match_annotations(obj: dict, selector: str) -> bool:
     if not selector:
@@ -334,7 +340,11 @@ class Controller:
                 self.store,
                 kind,
                 stages,
-                capacity=self.conf.device_capacity,
+                capacity=(
+                    min(self.conf.device_capacity, NODE_ROWS)
+                    if kind == "Node"
+                    else self.conf.device_capacity
+                ),
                 tick_ms=self.conf.device_tick_ms,
                 clock=self.clock,
                 recorder=self.recorder,
@@ -366,7 +376,7 @@ class Controller:
 
             lane = DeviceLeaseLane(
                 self.node_leases,
-                capacity=self.conf.device_capacity,
+                capacity=player.sim.capacity,
                 seed=self.rng.randrange(2**31),
             )
             self.node_leases.attach_device_lane(lane)

@@ -109,6 +109,12 @@ class DeviceLeaseLane:
         with self._mut:
             return len(self._slots)
 
+    @property
+    def capacity(self) -> int:
+        """Slots the lane holds, held or free (grown by doubling)."""
+        with self._mut:
+            return len(self._fire_np)
+
     # ------------------------------------------------------------------- tick
 
     def tick(self, now_ms: int) -> int:

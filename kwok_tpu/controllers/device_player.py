@@ -32,7 +32,12 @@ from kwok_tpu.cluster.informer import Informer, InformerEvent, WatchOptions
 from kwok_tpu.cluster.store import DELETED, EventRecorder, NotFound, ResourceStore
 from kwok_tpu.engine.render_plan import RenderPlan, compile_plan
 from kwok_tpu.engine.render_plan import build as _plan_build
-from kwok_tpu.engine.simulator import DEFAULT_EPOCH, DeviceSimulator, Transition
+from kwok_tpu.engine.simulator import (
+    COLLECT_TICKS,
+    DEFAULT_EPOCH,
+    DeviceSimulator,
+    Transition,
+)
 from kwok_tpu.native.fastdrain import load as _load_fastdrain
 from kwok_tpu.utils import telemetry as _telemetry
 from kwok_tpu.utils.clock import Clock, RealClock
@@ -52,12 +57,13 @@ _LOG = get_logger("device-player")
 #: (utils/telemetry.stage): a TraceAnnotation ``kwok/<kind>/<name>`` on
 #: the profiler's clock and ``kwok_tick_stage_seconds{kind,stage}``.
 #: Outermost: ingest, device_tick, host_drain, post_tick, pace_wait;
-#: host_build and store_bulk (the status batch), delete_commit (the
-#: delete batch), slow_build and slow_commit (``_drain_slow``: the
-#: per-row Python around its bulk, and the bulk) and event_post (the
-#: drain's Events handed to the recorder in one request, last) nest in
-#: host_drain, which reports self time; compile (engine/simulator.py)
-#: overlays the stage it stalls.
+#: fired_scan (the pass over the dense fired-stage output that finds the
+#: rows that fired, one a drain), host_build and store_bulk (the status
+#: batch), delete_commit (the delete batch), slow_build and slow_commit
+#: (``_drain_slow``: the per-row Python around its bulk, and the bulk)
+#: and event_post (the drain's Events handed to the recorder in one
+#: request, last) nest in host_drain, which reports self time; compile
+#: (engine/simulator.py) overlays the stage it stalls.
 _stage = _telemetry.stage
 
 #: rows in one ``store.apply_status_batch`` or ``apply_delete_batch``
@@ -483,8 +489,9 @@ class DeviceStagePlayer:
         self._informer.sync(opt, self.events)
 
     #: catch-up / saturation macro-tick width (sub-ticks per device
-    #: dispatch); bounds how much virtual time one dispatch covers
-    macro_ticks = 8
+    #: dispatch); bounds how much virtual time one dispatch covers, and
+    #: every count up to it runs one program
+    macro_ticks = COLLECT_TICKS
 
     def _tick_loop(self) -> None:
         dt_s = self.tick_ms / 1000.0
@@ -688,6 +695,11 @@ class DeviceStagePlayer:
         # complete it (stop's contract: the in-flight batch is not
         # stranded), while a huge drain aborts within ~a second
         self._drain_t0 = t_start
+        with _stage(self.kind, "fired_scan"):
+            # every sub-tick's fired rows in one pass over the dense
+            # [K, N] output: row-major, so by sub-tick, rows ascending
+            ks, fired_rows = np.nonzero(stages_np >= 0)
+            bounds = np.searchsorted(ks, np.arange(stages_np.shape[0] + 1))
         for k in range(stages_np.shape[0]):
             if self._done.is_set() and time.perf_counter() - t_start > 1.0:
                 # shutdown mid-macro-tick: small flushes complete, but a
@@ -698,7 +710,7 @@ class DeviceStagePlayer:
                 # resume)
                 break
             st = stages_np[k]
-            rows = np.nonzero(st >= 0)[0]
+            rows = fired_rows[bounds[k] : bounds[k + 1]]
             if rows.size:
                 # a row the host filled after this output was dispatched
                 # fired for what it held before: an overlapped macro-tick
@@ -768,14 +780,14 @@ class DeviceStagePlayer:
         with _stage(self.kind, "device_tick") as sp:
             stages_dev, t0_ms = self.sim.tick_many_async(dt, n_ticks)
             self._dispatches += 1
-            self._inflight = (stages_dev, t0_ms, dt, self._dispatches)
+            self._inflight = (stages_dev, n_ticks, t0_ms, dt, self._dispatches)
             # start the device->host copy NOW so it overlaps the drain
             # below: the next call's device_get finds the bytes on the host
             # instead of paying a blocking read
             stages_dev.copy_to_host_async()
             if prev is not None:
-                p_stages, p_t0, p_dt, p_dispatch = prev
-                stages_np = np.asarray(jax.device_get(p_stages))
+                p_stages, p_n, p_t0, p_dt, p_dispatch = prev
+                stages_np = np.asarray(jax.device_get(p_stages))[:p_n]
         self._note_dispatch(dt, n_ticks)
         self.t_device += sp.elapsed
         fired = 0
@@ -792,9 +804,9 @@ class DeviceStagePlayer:
             return 0
         import jax
 
-        stages_dev, t0_ms, dt, dispatch = prev
+        stages_dev, n_ticks, t0_ms, dt, dispatch = prev
         with _stage(self.kind, "device_tick") as sp:
-            stages_np = np.asarray(jax.device_get(stages_dev))
+            stages_np = np.asarray(jax.device_get(stages_dev))[:n_ticks]
         self.t_device += sp.elapsed
         return self._drain_stages(stages_np, t0_ms, dt, dispatch)
 

@@ -38,8 +38,8 @@ from kwok_tpu.engine.lifecycle import to_json_standard
 from kwok_tpu.ops.tick import (
     SoA,
     TickParams,
+    collect_program,
     params_from_compiled,
-    run_ticks_collect,
     scatter_rows,
     tick,
 )
@@ -56,6 +56,12 @@ DEFAULT_EPOCH = datetime.datetime(2026, 1, 1, tzinfo=datetime.timezone.utc)
 #: runs never approach the edge.
 REBASE_AT_MS = 2**30
 
+#: sub-ticks one macro-tick program holds: a dispatch of at most so many
+#: (the tick loop's ``macro_ticks``) runs that one program with its count
+#: as an argument, so the count, which follows the loop's timing, never
+#: compiles; a longer dispatch runs a program of its own length
+COLLECT_TICKS = 8
+
 
 #: first uses of a device program's shape key, by what made the shape
 #: new: every one is a trace + lower + compile (or a persistent-cache
@@ -63,8 +69,8 @@ REBASE_AT_MS = 2**30
 _NEW_SHAPES = _telemetry.counter(
     "kwok_device_new_shapes_total",
     help="first uses of a device program shape (program: run_ticks_collect/"
-    "scatter_rows/lease_tick/upload; cause: num_ticks/scatter_width/"
-    "capacity/signatures/first)",
+    "run_node_ticks_collect/scatter_rows/lease_tick/upload; cause: "
+    "num_ticks/scatter_width/capacity/signatures/first)",
     labelnames=("kind", "program", "cause"),
 )
 _TICKS = _telemetry.counter(
@@ -128,8 +134,9 @@ class ShapeLog:
         self._last: Dict[str, tuple] = {}
         # the sums a set-up metric reads stand at 0 from the start: a
         # process that never compiled for such a cause has a 0, not nothing
+        program = collect_program(kind)[0]
         for outcome in ("cold", "fetched"):
-            _COMPILE_STALL.add_running(0.0, kind, "run_ticks_collect", "signatures", outcome)
+            _COMPILE_STALL.add_running(0.0, kind, program, "signatures", outcome)
 
     def first_use(self, program: str, key: tuple, parts: Tuple[str, ...]):
         """The context to call ``program`` in: nothing for a key used
@@ -638,31 +645,38 @@ class DeviceSimulator:
                 self._rematch_pending = False
             return stages_np, t0_ms
         stages, t0_ms = self.tick_many_async(dt_ms, n_ticks)
-        return np.asarray(jax.device_get(stages)), t0_ms
+        return np.asarray(jax.device_get(stages))[:n_ticks], t0_ms
 
     def num_stages_over_int8(self) -> bool:
         return len(self.cset.compiled) > 126
 
     def tick_many_async(self, dt_ms: int, n_ticks: int):
-        """Like tick_many, but returns the [K, N] fired-stage DEVICE
-        array without blocking — the caller overlaps the device compute
-        with host work (drain of the previous macro-tick) and fetches
-        via jax.device_get when ready.  Single-device path only (the
-        caller falls back to tick_many for mesh / >int8 stage sets);
-        tick_many's single-device branch is this + the blocking get."""
+        """Like tick_many, but returns the fired-stage DEVICE array
+        without blocking — the caller overlaps the device compute with
+        host work (drain of the previous macro-tick) and fetches via
+        jax.device_get when ready.  The array holds
+        ``max(n_ticks, COLLECT_TICKS)`` rows, those past ``n_ticks`` IDLE:
+        the caller keeps the first ``n_ticks`` of the fetch.  Single-device
+        path only (the caller falls back to tick_many for mesh / >int8
+        stage sets); tick_many's single-device branch is this + the
+        blocking get."""
         assert self.mesh is None and not self.num_stages_over_int8()
         if self.now_ms >= REBASE_AT_MS:
             self._rebase()
         t0_ms = self._now_host
         params, soa = self.to_device()
-        # what the program is specialised on: the static arguments, the
+        # what the program is specialised on: the static arguments (a
+        # count up to COLLECT_TICKS is an argument of one program), the
         # rows, and the stage tensors' shapes (eff_mode is [SIG, S, C],
-        # ov_w [OVC, S]: a new signature or override class grows them)
-        key = (n_ticks, self.capacity, params.eff_mode.shape + params.ov_w.shape, dt_ms)
+        # ov_w [OVC, S]: a new override class, or a signature whose
+        # effects differ from the others', grows them)
+        width = max(n_ticks, COLLECT_TICKS)
+        key = (width, self.capacity, params.eff_mode.shape + params.ov_w.shape, dt_ms)
+        program, run = collect_program(self.kind)
         with self._shapes.first_use(
-            "run_ticks_collect", key, ("num_ticks", "capacity", "signatures")
+            program, key, ("num_ticks", "capacity", "signatures")
         ):
-            new_soa, stages = run_ticks_collect(params, soa, dt_ms, n_ticks)
+            new_soa, stages = run(params, soa, n_ticks, dt_ms=dt_ms, num_ticks=width)
         _TICKS.inc(n_ticks, self.kind)
         self._soa = new_soa
         self._now_host = t0_ms + dt_ms * n_ticks

@@ -20,10 +20,11 @@ pod_controller.go:196-360 and pkg/utils/queue/weight_delaying_queue.go)
 
 Everything is int32 (virtual milliseconds) and bfloat16/float32-free on
 purpose: the FSM is integer-exact, which keeps device/host parity
-bit-stable. All shapes are static; control flow is mask arithmetic, so
-XLA fuses the whole tick into a handful of elementwise kernels plus two
-small gathers — MXU is not the bottleneck here, HBM bandwidth is, and
-the layout is one contiguous [N, C] features array.
+bit-stable. All shapes are static; control flow is mask arithmetic, and
+the stage tables are read by selects, not gathers, wherever they are
+small (``_lookup``), so XLA fuses the whole tick into a handful of
+elementwise kernels — MXU is not the bottleneck here, HBM bandwidth is,
+and the layout is one contiguous [N, C] features array.
 """
 
 from __future__ import annotations
@@ -83,6 +84,11 @@ class TickOut(NamedTuple):
 
 def params_from_compiled(cset: CompiledStageSet) -> TickParams:
     eff_mode, eff_val = cset.effect_tables()
+    if (eff_mode == eff_mode[:1]).all() and (eff_val == eff_val[:1]).all():
+        # every signature lowers every stage alike (no effect reads the
+        # object): one row serves them all, the tick reads it by stage
+        # alone, and a new signature changes no shape the tick compiles for
+        eff_mode, eff_val = eff_mode[:1], eff_val[:1]
     ov_w, ov_d, ov_j = cset.override_tables()
     return TickParams(
         cond_col=jnp.asarray(cset.cond_col),
@@ -104,37 +110,68 @@ def params_from_compiled(cset: CompiledStageSet) -> TickParams:
     )
 
 
-def match_stages(params: TickParams, features: jax.Array) -> jax.Array:
-    """[N, S] bool: selector match per row per stage (Lifecycle.match)."""
-    S = params.cond_col.shape[0]
+#: a table of at most this many entries is read by one select an entry,
+#: elementwise and fused with the rest of the tick, and not by a gather,
+#: which the TPU runs row by row (a 1,048,576-row SoA spent most of its
+#: tick in the effect gathers)
+_SELECT_MAX = 32
+
+
+def _lookup(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` along axis 0 for in-range indices ``idx`` [N]."""
+    n = table.shape[0]
+    if n > _SELECT_MAX:
+        return table[idx]
+    tail = (1,) * (table.ndim - 1)
+    out = jnp.broadcast_to(table[0], idx.shape + table.shape[1:])
+    for i in range(1, n):
+        out = jnp.where((idx == i).reshape(idx.shape + tail), table[i], out)
+    return out
+
+
+def match_stages(params: TickParams, features: jax.Array) -> list:
+    """S [N] bool arrays: selector match per stage per row (Lifecycle.match)."""
+    S, K = params.cond_col.shape
+    C = features.shape[1]
+    cols = [features[:, c] for c in range(C)]
     outs = []
-    for s in range(S):  # S is small & static: unrolled, fuses to elementwise
+    for s in range(S):  # S, K and C are small & static: unrolled, elementwise
         m = jnp.ones(features.shape[0], dtype=bool)
-        for k in range(params.cond_col.shape[1]):
+        for k in range(K):
             col = params.cond_col[s, k]
-            test = (features[:, col] & params.cond_mask[s, k]) != 0
+            picked = cols[0]
+            for c in range(1, C):
+                picked = jnp.where(col == c, cols[c], picked)
+            test = (picked & params.cond_mask[s, k]) != 0
             test = jnp.where(params.cond_neg[s, k], ~test, test)
             m = m & jnp.where(params.cond_valid[s, k], test, True)
         outs.append(m)
-    return jnp.stack(outs, axis=1)
+    return outs
 
 
 def _weighted_choice(
-    match: jax.Array, weights: jax.Array, u: jax.Array
+    match: list, weights: list, u: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """Reference fallback ladder, vectorized (no weight-error rungs on
     device): weighted among matched with weight>0 when total>0, else
-    uniform among matched. Returns (stage_idx, any_match)."""
-    wm = jnp.where(match & (weights > 0), weights, 0)
-    total = wm.sum(axis=1)
-    probs = jnp.where((total > 0)[:, None], wm, match.astype(jnp.int32))
-    ptot = probs.sum(axis=1)
+    uniform among matched.  ``match`` and ``weights`` hold one [N] array
+    a stage.  Returns (stage_idx, any_match)."""
+    wm = [jnp.where(m & (w > 0), w, 0) for m, w in zip(match, weights)]
+    total = functools.reduce(jnp.add, wm)
+    probs = [jnp.where(total > 0, x, m.astype(jnp.int32)) for x, m in zip(wm, match)]
+    ptot = functools.reduce(jnp.add, probs)
     any_match = ptot > 0
     # sample by cumulative-sum inversion: first index with cum > r
     r = (u * ptot.astype(jnp.float32)).astype(jnp.int32)  # r in [0, ptot)
     r = jnp.minimum(r, jnp.maximum(ptot - 1, 0))
-    cum = jnp.cumsum(probs, axis=1)
-    choice = jnp.argmax(cum > r[:, None], axis=1).astype(jnp.int32)
+    cum = jnp.zeros_like(ptot)
+    choice = jnp.zeros_like(ptot)
+    found = jnp.zeros(ptot.shape, bool)
+    for s, p in enumerate(probs):
+        cum = cum + p
+        hit = ~found & (cum > r)
+        choice = jnp.where(hit, s, choice)
+        found = found | hit
     return jnp.where(any_match, choice, IDLE), any_match
 
 
@@ -142,19 +179,26 @@ def _tick_impl(params: TickParams, soa: SoA, dt_ms: int) -> Tuple[SoA, TickOut]:
     """Advance virtual time by dt_ms and run one transition pass."""
     now = soa.now + jnp.int32(dt_ms)
     key, k_choice, k_jitter = jax.random.split(soa.key, 3)
-    N = soa.features.shape[0]
+    N, C = soa.features.shape
+    S = params.w_static.shape[0]
 
     # 1. fire: delay elapsed (the WeightDelayingQueue pop)
     fired = soa.active & (soa.stage >= 0) & (soa.fire_at <= now)
-    stage_c = jnp.clip(soa.stage, 0, params.w_static.shape[0] - 1)
+    stage_c = jnp.clip(soa.stage, 0, S - 1)
 
-    # 2. effects: gather the compiled patch lowering for (sig, stage)
-    mode = params.eff_mode[soa.sig, stage_c]  # [N, C]
-    val = params.eff_val[soa.sig, stage_c]  # [N, C]
+    # 2. effects: the compiled patch lowering for (sig, stage), mode and
+    # value in one read; one table row where every signature's effects
+    # are the same (params_from_compiled)
+    eff = jnp.concatenate([params.eff_mode, params.eff_val], axis=-1)
+    if eff.shape[0] == 1:
+        eff = _lookup(eff[0], stage_c)  # [N, 2C]
+    else:
+        eff = _lookup(eff.reshape(-1, 2 * C), soa.sig * S + stage_c)
+    mode, val = eff[:, :C], eff[:, C:]
     apply_mask = fired[:, None] & (mode == 1)
     features = jnp.where(apply_mask, val, soa.features)
 
-    deleted_now = fired & params.stage_delete[stage_c]
+    deleted_now = fired & _lookup(params.stage_delete, stage_c)
     active = soa.active & ~deleted_now
 
     # 3. rematch rows: fresh transitions + host-forced
@@ -162,23 +206,27 @@ def _tick_impl(params: TickParams, soa: SoA, dt_ms: int) -> Tuple[SoA, TickOut]:
 
     # 4. match + weighted choice
     match = match_stages(params, features)
-    w_over = params.ov_w[soa.ovc]  # [N, S]
-    weights = jnp.where(w_over != SENTINEL, w_over, params.w_static[None, :])
+    w_over = _lookup(params.ov_w, soa.ovc)  # [N, S]
+    weights = [
+        jnp.where(w_over[:, s] != SENTINEL, w_over[:, s], params.w_static[s])
+        for s in range(S)
+    ]
     u = jax.random.uniform(k_choice, (N,))
     new_stage, any_match = _weighted_choice(match, weights, u)
 
     # 5. timers: delay + jitter for the chosen stage
-    ns_c = jnp.clip(new_stage, 0, params.w_static.shape[0] - 1)
-    d_over = jnp.take_along_axis(params.ov_d[soa.ovc], ns_c[:, None], axis=1)[:, 0]
-    j_over = jnp.take_along_axis(params.ov_j[soa.ovc], ns_c[:, None], axis=1)[:, 0]
-    d = jnp.where(d_over != SENTINEL, d_over, params.d_static[ns_c])
+    ns_c = jnp.clip(new_stage, 0, S - 1)
+    ov_at = soa.ovc * S + ns_c
+    d_over = _lookup(params.ov_d.reshape(-1), ov_at)
+    j_over = _lookup(params.ov_j.reshape(-1), ov_at)
+    d = jnp.where(d_over != SENTINEL, d_over, _lookup(params.d_static, ns_c))
     # deletionTimestamp deadline: duration = deadline - now
     has_dl = soa.del_ts != SENTINEL
-    d = jnp.where(params.d_from_del_ts[ns_c] & has_dl, soa.del_ts - now, d)
+    d = jnp.where(_lookup(params.d_from_del_ts, ns_c) & has_dl, soa.del_ts - now, d)
 
-    j = jnp.where(j_over != SENTINEL, j_over, params.j_static[ns_c])
-    j = jnp.where(params.j_from_del_ts[ns_c] & has_dl, soa.del_ts - now, j)
-    has_j = params.has_jitter[ns_c] & (j != SENTINEL)
+    j = jnp.where(j_over != SENTINEL, j_over, _lookup(params.j_static, ns_c))
+    j = jnp.where(_lookup(params.j_from_del_ts, ns_c) & has_dl, soa.del_ts - now, j)
+    has_j = _lookup(params.has_jitter, ns_c) & (j != SENTINEL)
 
     uj = jax.random.uniform(k_jitter, (N,))
     span = jnp.maximum(j - d, 0)
@@ -220,26 +268,58 @@ tick = functools.partial(jax.jit, static_argnames=("dt_ms",), donate_argnums=(1,
 
 
 def _run_ticks_collect_impl(
-    params: TickParams, soa: SoA, dt_ms: int, num_ticks: int
+    params: TickParams, soa: SoA, count: jax.Array, dt_ms: int, num_ticks: int
 ) -> Tuple[SoA, jax.Array]:
-    """Macro-tick: advance ``num_ticks`` ticks on device, collecting the
-    per-tick fired stage as one compact [K, N] int8 array (IDLE = not
-    fired).  One dispatch + ONE device->host transfer replaces 4 blocking
-    reads per tick: each round-trip stalls the host, and the tick itself
-    is short.  ``deleted`` is recomputed on host from stage_delete[stage];
-    sub-tick virtual times are now0 + (k+1)*dt."""
+    """Macro-tick: advance ``count`` ticks on device (a traced int of at
+    most ``num_ticks``), collecting the per-tick fired stage as one
+    compact [num_ticks, N] int8 array whose rows past ``count`` are IDLE.
+    One dispatch + ONE device->host transfer replaces 4 blocking reads
+    per tick: each round-trip stalls the host, and the tick itself is
+    short.  The count is not part of the program, so a loop whose count
+    follows its timing meets no new shape.  ``deleted`` is recomputed on
+    host from stage_delete[stage]; sub-tick virtual times are
+    now0 + (k+1)*dt."""
 
-    def body(soa, _):
+    def body(carry):
+        k, soa, stages = carry
         soa, out = _tick_impl(params, soa, dt_ms)
-        return soa, out.fired_stage.astype(jnp.int8)
+        stages = jax.lax.dynamic_update_index_in_dim(
+            stages, out.fired_stage.astype(jnp.int8), k, 0
+        )
+        return k + 1, soa, stages
 
-    soa, stages = jax.lax.scan(body, soa, None, length=num_ticks)
+    stages = jnp.full((num_ticks, soa.stage.shape[0]), IDLE, jnp.int8)
+    _, soa, stages = jax.lax.while_loop(
+        lambda carry: carry[0] < count, body, (jnp.int32(0), soa, stages)
+    )
     return soa, stages
 
 
 run_ticks_collect = functools.partial(
     jax.jit, static_argnames=("dt_ms", "num_ticks"), donate_argnums=(1,)
 )(_run_ticks_collect_impl)
+
+
+def _run_node_ticks_collect_impl(
+    params: TickParams, soa: SoA, count: jax.Array, dt_ms: int, num_ticks: int
+) -> Tuple[SoA, jax.Array]:
+    """The Node player's macro-tick: ``_run_ticks_collect_impl`` under a
+    jit name of its own (``jit__run_node_ticks_collect_impl``), so that a
+    profile tells a Node tick from a Pod tick.  The two SoAs may differ
+    in rows by a hundredfold (the Node player is sized to the nodes)."""
+    return _run_ticks_collect_impl(params, soa, count, dt_ms, num_ticks)
+
+
+run_node_ticks_collect = functools.partial(
+    jax.jit, static_argnames=("dt_ms", "num_ticks"), donate_argnums=(1,)
+)(_run_node_ticks_collect_impl)
+
+
+def collect_program(kind: str) -> Tuple[str, Any]:
+    """(name, jitted function) of the macro-tick that plays ``kind``."""
+    if kind == "Node":
+        return "run_node_ticks_collect", run_node_ticks_collect
+    return "run_ticks_collect", run_ticks_collect
 
 
 def _scatter_rows_impl(

@@ -203,6 +203,7 @@ def test_bringup_counts_nodes_acquired_and_the_wave_stands_still(fresh):
 
     t0 = time.perf_counter()
     ctr.start()
+    t_started = time.perf_counter()
     try:
         player = ctr.device_players["Node"]
         assert ctr.device_players["Pod"].wave_wall_s is None
@@ -236,13 +237,18 @@ def test_bringup_counts_nodes_acquired_and_the_wave_stands_still(fresh):
             lambda: bringup_counts() == {"lease_acquire": n + 2, "node_sync": n + 2})
         assert player.wave_wall_s > wall + 1.0  # the rounds above lie inside it
         started = player._threads[0]
+        t_stopping = time.perf_counter()
     finally:
         ctr.stop()
+    t_end = time.perf_counter()
     assert not started.is_alive()
     table = stage_table("Node")
     total = sum(s for s, _n in table.values()) - table["compile"][0]
-    # the thread started inside ctr.start() and ended inside ctr.stop()
-    assert total == pytest.approx(time.perf_counter() - t0, rel=0.1), table
+    # the thread started inside ctr.start() and ended inside ctr.stop(), each
+    # of which spends time off the thread (the other players' starts and
+    # joins): its wall lies between stop's call less start's return and
+    # stop's return less start's call
+    assert (t_stopping - t_started) * 0.9 <= total <= (t_end - t0) * 1.1, table
     assert not {"lease_acquire", "node_sync"} & set(table)
     # both kinds' first ticks are milestones of the process by now
     got = {(m, ls.get("kind")) for m, ls, _v in telemetry.milestones().snapshot()}
